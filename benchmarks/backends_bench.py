@@ -11,7 +11,7 @@ mode (a correctness proxy, not kernel speed), so they run at a reduced
 batch to keep CI wall-clock sane.  Every row is stamped with its
 measurement provenance (platform / interpret flag / jax version) by
 ``benchmarks.common.stamp_row``, and two end-to-end serve rows record the
-full-table baseline vs the one-pass ``serve_fused`` robe path
+full-table baseline vs robe through its Pallas kernels
 (``table4_inference_throughput.serve_rows``).  The JSON lands at the repo
 root.
 """
@@ -91,8 +91,8 @@ def run(batch: int = 8192, iters: int = 16):
     for kind in KERNEL_KINDS:
         rows.append(_row(kind, k_batch, k_iters, idx_np, use_kernel=True))
     # end-to-end serve rows: the paper's 3.1×-vs-full inference comparison
-    # as recorded data — full-table serve baseline vs the one-pass robe
-    # serve super-kernel (lazy import: table4 pulls in the model stack)
+    # as recorded data — full-table serve baseline vs the robe
+    # serve op (lazy import: table4 pulls in the model stack)
     from benchmarks.table4_inference_throughput import serve_rows
     rows.extend(serve_rows(batch=k_batch, iters=k_iters))
     return rows

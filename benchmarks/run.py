@@ -84,4 +84,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     main()

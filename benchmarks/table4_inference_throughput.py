@@ -17,7 +17,7 @@ Three complementary measurements:
    not a loose script.
 
 ``serve_rows`` additionally records the end-to-end serve comparison —
-full-table baseline vs the one-pass ``serve_fused`` robe super-kernel —
+full-table baseline vs robe through its Pallas kernels —
 as provenance-stamped rows appended to ``BENCH_backends.json`` by
 ``backends_bench.run`` (the 3.1× claim's landing place once TPU-mode
 numbers exist).
@@ -71,14 +71,15 @@ def serve_rows(batch: int = 512, iters: int = 2) -> list:
     """The paper's serve comparison as recorded ``BENCH_backends.json``
     rows instead of a loose script: the full-table serve baseline (row-
     sharded `model` layout on the production mesh; dense jnp path here)
-    against the one-pass robe serve super-kernel (``serve_fused`` —
-    interpret mode off-TPU, so the row is a correctness/regression
-    datapoint; the 3.1× claim needs the TPU-mode run, see ROADMAP)."""
+    against robe with ``use_kernel`` (the DMA-gather lookup and
+    dot-interaction kernels — interpret mode off-TPU, so the row is a
+    correctness/regression datapoint; the 3.1× claim needs the TPU-mode
+    run, see ROADMAP)."""
     on_tpu = jax.default_backend() == "tpu"
     rows = []
     for name, cfg, mode in (
             ("backends/full+serve", make_cfg("dlrm", "full"), "jnp"),
-            ("backends/robe+serve_fused",
+            ("backends/robe+kernel+serve",
              make_cfg("dlrm", "robe", use_kernel=True),
              "pallas" if on_tpu else "interpret")):
         params = init_params(jax.random.PRNGKey(0), cfg)

@@ -38,6 +38,8 @@ def train(embedding: str, steps: int = 120):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     lf = train("full")
     lr = train("robe")
     print(f"gap (robe - full): {lr - lf:+.3f} nats at 8x compression")

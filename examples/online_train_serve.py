@@ -85,6 +85,8 @@ def serve_through_pushes(server: EmbeddingServer, trainer: OnlineTrainer,
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     with tempfile.TemporaryDirectory() as publish_dir:
         server = EmbeddingServer(ServerConfig(vocab_sizes=VOCABS,
                                               backends=("full",),

@@ -151,6 +151,8 @@ def retrieval():
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     server = build_server()
     async_router(server)
     replay_policies(server)

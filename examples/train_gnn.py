@@ -53,5 +53,7 @@ def sampled_minibatch():
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     full_graph()
     sampled_minibatch()

@@ -1,16 +1,7 @@
-"""repro package bootstrap.
+"""ROBE / DLRM reproduction in jax, with Pallas TPU kernels.
 
-Installs the jax compatibility shims (``repro.dist.compat``) at package
-import, so every module — and the test subprocesses, which import a repro
-module before touching ``jax.shard_map`` — sees one distributed API
-surface regardless of the pinned jax version.
-
-Importing jax here does NOT initialize the backend, so modules that must
-set XLA_FLAGS (launch/dryrun.py, tests/conftest.py) still work as long as
-they set the flag before the first device query.
+Importing the package touches no device state, so scripts that must set
+XLA_FLAGS (tests/conftest.py, the dry-run's ``__main__``) can still do so
+before the first device query.  Entry points call
+``repro.launch.cache.use_compile_cache()`` once, before they compile.
 """
-
-from repro.dist import compat as _compat
-
-_compat.install()
-del _compat

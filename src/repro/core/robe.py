@@ -14,7 +14,7 @@ block-id computation be a limb-wise shift instead of a 64-bit division.
 
 The jnp path below is the reference implementation used everywhere off the
 hot path; ``repro.kernels.ops.robe_lookup`` is the Pallas TPU kernel with the
-same semantics (block-coalesced VMEM reads), validated against this module.
+same semantics (block-coalesced DMA reads), validated against this module.
 Models never call this module directly: the consumer-facing surface is the
 ``robe`` ``EmbeddingBackend`` (``repro.nn.embedding_backends.robe``), which
 owns placement, PartitionSpecs, and the roofline cost model on top of the

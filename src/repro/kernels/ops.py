@@ -1,21 +1,23 @@
 """Jit'd public wrappers around the Pallas kernels, with autodiff.
 
-``robe_lookup``: forward = Pallas kernel (or the jnp path on non-TPU /
-awkward shapes); backward = the paper's Fig.-2 scatter-add of output grads
-into the shared array, expressed as an XLA scatter (segment-sum over slots).
-The scatter IS the semantics of weight sharing — every aliased parameter's
-gradient accumulates into its slot.
+``robe_lookup``: forward = Pallas kernel (a DMA gather of Z-blocks from
+the array in HBM) or the jnp path; backward = the paper's Fig.-2
+scatter-add of output grads into the shared array, expressed as an XLA
+scatter (segment-sum over slots).  The scatter IS the semantics of weight
+sharing — every aliased parameter's gradient accumulates into its slot.
 
 ``qr_lookup`` / ``tt_lookup`` follow the identical contract for the two
-baseline substrates: fused Pallas forward (index math in-kernel, tables /
-cores VMEM-resident), custom-VJP backward as an XLA scatter-add into the
-tables/cores.
+baseline substrates: fused Pallas forward, custom-VJP backward as an XLA
+scatter-add into the tables/cores.
 
-Selection logic: kernels run on TPU, or in interpret mode when
-``use_kernel`` forces them; everywhere else the pure-jnp path (same math)
-keeps CPU benchmarks fast.  Every fused op must pass the conformance
-harness (tests/test_kernel_conformance.py) before it ships — see ROADMAP
-§Kernel conformance.
+Selection: ``use_kernel`` picks the Pallas forward.  On a TPU the kernel
+is compiled by Mosaic; elsewhere it runs in Pallas interpret mode.  A
+kernel the TPU compiler refuses raises on a TPU with the compiler's
+reason (``REFUSED_ON_TPU``) — it is never swapped for interpret mode or
+the jnp path there.  Every fused op must pass the conformance harness
+(tests/test_kernel_conformance.py) before it ships, and the ones that
+compile are compiled for a v5e at published widths by
+tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -28,18 +30,36 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.robe import RobeSpec, robe_slots, robe_signs
-from repro.core import robe as _core
 from repro.kernels import ref as _ref
 from repro.kernels.robe_lookup import (qrobe_lookup_pallas,
                                        robe_lookup_pallas)
 from repro.kernels.dot_interaction import dot_interaction_pallas
 from repro.kernels.qr_lookup import qr_lookup_pallas
-from repro.kernels.serve_fused import serve_fused_pallas
 from repro.kernels.tt_lookup import tt_lookup_pallas
+
+
+#: kernels that Mosaic refuses for a TPU, with the compiler's reason
+#: (ROADMAP queue 1 lists each)
+REFUSED_ON_TPU = {
+    "tt_lookup": "Mosaic refuses kernels/tt_lookup.py: NotImplementedError: "
+                 "Only 2D gather is supported (the in-kernel jnp.take of "
+                 "core slices from the VMEM-resident TT cores)",
+}
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _interpret(op: str) -> bool:
+    """Interpret mode off a TPU; on a TPU, compile — or raise the
+    compiler's refusal for a kernel that cannot compile there."""
+    if not _on_tpu():
+        return True
+    if op in REFUSED_ON_TPU:
+        raise NotImplementedError(f"{op}: no TPU kernel: "
+                                  f"{REFUSED_ON_TPU[op]}")
+    return False
 
 
 # ---------------------------------------------------------------------------
@@ -54,7 +74,7 @@ def robe_lookup(memory: jnp.ndarray, rows: jnp.ndarray,
     if use_kernel:
         return robe_lookup_pallas(memory, rows,
                                   table_ids, dim, spec,
-                                  interpret=not _on_tpu())
+                                  interpret=_interpret("robe_lookup"))
     return _ref.robe_lookup_ref(memory, rows,
                                 jnp.asarray(table_ids, jnp.uint32), dim, spec)
 
@@ -104,7 +124,7 @@ def qrobe_lookup(codes: jnp.ndarray, scale: jnp.ndarray, rows: jnp.ndarray,
     ROBE array, delivered in ``scale.dtype`` (single-rounding contract)."""
     if use_kernel:
         return qrobe_lookup_pallas(codes, scale, rows, table_ids, dim, spec,
-                                   group_log2, interpret=not _on_tpu())
+                                   group_log2, interpret=_interpret("qrobe_lookup"))
     return _ref.qrobe_lookup_ref(codes, scale, rows,
                                  jnp.asarray(table_ids, jnp.uint32), dim,
                                  spec, group_log2)
@@ -147,7 +167,7 @@ def dot_interaction(feats: jnp.ndarray, self_interaction: bool = False,
     """[B, F, D] -> [B, F*(F±1)/2] pairwise dots (DLRM interaction)."""
     if use_kernel:
         return dot_interaction_pallas(feats, self_interaction,
-                                      interpret=not _on_tpu())
+                                      interpret=_interpret("dot_interaction"))
     return _ref.dot_interaction_ref(feats, self_interaction)
 
 
@@ -176,80 +196,9 @@ dot_interaction.defvjp(_dot_fwd, _dot_bwd)
 
 
 # ---------------------------------------------------------------------------
-# serve_fused: the one-pass serve super-kernel (lookup → bag pool → gram).
-# Forward-only speed is the point — it exists for the inference hot path —
-# but the VJP is real (conformance harness checks it against jax.grad of
-# the reference) so a fused serve path is still differentiable end to end.
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def serve_fused(memory: jnp.ndarray, idx: jnp.ndarray, bot: jnp.ndarray,
-                table_ids: Tuple[int, ...], dim: int, spec: RobeSpec,
-                use_kernel: bool = False) -> jnp.ndarray:
-    """Fused multi-field ROBE lookup → bag pooling → dot interaction.
-
-    idx [B, F] or [B, F, bag] (−1-padded bags), bot [B, dim] ->
-    [B, (F+1)·F/2] strictly-lower gram triangle of [bot; pooled emb],
-    in ``bot``'s dtype.  One Pallas pass per batch tile — no [B, F, D]
-    intermediate in HBM (see kernels/serve_fused.py).
-    """
-    if use_kernel:
-        return serve_fused_pallas(memory, idx, bot, table_ids, dim, spec,
-                                  interpret=not _on_tpu())
-    return _ref.serve_fused_ref(memory, idx, bot,
-                                jnp.asarray(table_ids, jnp.uint32), dim,
-                                spec)
-
-
-def _serve_fwd(memory, idx, bot, table_ids, dim, spec, use_kernel):
-    out = serve_fused(memory, idx, bot, table_ids, dim, spec, use_kernel)
-    # residuals stay O(|M| + B·F): the [B, F, dim] pooled embeddings are
-    # recomputed in the backward rather than saved
-    return out, (memory, idx, bot)
-
-
-def _serve_bwd(table_ids, dim, spec, use_kernel, res, g):
-    memory, idx, bot = res
-    if idx.ndim == 2:
-        idx = idx[..., None]
-    b, f, bag = idx.shape
-    mask = idx >= 0
-    safe = jnp.where(mask, idx, 0)
-    tids = jnp.asarray(table_ids, jnp.uint32)[None, :, None]
-    # recompute the pooled features (same path as the reference forward)
-    emb = _core.robe_lookup(memory, spec, tids, safe, dim)
-    pooled = (emb * mask[..., None].astype(emb.dtype)).sum(axis=2)
-    feats = jnp.concatenate(
-        [bot[:, None, :].astype(jnp.float32),
-         pooled.astype(bot.dtype).astype(jnp.float32)], axis=1)
-    # gram transpose, as in _dot_bwd: symmetric scatter of the triangle
-    # cotangent, then one fused contraction against the features
-    rows, cols = np.tril_indices(f + 1, k=-1)
-    g32 = g.astype(jnp.float32)
-    sym = jnp.zeros((b, f + 1, f + 1), jnp.float32
-                    ).at[:, rows, cols].add(g32).at[:, cols, rows].add(g32)
-    dfeats = jnp.einsum("bfg,bgd->bfd", sym, feats)       # [B, F+1, dim]
-    dbot = dfeats[:, 0]
-    # pooling transpose: broadcast the field cotangent over the bag, mask
-    # the padded slots, then the paper's Fig.-2 scatter-add into the array
-    dpool = jnp.broadcast_to(dfeats[:, 1:, None, :], (b, f, bag, dim))
-    dpool = dpool * mask[..., None].astype(jnp.float32)
-    if spec.use_sign:
-        dpool = dpool * robe_signs(spec, tids, safe, dim)
-    slots = robe_slots(spec, tids, safe, dim)             # [B, F, bag, dim]
-    gmem = jnp.zeros((memory.shape[0],), jnp.float32
-                     ).at[slots.reshape(-1).astype(jnp.int32)
-                          ].add(dpool.reshape(-1))
-    return gmem.astype(memory.dtype), None, dbot.astype(bot.dtype)
-
-
-serve_fused.defvjp(_serve_fwd, _serve_bwd)
-
-
-# ---------------------------------------------------------------------------
 # compressed-substrate lookups (hashed / tensor-train backends).  Same
-# contract as robe_lookup: forward = fused Pallas kernel (TPU, or interpret
-# mode when forced) or the jnp reference path; backward = an explicit XLA
+# contract as robe_lookup: forward = fused Pallas kernel or the jnp
+# reference path; backward = an explicit XLA
 # scatter-add of the output grads into the tables/cores, f32-accumulated and
 # delivered in the parameter dtype (mirrors _lookup_bwd).
 # ---------------------------------------------------------------------------
@@ -262,12 +211,12 @@ def qr_lookup(q_table: jnp.ndarray, r_table: jnp.ndarray,
     """Fused QR compositional lookup.
 
     [B, F] int rows -> [B, F, dim] via ``Q[id // m + q_off[f]] *
-    R[id % m + r_off[f]]`` — quotient/remainder indices computed in-path
-    (in-kernel on the Pallas side), both gathers and the product one pass.
+    R[id % m + r_off[f]]`` — both row gathers (DMA on the Pallas side)
+    and the product in one pass.
     """
     if use_kernel:
         return qr_lookup_pallas(q_table, r_table, idx, q_off, r_off, m,
-                                interpret=not _on_tpu())
+                                interpret=_interpret("qr_lookup"))
     return _ref.qr_lookup_ref(q_table, r_table, idx, q_off, r_off, m)
 
 
@@ -308,7 +257,7 @@ def tt_lookup(core0: jnp.ndarray, core1: jnp.ndarray, core2: jnp.ndarray,
     """
     if use_kernel:
         return tt_lookup_pallas(core0, core1, core2, idx, offsets, factors,
-                                dim, interpret=not _on_tpu())
+                                dim, interpret=_interpret("tt_lookup"))
     return _ref.tt_lookup_ref(core0, core1, core2, idx, offsets, factors,
                               dim)
 

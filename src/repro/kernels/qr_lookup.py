@@ -1,20 +1,19 @@
 """Pallas TPU kernel: fused QR (quotient × remainder) embedding lookup.
 
 The ``hashed`` backend's hot path.  The unfused jnp path is three HBM
-round-trips per batch — gather Q rows, gather R rows, elementwise product —
-with the quotient/remainder index arithmetic materialized as two [B, F]
-intermediates.  Here the whole composition runs per VMEM tile:
+round-trips per batch — gather Q rows, gather R rows, elementwise product.
+Here the two gathers and the product run in one pass per tile of (row,
+field) pairs:
 
-  * both tables are small by construction (O(m + vocab/m) rows per field)
-    and stay **VMEM-resident**, like the ROBE array in ``robe_lookup``;
-  * ``q_idx = id // m + q_off[f]`` / ``r_idx = id % m + r_off[f]`` are a few
-    VPU integer ops computed in-kernel from the tiled row ids — no
-    host-side index preprocessing and no [B, F] index traffic;
-  * the two row gathers and the product fuse into one pass per tile, so the
-    [TB, F, dim] product tile is the only thing written back to HBM.
+  * ``q_idx = id // m + q_off[f]`` / ``r_idx = id % m + r_off[f]`` are
+    computed before the kernel and arrive in SMEM, one tile at a time;
+  * both tables stay in HBM (``pl.ANY``): each pair's Q row and R row are
+    copied into VMEM by DMA — no vector gather, which Mosaic lacks;
+  * the product of the two staged tiles is the only thing written back.
 
-Batching reuses ``pick_batch_tile``'s pad-and-slice scheme: the grid tiles
-the batch, prime batch sizes pad up to the tile and slice back.
+The tables are widened to f32 (exact) and their rows padded to a
+multiple of 128 lanes before the kernel: a DMA moves whole lane tiles.
+The product is rounded once into the table dtype, as in the reference.
 
 Validated in interpret mode against ``repro.kernels.ref.qr_lookup_ref``
 (tests/test_kernel_conformance.py sweeps dtype/shape/bag regimes).
@@ -28,19 +27,33 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiling import pad_batch, pick_batch_tile, round_up
+from repro.kernels import ref as _ref
+from repro.kernels.tiling import LANES, pair_tile, round_up
 
 
-def _kernel(m: int, idx_ref, qoff_ref, roff_ref, q_ref, r_ref, out_ref):
-    idx = idx_ref[...]                                   # [TB, F] int32
-    q_idx = idx // m + qoff_ref[...][None, :]
-    r_idx = idx % m + roff_ref[...][None, :]
-    tb, f = idx.shape
-    dim = q_ref.shape[1]
-    q = jnp.take(q_ref[...], q_idx.reshape(-1), axis=0)  # [TB·F, dim]
-    r = jnp.take(r_ref[...], r_idx.reshape(-1), axis=0)
-    out_ref[...] = (q * r).reshape(tb, f, dim).astype(out_ref.dtype)
+def _kernel(pairs: int, q_idx_ref, r_idx_ref, q_hbm, r_hbm, out_ref,
+            q_stage, r_stage, sem):
+    def copies(p):
+        return (pltpu.make_async_copy(q_hbm.at[pl.ds(q_idx_ref[p], 1)],
+                                      q_stage.at[pl.ds(p, 1)], sem),
+                pltpu.make_async_copy(r_hbm.at[pl.ds(r_idx_ref[p], 1)],
+                                      r_stage.at[pl.ds(p, 1)], sem))
+
+    def issue(p, c):
+        for cp in copies(p):
+            cp.start()
+        return c
+
+    def drain(p, c):
+        for cp in copies(p):
+            cp.wait()
+        return c
+
+    jax.lax.fori_loop(0, pairs, issue, 0)
+    jax.lax.fori_loop(0, pairs, drain, 0)
+    out_ref[...] = q_stage[...] * r_stage[...]
 
 
 @functools.partial(jax.jit, static_argnames=("q_off", "r_off", "m",
@@ -56,24 +69,32 @@ def qr_lookup_pallas(q_table: jnp.ndarray, r_table: jnp.ndarray,
     """
     b, f = idx.shape
     dim = q_table.shape[1]
-    tb = pick_batch_tile(b, f, dim)
-    b_pad = round_up(b, tb)
-    # pad with row 0 (any valid id) and slice the output back below
-    idx = pad_batch(idx, b_pad)
+    lanes = round_up(dim, LANES)
+    q_idx, r_idx = _ref.qr_indices(idx, q_off, r_off, m)
+    widen = lambda t: jnp.pad(t.astype(jnp.float32),
+                              ((0, 0), (0, lanes - dim)))
+    n = b * f
+    pairs, n_pad = pair_tile(n)
+    # padded pairs fetch row 0 of each table and are sliced off below
+    q_idx = jnp.pad(q_idx.reshape(-1).astype(jnp.int32), (0, n_pad - n))
+    r_idx = jnp.pad(r_idx.reshape(-1).astype(jnp.int32), (0, n_pad - n))
 
     out = pl.pallas_call(
-        functools.partial(_kernel, m),
-        grid=(b_pad // tb,),
+        functools.partial(_kernel, pairs),
+        grid=(n_pad // pairs,),
         in_specs=[
-            pl.BlockSpec((tb, f), lambda i: (i, 0)),              # row ids
-            pl.BlockSpec((f,), lambda i: (0,)),                   # q offsets
-            pl.BlockSpec((f,), lambda i: (0,)),                   # r offsets
-            pl.BlockSpec(q_table.shape, lambda i: (0, 0)),        # Q in VMEM
-            pl.BlockSpec(r_table.shape, lambda i: (0, 0)),        # R in VMEM
+            pl.BlockSpec((pairs,), lambda i: (i,),
+                         memory_space=pltpu.SMEM),           # Q rows
+            pl.BlockSpec((pairs,), lambda i: (i,),
+                         memory_space=pltpu.SMEM),           # R rows
+            pl.BlockSpec(memory_space=pl.ANY),               # Q in HBM
+            pl.BlockSpec(memory_space=pl.ANY),               # R in HBM
         ],
-        out_specs=pl.BlockSpec((tb, f, dim), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b_pad, f, dim), q_table.dtype),
+        out_specs=pl.BlockSpec((pairs, lanes), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, lanes), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((pairs, lanes), jnp.float32),
+                        pltpu.VMEM((pairs, lanes), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )(idx, jnp.asarray(q_off, jnp.int32), jnp.asarray(r_off, jnp.int32),
-      q_table, r_table)
-    return out[:b] if b_pad != b else out
+    )(q_idx, r_idx, widen(q_table), widen(r_table))
+    return out[:n, :dim].reshape(b, f, dim).astype(q_table.dtype)

@@ -67,28 +67,6 @@ def dot_interaction_ref(feats: jnp.ndarray, self_interaction: bool = False
     return gram[:, rows, cols]
 
 
-def serve_fused_ref(memory: jnp.ndarray, idx: jnp.ndarray,
-                    bot: jnp.ndarray, table_ids: jnp.ndarray, dim: int,
-                    spec: RobeSpec) -> jnp.ndarray:
-    """Per-row oracle for the one-pass serve super-kernel: ROBE lookup →
-    masked bag pooling → DLRM dot interaction against the bottom-MLP
-    output, composed from the existing references (autodiff-able).
-
-    idx: [B, F] or [B, F, bag] int32 row ids (−1 = padded bag slot);
-    bot: [B, dim] -> [B, (F+1)·F/2] in ``bot``'s dtype.
-    """
-    if idx.ndim == 2:
-        idx = idx[..., None]
-    mask = idx >= 0
-    safe = jnp.where(mask, idx, 0)
-    tids = jnp.asarray(table_ids, jnp.uint32)[None, :, None]
-    emb = _core_lookup(memory, spec, tids, safe, dim)     # [B, F, bag, dim]
-    pooled = (emb * mask[..., None].astype(emb.dtype)).sum(axis=2)
-    feats = jnp.concatenate([bot[:, None, :], pooled.astype(bot.dtype)],
-                            axis=1)
-    return dot_interaction_ref(feats, False)
-
-
 def cin_layer_ref(x0: jnp.ndarray, xk: jnp.ndarray, w: jnp.ndarray
                   ) -> jnp.ndarray:
     """xDeepFM Compressed Interaction Network layer.

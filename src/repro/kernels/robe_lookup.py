@@ -1,212 +1,235 @@
-"""Pallas TPU kernel: fused ROBE hash + block-coalesced embedding lookup.
+"""Pallas TPU kernel: ROBE lookup as a DMA gather of Z-blocks from HBM.
 
 This is the paper's hot path (inference is memory-bound on embedding
-fetches; §2.3 Table 1).  TPU adaptation of the paper's cache story:
+fetches; §2.3 Table 1).  Element ``i`` of row ``x`` lives at
+``(h(e, Z_id) + Z_off) mod |M|``: a row of ``d`` elements is a handful of
+contiguous runs of the array, one per Z-block it touches.  The kernel
+reads exactly those runs — the "coalesced block read" of Table 1 — and
+never holds the array in VMEM (it is 52–105 MB at the paper's sizes):
 
-  * the compressed array M is small enough to be **VMEM-resident** (the
-    per-chip slice of a 100 MB array sharded 16-way is ~6 MB); VMEM plays the
-    role the LLC plays in the paper.
-  * with Z a multiple of d, one embedding row is ONE contiguous ``Z_off``-
-    shifted slice of M, so the fetch is a single aligned ``dynamic_slice``
-    (the "coalesced block read" of Table 1, row ``Z ≥ d``) instead of ``d``
-    random scalar gathers.
-  * the universal hash itself is ~a dozen uint32 VPU ops computed in-kernel
-    from the prefetched row ids — no host-side index preprocessing.
+  * **before the kernel** (XLA, vectorised): the hash of every block each
+    (row, field) pair touches, turned into one *window start* per block
+    into a circularly padded copy of the array laid out as ``[R, 128]``
+    lanes.  Window ``k`` is placed so that its lane ``i`` holds row
+    element ``i`` whenever element ``i`` falls in block ``k``;
+  * **in the kernel** (grid over tiles of pairs): the starts arrive in
+    SMEM, each window's ``W`` array rows are copied HBM→VMEM by DMA, a
+    dynamic lane rotate aligns the window, and a static-shape select keeps
+    the lanes that belong to block ``k``.  No vector gather and no dynamic
+    index into a value, for every ``(Z, d)``.
 
-Two kernels:
-  * ``robe_lookup_aligned``  — Z % d == 0 (paper's recommended regime).
-    grid over batch tiles; per (row, field) one dslice from the padded array.
-  * ``robe_lookup_general``  — any Z ≥ 1: per-element slot computation and a
-    VMEM gather.  Semantically identical to the oracle for every Z.
+The ±1 sign hash (``use_sign``) is applied after the kernel, and bf16 or
+int8 arrays are widened to f32 in the padded copy (exact), so the kernel
+itself only ever moves f32.  ``qrobe_lookup_pallas`` reuses the same
+gather over the dequantized array.
 
-Both validated in interpret mode against ``repro.kernels.ref.robe_lookup_ref``
-(tests/test_kernels.py sweeps B/F/d/Z/dtype).
+Validated in interpret mode against ``repro.kernels.ref`` (tests/
+test_kernels.py, tests/test_kernel_conformance.py) and compiled for a
+v5e at published widths by tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hashing import add64, mul32
-from repro.core.robe import RobeSpec
-from repro.kernels.tiling import pad_batch, pick_batch_tile, round_up
-
-# compat alias: the tile policy moved to repro.kernels.tiling (one shared
-# copy for every kernel); older call sites import it from here
-_pick_batch_tile = pick_batch_tile
+from repro.core.robe import RobeSpec, robe_signs
+from repro.kernels.tiling import LANES, pair_tile, round_up
 
 
-def _hash_rows(spec: RobeSpec, table_ids: jnp.ndarray, rows: jnp.ndarray,
-               dim: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Vectorized in-kernel hash for the aligned case (Z % d == 0).
+def n_segments(dim: int, z: int) -> int:
+    """Most Z-blocks one ``dim``-element row can touch.
 
-    rows [TB, F] int32 -> (start, None): start[TB, F] uint32 slice start into
-    the padded memory (= h(e, blk) + Z_off of the row's first element).
-    """
-    rows_u = rows.astype(jnp.uint32)
-    hi, lo = mul32(rows_u, jnp.uint32(dim))            # x*d, exact 64-bit
+    A row starts at ``x·d``, whose offset inside its block is a multiple
+    of ``gcd(d, Z)``; the worst start is ``Z − gcd`` and spans
+    ``(Z − gcd + d − 1) // Z + 1`` blocks (``d/Z`` when Z | d, 1 when
+    d | Z)."""
+    g = math.gcd(dim, z)
+    return (z - g + dim - 1) // z + 1
+
+
+def window_rows(dim: int) -> int:
+    """``[*, 128]`` array rows one ``dim``-element window can straddle."""
+    return (dim + LANES - 2) // LANES + 1
+
+
+def _padded_len(m: int, dim: int, z: int) -> int:
+    """Slots in the padded ``[R, 128]`` copy of an ``m``-slot array: room
+    for the farthest window (start ≤ front + m + Z − 2, plus its rows)."""
+    front = n_segments(dim, z) * z
+    return round_up(front + m + z + LANES * window_rows(dim), LANES)
+
+
+def gather_bytes(m: int, dim: int, z: int, n_pairs: int,
+                 itemsize: int = 4) -> int:
+    """HBM bytes one ``robe_gather`` call moves for ``n_pairs`` (row,
+    field) pairs over an ``m``-slot array of ``itemsize``-byte slots: the
+    per-call padded f32 copy (the array read, the copy written), every
+    pair's ``n_segments`` windows of ``window_rows · 128`` f32 read by DMA,
+    its SMEM starts, and the ``[n_pairs, dim]`` f32 result written."""
+    s = n_segments(dim, z)
+    copy = m * itemsize + _padded_len(m, dim, z) * 4
+    windows = n_pairs * s * window_rows(dim) * LANES * 4
+    return copy + windows + n_pairs * (s + 1) * 4 + n_pairs * dim * 4
+
+
+def _circular_rows(memory: jnp.ndarray, front: int, min_len: int
+                   ) -> jnp.ndarray:
+    """f32 copy of the circular array as ``[R, 128]``: padded index ``j``
+    holds slot ``(j − front) mod |M|`` for ``j < R·128`` (R·128 ≥
+    ``min_len``)."""
+    m = memory.shape[0]
+    total = round_up(max(min_len, front + m), LANES)
+    back = total - front - m
+    reps = -(-max(front, back) // m)
+    ext = jnp.tile(memory, reps) if reps > 1 else memory
+    flat = jnp.concatenate([ext[ext.shape[0] - front:], memory, ext[:back]])
+    return flat.astype(jnp.float32).reshape(total // LANES, LANES)
+
+
+def window_starts(spec: RobeSpec, table_ids: Tuple[int, ...],
+                  rows: jnp.ndarray, dim: int
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Window starts [N, S] (int32, into the padded copy) and each pair's
+    offset inside its first block [N] for rows [B, F] (N = B·F, row-major).
+
+    Block ``k`` of row ``x`` holds elements ``i`` with
+    ``(off0 + i) >> log2 Z == k``; its window start is
+    ``front + h(e, b0 + k) + off0 − k·Z`` so that window lane ``i`` is
+    element ``i`` (``front = S·Z`` keeps every start ≥ 0)."""
+    s = n_segments(dim, spec.block_size)
     lz = spec.log2_z
+    x = rows.astype(jnp.uint32).reshape(-1)
+    t = jnp.broadcast_to(jnp.asarray(table_ids, jnp.uint32)[None, :],
+                         rows.shape).reshape(-1)
+    hi, lo = mul32(x, jnp.uint32(dim))                    # x·d, exact
     if lz == 0:
         b_hi, b_lo = hi, lo
-        off = jnp.zeros_like(lo)
     else:
         b_lo = (lo >> lz) | (hi << (32 - lz))
         b_hi = hi >> lz
-        off = lo & jnp.uint32(spec.block_size - 1)
+    off0 = (lo & jnp.uint32(spec.block_size - 1)).astype(jnp.int32)
     h = spec.hash_fn()
-    t = jnp.broadcast_to(table_ids.astype(jnp.uint32)[None, :], rows.shape)
-    base = h(t, b_hi, b_lo)
-    return base + off, off
+    front = s * spec.block_size
+    starts = []
+    for k in range(s):
+        k_hi, k_lo = add64(b_hi, b_lo, jnp.uint32(k))
+        base = h(t, k_hi, k_lo).astype(jnp.int32)
+        starts.append(front + base + off0 - k * spec.block_size)
+    return jnp.stack(starts, axis=1), off0
 
 
-def _signs_tile(spec: RobeSpec, table_ids: jnp.ndarray, rows: jnp.ndarray,
-                dim: int) -> jnp.ndarray:
-    """±1 signs for a [TB, F] tile -> [TB, F, dim] float32."""
-    g = spec.sign_fn()
-    rows_u = rows.astype(jnp.uint32)[..., None]
-    hi, lo = mul32(rows_u, jnp.uint32(dim))
-    shape = lo.shape[:-1] + (dim,)
-    hi = jnp.broadcast_to(hi, shape)
-    lo = jnp.broadcast_to(lo, shape)
-    i = jnp.broadcast_to(jnp.arange(dim, dtype=jnp.uint32), shape)
-    hi, lo = add64(hi, lo, i)
-    t = jnp.broadcast_to(table_ids.astype(jnp.uint32)[None, :, None], shape)
-    bit = g(t, hi, lo)
-    return (1 - 2 * bit.astype(jnp.int32)).astype(jnp.float32)
+def _gather_kernel(n_seg: int, log2_z: int, dim: int, n_rows: int,
+                   pairs: int, starts_ref, off0_ref, mem_hbm, out_ref,
+                   stage, sem):
+    """One tile of ``pairs`` (row, field) pairs -> out_ref [pairs, dim]."""
+    n_blk = -(-dim // LANES)                 # 128-lane blocks per row
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def copy(r, k):
+        t = starts_ref[r * n_seg + k]
+        return pltpu.make_async_copy(
+            mem_hbm.at[pl.ds(t // LANES, n_rows)], stage.at[r], sem)
+
+    def segment(k, c):
+        def issue(r, c):
+            copy(r, k).start()
+            return c
+
+        def drain(r, c):
+            copy(r, k).wait()
+            return c
+
+        def place(r, c):
+            t = starts_ref[r * n_seg + k]
+            sh = t % LANES
+            # rolled[w, j] = window row w at lane (j + sh) % 128
+            rolled = pltpu.roll(stage[r], (LANES - sh) % LANES, 1)
+            for a in range(n_blk):
+                w = min(LANES, dim - a * LANES)
+                win = jnp.where(lane + sh < LANES, rolled[a:a + 1],
+                                rolled[a + 1:a + 2])[:, :w]
+                dst = (pl.ds(r, 1), pl.ds(a * LANES, w))
+                if n_seg > 1:
+                    # keep only the lanes whose element falls in block k
+                    seg = (off0_ref[r] + lane[:, :w] + a * LANES) >> log2_z
+                    win = jnp.where(seg == k, win, out_ref[dst])
+                out_ref[dst] = win
+            return c
+
+        jax.lax.fori_loop(0, pairs, issue, 0)
+        jax.lax.fori_loop(0, pairs, drain, 0)
+        return jax.lax.fori_loop(0, pairs, place, c)
+
+    jax.lax.fori_loop(0, n_seg, segment, 0)
 
 
-def _aligned_kernel(spec: RobeSpec, dim: int,
-                    rows_ref, tids_ref, mem_ref, out_ref):
-    tb, f = rows_ref.shape
-    rows = rows_ref[...]
-    table_ids = tids_ref[...]
-    start, _ = _hash_rows(spec, table_ids, rows, dim)      # [TB, F] uint32
-    start = start.astype(jnp.int32)
+def gather_windows(mem_rows: jnp.ndarray, starts: jnp.ndarray,
+                   off0: jnp.ndarray, dim: int, log2_z: int,
+                   interpret: bool) -> jnp.ndarray:
+    """[N, S] window starts into the padded ``[R, 128]`` array -> [N, dim]
+    f32 rows (the Pallas call; ``N`` is padded internally)."""
+    n, n_seg = starts.shape
+    n_rows = window_rows(dim)
+    pairs, n_pad = pair_tile(n)
+    if n_pad != n:           # padded pairs re-read pair 0's windows
+        starts = jnp.concatenate(
+            [starts, jnp.broadcast_to(starts[:1], (n_pad - n, n_seg))])
+        off0 = jnp.concatenate([off0, jnp.broadcast_to(off0[:1],
+                                                       (n_pad - n,))])
+    out = pl.pallas_call(
+        functools.partial(_gather_kernel, n_seg, log2_z, dim, n_rows,
+                          pairs),
+        grid=(n_pad // pairs,),
+        in_specs=[
+            pl.BlockSpec((pairs * n_seg,), lambda i: (i,),
+                         memory_space=pltpu.SMEM),           # window starts
+            pl.BlockSpec((pairs,), lambda i: (i,),
+                         memory_space=pltpu.SMEM),           # block offsets
+            pl.BlockSpec(memory_space=pl.ANY),               # M stays in HBM
+        ],
+        out_specs=pl.BlockSpec((pairs, dim), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, dim), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((pairs, n_rows, LANES), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+        interpret=interpret,
+    )(starts.reshape(-1), off0, mem_rows)
+    return out[:n] if n_pad != n else out
 
-    def body(r, _):
-        bi = r // f
-        fi = r % f
-        s = start[bi, fi]
-        vec = mem_ref[pl.dslice(s, dim)]
-        out_ref[pl.dslice(bi, 1), pl.dslice(fi, 1), :] = vec.reshape(1, 1, dim)
-        return 0
 
-    jax.lax.fori_loop(0, tb * f, body, 0)
+def robe_gather(memory: jnp.ndarray, rows: jnp.ndarray,
+                table_ids: Tuple[int, ...], dim: int, spec: RobeSpec,
+                interpret: bool) -> jnp.ndarray:
+    """[B, F] rows -> [B, F, dim] f32 (signs applied) from any-dtype M."""
+    b, f = rows.shape
+    starts, off0 = window_starts(spec, table_ids, rows, dim)
+    front = starts.shape[1] * spec.block_size
+    mem_rows = _circular_rows(memory, front,
+                              _padded_len(memory.shape[0], dim,
+                                          spec.block_size))
+    out = gather_windows(mem_rows, starts, off0, dim, spec.log2_z,
+                         interpret).reshape(b, f, dim)
     if spec.use_sign:
-        out_ref[...] = (out_ref[...] *
-                        _signs_tile(spec, table_ids, rows, dim
-                                    ).astype(out_ref.dtype))
+        tids = jnp.asarray(table_ids, jnp.uint32)[None, :]
+        out = out * robe_signs(spec, tids, rows, dim)
+    return out
 
 
-def _general_kernel(spec: RobeSpec, dim: int,
-                    rows_ref, tids_ref, mem_ref, out_ref):
-    rows = rows_ref[...]
-    table_ids = tids_ref[...]
-    # per-element slots, identical math to core.robe.robe_slots
-    rows_u = rows.astype(jnp.uint32)[..., None]
-    hi, lo = mul32(rows_u, jnp.uint32(dim))
-    shape = lo.shape[:-1] + (dim,)
-    hi = jnp.broadcast_to(hi, shape)
-    lo = jnp.broadcast_to(lo, shape)
-    i = jnp.broadcast_to(jnp.arange(dim, dtype=jnp.uint32), shape)
-    hi, lo = add64(hi, lo, i)
-    lz = spec.log2_z
-    if lz == 0:
-        b_hi, b_lo = hi, lo
-        off = jnp.zeros_like(lo)
-    else:
-        b_lo = (lo >> lz) | (hi << (32 - lz))
-        b_hi = hi >> lz
-        off = lo & jnp.uint32(spec.block_size - 1)
-    h = spec.hash_fn()
-    t = jnp.broadcast_to(table_ids[None, :, None], shape)
-    slot = h(t, b_hi, b_lo) + off
-    m = jnp.uint32(spec.size)
-    slot = jnp.where(slot >= m, slot - m, slot).astype(jnp.int32)
-    mem = mem_ref[...]
-    out = jnp.take(mem, slot.reshape(-1), axis=0).reshape(shape)
-    if spec.use_sign:
-        sg = _signs_tile(spec, table_ids, rows, dim)
-        out = out * sg.astype(out.dtype)
-    out_ref[...] = out
-
-
-def _q_aligned_kernel(spec: RobeSpec, dim: int, group_log2: int,
-                      out_dtype, rows_ref, tids_ref, mem_ref, scale_ref,
-                      out_ref):
-    """int8 aligned path: one contiguous code slice per (row, field), each
-    element dequantized in-register against its group's f32 scale before it
-    ever leaves the kernel — HBM sees 1 byte per weight, not 4."""
-    tb, f = rows_ref.shape
-    rows = rows_ref[...]
-    table_ids = tids_ref[...]
-    start, _ = _hash_rows(spec, table_ids, rows, dim)      # [TB, F] uint32
-    m = jnp.uint32(spec.size)
-    scale = scale_ref[...].astype(jnp.float32)
-    lane = jnp.arange(dim, dtype=jnp.uint32)
-
-    def body(r, _):
-        bi = r // f
-        fi = r % f
-        s = start[bi, fi]
-        vec = mem_ref[pl.dslice(s.astype(jnp.int32), dim)]  # int8 [dim]
-        # group index from the WRAPPED slot: the padded code array absorbs
-        # the circular wrap for the gather, but scale groups are defined on
-        # canonical slots in [0, |M|)
-        slot = (s + lane) % m
-        sv = jnp.take(scale, (slot >> group_log2).astype(jnp.int32), axis=0)
-        deq = vec.astype(jnp.float32) * sv
-        out_ref[pl.dslice(bi, 1), pl.dslice(fi, 1), :] = \
-            deq.astype(out_dtype).reshape(1, 1, dim)
-        return 0
-
-    jax.lax.fori_loop(0, tb * f, body, 0)
-    if spec.use_sign:
-        out_ref[...] = (out_ref[...] *
-                        _signs_tile(spec, table_ids, rows, dim
-                                    ).astype(out_dtype))
-
-
-def _q_general_kernel(spec: RobeSpec, dim: int, group_log2: int,
-                      out_dtype, rows_ref, tids_ref, mem_ref, scale_ref,
-                      out_ref):
-    """int8 general path (any Z): per-element slots, int8 gather, in-kernel
-    group-scale dequant.  Same slot math as ``_general_kernel``."""
-    rows = rows_ref[...]
-    table_ids = tids_ref[...]
-    rows_u = rows.astype(jnp.uint32)[..., None]
-    hi, lo = mul32(rows_u, jnp.uint32(dim))
-    shape = lo.shape[:-1] + (dim,)
-    hi = jnp.broadcast_to(hi, shape)
-    lo = jnp.broadcast_to(lo, shape)
-    i = jnp.broadcast_to(jnp.arange(dim, dtype=jnp.uint32), shape)
-    hi, lo = add64(hi, lo, i)
-    lz = spec.log2_z
-    if lz == 0:
-        b_hi, b_lo = hi, lo
-        off = jnp.zeros_like(lo)
-    else:
-        b_lo = (lo >> lz) | (hi << (32 - lz))
-        b_hi = hi >> lz
-        off = lo & jnp.uint32(spec.block_size - 1)
-    h = spec.hash_fn()
-    t = jnp.broadcast_to(table_ids[None, :, None], shape)
-    slot = h(t, b_hi, b_lo) + off
-    m = jnp.uint32(spec.size)
-    slot = jnp.where(slot >= m, slot - m, slot)
-    flat = slot.reshape(-1).astype(jnp.int32)
-    c = jnp.take(mem_ref[...], flat, axis=0).astype(jnp.float32)
-    sv = jnp.take(scale_ref[...].astype(jnp.float32),
-                  (slot.reshape(-1) >> group_log2).astype(jnp.int32), axis=0)
-    out = (c * sv).reshape(shape)
-    if spec.use_sign:
-        out = out * _signs_tile(spec, table_ids, rows, dim)
-    out_ref[...] = out.astype(out_dtype)
+@functools.partial(jax.jit, static_argnames=("spec", "dim", "table_ids",
+                                             "interpret"))
+def robe_lookup_pallas(memory: jnp.ndarray, rows: jnp.ndarray,
+                       table_ids: Tuple[int, ...], dim: int, spec: RobeSpec,
+                       interpret: bool = True) -> jnp.ndarray:
+    """Fused ROBE lookup: [B, F] int rows -> [B, F, dim] in memory.dtype."""
+    return robe_gather(memory, rows, table_ids, dim, spec,
+                       interpret).astype(memory.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "dim", "table_ids",
@@ -215,85 +238,17 @@ def qrobe_lookup_pallas(codes: jnp.ndarray, scale: jnp.ndarray,
                         rows: jnp.ndarray, table_ids: Tuple[int, ...],
                         dim: int, spec: RobeSpec, group_log2: int,
                         interpret: bool = True) -> jnp.ndarray:
-    """Fused int8 ROBE lookup with in-kernel dequantization.
+    """int8 ROBE lookup: the same DMA gather over the dequantized array.
 
     codes: [|M|] int8; scale: [ceil(|M| / 2**group_log2)] learned per-group
-    scales.  Same grid/tiling policy as ``robe_lookup_pallas``; the output
-    is delivered in ``scale.dtype`` under the single-rounding contract of
-    ``repro.kernels.ref.qrobe_lookup_ref``.
+    scales.  Slot ``s`` dequantizes in f32 as ``codes[s] ·
+    scale[s >> group_log2]`` before the gather, so the result is
+    bit-identical to ``repro.kernels.ref.qrobe_lookup_ref`` (one rounding,
+    into ``scale.dtype``).  The dequantized copy is 4 B per slot: this
+    path does not yet read int8 from HBM (ROADMAP queue 1).
     """
-    b, f = rows.shape
-    aligned = (spec.block_size % dim == 0)
-    tb = pick_batch_tile(b, f, dim)
-    b_pad = round_up(b, tb)
-    rows = pad_batch(rows, b_pad)
-    grid = (b_pad // tb,)
-    out_dtype = scale.dtype
-
-    if aligned:
-        pad = spec.block_size + dim
-        mem_in = jnp.concatenate([codes, codes[:pad]])
-        body = functools.partial(_q_aligned_kernel, spec, dim, group_log2,
-                                 out_dtype)
-    else:
-        mem_in = codes
-        body = functools.partial(_q_general_kernel, spec, dim, group_log2,
-                                 out_dtype)
-
-    tids = jnp.asarray(table_ids, dtype=jnp.uint32)
-    out = pl.pallas_call(
-        body,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tb, f), lambda i: (i, 0)),             # row ids
-            pl.BlockSpec((f,), lambda i: (0,)),                  # table ids
-            pl.BlockSpec((mem_in.shape[0],), lambda i: (0,)),    # int8 codes
-            pl.BlockSpec((scale.shape[0],), lambda i: (0,)),     # scales
-        ],
-        out_specs=pl.BlockSpec((tb, f, dim), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b_pad, f, dim), out_dtype),
-        interpret=interpret,
-    )(rows, tids, mem_in, scale)
-    return out[:b] if b_pad != b else out
-
-
-@functools.partial(jax.jit, static_argnames=("spec", "dim", "table_ids",
-                                             "interpret"))
-def robe_lookup_pallas(memory: jnp.ndarray, rows: jnp.ndarray,
-                       table_ids: Tuple[int, ...], dim: int, spec: RobeSpec,
-                       interpret: bool = True) -> jnp.ndarray:
-    """Fused ROBE lookup: [B, F] int rows -> [B, F, dim] embeddings.
-
-    memory: [|M|] array; padded internally by one block + row so the aligned
-    kernel's dynamic slices never wrap (circular-array semantics preserved).
-    """
-    b, f = rows.shape
-    aligned = (spec.block_size % dim == 0)
-    tb = pick_batch_tile(b, f, dim)
-    b_pad = round_up(b, tb)
-    # pad with row 0 (any valid id) and slice the output back below
-    rows = pad_batch(rows, b_pad)
-    grid = (b_pad // tb,)
-
-    if aligned:
-        pad = spec.block_size + dim
-        mem_in = jnp.concatenate([memory, memory[:pad]])
-        body = functools.partial(_aligned_kernel, spec, dim)
-    else:
-        mem_in = memory
-        body = functools.partial(_general_kernel, spec, dim)
-
-    tids = jnp.asarray(table_ids, dtype=jnp.uint32)
-    out = pl.pallas_call(
-        body,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tb, f), lambda i: (i, 0)),            # row ids
-            pl.BlockSpec((f,), lambda i: (0,)),                 # table ids
-            pl.BlockSpec((mem_in.shape[0],), lambda i: (0,)),   # M in VMEM
-        ],
-        out_specs=pl.BlockSpec((tb, f, dim), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b_pad, f, dim), memory.dtype),
-        interpret=interpret,
-    )(rows, tids, mem_in)
-    return out[:b] if b_pad != b else out
+    m = codes.shape[0]
+    group = jnp.repeat(scale.astype(jnp.float32), 1 << group_log2)[:m]
+    deq = codes.astype(jnp.float32) * group
+    return robe_gather(deq, rows, table_ids, dim, spec,
+                       interpret).astype(scale.dtype)

@@ -18,7 +18,10 @@ larger of the output row and the gathered core slices per element so the
 working set stays inside the VMEM budget.
 
 Validated in interpret mode against ``repro.kernels.ref.tt_lookup_ref``
-(tests/test_kernel_conformance.py sweeps dtype/shape/bag regimes).
+(tests/test_kernel_conformance.py sweeps dtype/shape/bag regimes).  The
+TPU compiler refuses it ("Only 2D gather is supported": the in-kernel
+``jnp.take`` of core slices), so ``kernels/ops.tt_lookup`` raises on a
+TPU (``REFUSED_ON_TPU``; ROADMAP queue 1).
 """
 
 from __future__ import annotations

@@ -261,9 +261,6 @@ def build_recsys_cell(arch_id: str, shape_name: str, ctx,
         if cfg.arch == "two_tower":
             fn = lambda params, batch: R.tower_vectors(params, cfg, batch)
         else:
-            # serve_scores marks the inference hot path (serve=True), so a
-            # backend with a fused serve super-kernel (robe + use_kernel)
-            # scores in one Pallas pass per batch tile
             fn = lambda params, batch: R.serve_scores(params, cfg, batch)
         flops = 2.0 * dense_params * b
         return BuiltCell(cell_id, fn, (pshapes, bshape),

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell:  jax.jit(fn, in_shardings=…).lower(*ShapeDtypeStructs)
@@ -8,7 +5,9 @@ For each cell:  jax.jit(fn, in_shardings=…).lower(*ShapeDtypeStructs)
 schedule parsed from the post-SPMD HLO.  No arrays are ever allocated.
 
 Results cache to results/dryrun/<cell>.json so the sweep is resumable; the
-roofline report (launch/roofline.py) reads these JSONs.
+roofline report (launch/roofline.py) reads these JSONs.  Run as a script,
+it forces 512 host devices (XLA_FLAGS, before jax starts) and exits
+non-zero when any cell failed; importing the module changes nothing.
 
 Usage:
   python -m repro.launch.dryrun                       # all cells, both meshes
@@ -18,7 +17,9 @@ Usage:
 
 import argparse
 import json
+import os
 import re
+import sys
 import time
 import traceback
 
@@ -63,12 +64,8 @@ def _shape_bytes(shape_str: str) -> int:
 
 
 def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() as one dict (jax<0.5 returns a per-module
-    list; newer jax returns the dict directly)."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """compiled.cost_analysis(), or {} when the backend reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def parse_collectives(hlo_text: str) -> dict:
@@ -170,7 +167,7 @@ def default_cells():
     return cells
 
 
-def main():
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)
@@ -193,10 +190,18 @@ def main():
     meshes = {"single": [False], "multi": [True],
               "both": [False, True]}[args.mesh]
 
+    if not cells:
+        print(f"no cells match --arch {args.arch} --shape {args.shape}",
+              file=sys.stderr)
+        return 2
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
+    failed = 0
     for arch, shape, emb in cells:
         for mp in meshes:
             rec = run_cell(arch, shape, mp, emb, force=args.force,
                            save_hlo=args.save_hlo)
+            failed += not rec.get("ok")
             status = ("SKIP " + rec.get("skipped", "")[:40]) if \
                 rec.get("skipped") else \
                 ("OK" if rec.get("ok") else "FAIL " + rec.get("error",
@@ -204,7 +209,12 @@ def main():
             mesh_name = "multi" if mp else "single"
             print(f"[{mesh_name:6s}] {arch}/{shape}[{emb}]: {status} "
                   f"({rec.get('wall_s', 0)}s)", flush=True)
+    if failed:
+        print(f"{failed} cell(s) failed; see results/dryrun/*.json",
+              file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    sys.exit(main())

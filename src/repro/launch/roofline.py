@@ -1,7 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=512")
-
 """Roofline analysis from the dry-run artifacts (EXPERIMENTS.md §Roofline).
 
 Terms (per device; the HLO module IS the per-device SPMD program):
@@ -14,10 +10,14 @@ for scan-over-layers LMs we compile two shallow probes (same width, L=k and
 L=k+1) and extrapolate:  total = probe(k) + (L_full − k)·Δ, where
 Δ = probe(k+1) − probe(k).  The same correction applies to the parsed
 collective bytes (the body's collectives also appear once).
+
+Run as a script it forces 512 host devices (XLA_FLAGS, before jax
+starts) for its probes; importing the module changes nothing.
 """
 
 import argparse
 import json
+import os
 from typing import Optional
 
 PEAK_FLOPS = 197e12          # bf16 / chip
@@ -222,4 +222,8 @@ def main():
 
 
 if __name__ == "__main__":
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     main()

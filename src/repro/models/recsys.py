@@ -159,44 +159,28 @@ def _batch_emb(params, cfg: RecsysConfig, batch: dict) -> jnp.ndarray:
 
 
 def _dlrm_interaction(params, cfg: RecsysConfig, batch: dict,
-                      bot: jnp.ndarray, serve: bool) -> jnp.ndarray:
-    """[B, (F+1)·F/2] dot-interaction triangle of [bot; field embeddings].
-
-    On the serve path with ``use_kernel`` set, a backend that offers the
-    optional ``fused_serve`` protocol method (robe) computes the whole
-    lookup → bag-pool → gram chain in one Pallas pass — no [B, F, D]
-    intermediate in HBM.  Everywhere else (training, substrates without a
-    super-kernel, ZeRO-3 placement): the unfused lookup + dot_interaction.
-    """
-    if serve and cfg.use_kernel and "emb" not in batch:
-        spec = cfg.embedding_spec()
-        backend = get_backend(spec.kind)
-        if backend.fused_serve is not None:
-            inter = backend.fused_serve(params["embedding"], spec,
-                                        batch["sparse"], bot)
-            if inter is not None:
-                return inter
+                      bot: jnp.ndarray) -> jnp.ndarray:
+    """[B, (F+1)·F/2] dot-interaction triangle of [bot; field embeddings]
+    (with ``use_kernel``: the substrate's lookup kernel, then the
+    dot-interaction kernel)."""
     emb = _batch_emb(params, cfg, batch)
     feats = jnp.concatenate([bot[:, None, :], emb], axis=1)
     return dot_interaction_op(feats, use_kernel=cfg.use_kernel)
 
 
-def forward(params, cfg: RecsysConfig, batch: dict,
-            serve: bool = False) -> jnp.ndarray:
+def forward(params, cfg: RecsysConfig, batch: dict) -> jnp.ndarray:
     """batch: {"dense": [B,n_dense], "sparse": [B,F]} -> logits [B].
 
-    ``serve`` marks the inference hot path: forward-only fast paths (the
-    fused serve super-kernel) may engage; training always takes the
-    general path.  A batch may carry precomputed ``"emb"`` [B, F, dim]
-    instead of (or alongside) ``"sparse"`` — the serving tier's hot-row
-    cache path (``serve/hot_cache.py``); it takes precedence over both
-    the substrate lookup and the fused serve kernel.
+    A batch may carry precomputed ``"emb"`` [B, F, dim] instead of (or
+    alongside) ``"sparse"`` — the serving tier's hot-row cache path
+    (``serve/hot_cache.py``); it takes precedence over the substrate
+    lookup.
     """
     a = cfg.arch
     if a == "dlrm":
         dense = batch["dense"].astype(cfg.compute_dtype)
         bot = mlp_apply(params["bot"], dense, final_act=jax.nn.relu)
-        inter = _dlrm_interaction(params, cfg, batch, bot, serve)
+        inter = _dlrm_interaction(params, cfg, batch, bot)
         top_in = jnp.concatenate([bot, inter], axis=-1)
         return mlp_apply(params["top"], top_in)[:, 0]
     emb = _batch_emb(params, cfg, batch)             # [B,F,D]
@@ -297,4 +281,4 @@ def serve_scores(params, cfg: RecsysConfig, batch: dict) -> jnp.ndarray:
                        cand.astype(cfg.compute_dtype).reshape(n, -1))
         vi = vi / jnp.linalg.norm(vi, axis=-1, keepdims=True).clip(1e-6)
         return (u @ vi.T)                        # [B, n_candidates]
-    return forward(params, cfg, batch, serve=True)
+    return forward(params, cfg, batch)

@@ -50,13 +50,6 @@ class EmbeddingBackend:
     #: lookups are device-local (no model-axis embedding exchange) — the
     #: batch may shard over every mesh axis (the "flat_batch" rule)
     local_batch: bool = True
-    #: optional serve fast path: a backend that can fuse lookup → bag
-    #: pooling → dot interaction into one kernel pass overrides this with a
-    #: method ``fused_serve(params, spec, idx, bot) -> [B, (F+1)·F/2]`` (or
-    #: returning None when the current placement can't fuse); ``None`` here
-    #: means "no fused serve path" and consumers fall back to the unfused
-    #: lookup → concat → dot_interaction ops (models/recsys.py score path)
-    fused_serve = None
     #: optional serving-tier hot-row-cache hook: a fetch-bound backend
     #: overrides this with a method ``cacheable_rows(params, spec, field,
     #: ids) -> [n, dim]`` float32 host rows that are BIT-IDENTICAL to what

@@ -70,9 +70,12 @@ class FullTableBackend(EmbeddingBackend):
                        ids: np.ndarray) -> np.ndarray:
         """Hot-row-cache hook: the exact rows ``lookup`` would gather for
         ``ids`` in ``field`` — a host-side copy of the same f32 bits, so a
-        cached serve score is bit-exact against the device gather."""
-        table = np.asarray(params["table"])
-        return table[np.asarray(ids, np.int64) + int(spec.offsets[field])]
+        cached serve score is bit-exact against the device gather.  Only
+        the requested rows cross to the host: the table (52 GB at
+        CriteoTB d=64, possibly sharded) is gathered where it lives."""
+        rows = np.asarray(ids, np.int64) + int(spec.offsets[field])
+        return np.asarray(jnp.take(params["table"],
+                                   jnp.asarray(rows, jnp.int32), axis=0))
 
     def lookup_dist(self, params, spec, idx, *, compute_dtype=None):
         from repro.dist import api as dist

@@ -14,9 +14,8 @@ replicated — the substrate is small by construction, so lookups are local
 and batches shard over the whole mesh, same serving story as ROBE.
 
 Lookups go through the fused ``kernels/ops.qr_lookup`` op: with
-``spec.use_kernel`` the quotient/remainder index math, both VMEM-resident
-table gathers, and the product run in one Pallas pass
-(``kernels/qr_lookup.py``); otherwise the same math runs as the jnp
+``spec.use_kernel`` both row gathers (DMA from the tables in HBM) and the
+product run in one Pallas pass (``kernels/qr_lookup.py``); otherwise the same math runs as the jnp
 reference path.
 
 ``m`` defaults to the power of two nearest √(max vocab), the

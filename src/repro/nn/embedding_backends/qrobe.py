@@ -6,9 +6,12 @@ bytes-per-weight.  This substrate stores the shared circular array as int8
 codes plus one learned f32 scale per ``GROUP_SIZE``-slot group, ALPT-style:
 
 * **forward** — ``repro.kernels.ops.qrobe_lookup`` gathers int8 codes
-  through the unchanged ROBE hash and dequantizes INSIDE the Pallas kernel
+  through the unchanged ROBE hash and dequantizes them
   (``codes_f32 · scale_f32[slot >> GROUP_LOG2] · sign``, one rounding on
-  delivery into ``scale.dtype``), so the lookup's HBM traffic drops ~4×.
+  delivery into ``scale.dtype``).  The jnp path gathers the int8 codes
+  themselves, so its row reads are ~4× fewer bytes; the Pallas path
+  dequantizes the whole array to f32 before its DMA gather and saves
+  nothing yet (see ``cost``).
 * **scale training** — the scales are ordinary float leaves; the op's
   custom_vjp delivers their analytic gradient, so quantization is learned,
   not calibrated.
@@ -26,8 +29,8 @@ codes plus one learned f32 scale per ``GROUP_SIZE``-slot group, ALPT-style:
 This is the first backend whose stored parameters are not what the math
 sees, which is why the :class:`EmbeddingBackend` protocol grew the
 ``project`` hook — the groundwork for the DPQ / int4 entries of the same
-ROADMAP item.  ``fused_serve`` and ``cacheable_rows`` are declined for now
-(the serve super-kernel and the hot-row cache speak f32 memories).
+ROADMAP item.  ``cacheable_rows`` is declined for now (the hot-row cache
+speaks f32 memories).
 
 Optimizer note: a scale's analytic gradient sums ``g · codes`` over its
 group — code magnitudes reach ±127, so it runs ~two orders larger than
@@ -84,7 +87,6 @@ def quantize_array(w: jnp.ndarray, scale: jnp.ndarray):
 class QRobeBackend(EmbeddingBackend):
     name = "qrobe"
     local_batch = True           # replicated codes+scales, purely local
-    fused_serve = None           # declined: serve_fused speaks f32 memories
     cacheable_rows = None        # declined, as robe: the array IS the cache
 
     def validate(self, spec) -> None:
@@ -153,18 +155,26 @@ class QRobeBackend(EmbeddingBackend):
         return spec.robe.size + n_groups(spec.robe.size)
 
     def cost(self, spec, batch: int, bus: int = 16) -> dict:
-        # same coalesced-fetch bound as robe, at 1 byte/element instead of
-        # 4, plus ~one f32 scale line per row — the ~4× serve-bytes claim
-        z = spec.robe.block_size
-        fetches = analytic_max_fetches(spec.dim, z, bus)
-        flops = 10 * batch * spec.n_fields * spec.dim
-        flops += batch * spec.n_fields * spec.dim      # the dequant multiply
+        """jnp path: robe's coalesced-fetch model at 1 byte/element instead
+        of 4, plus ~one f32 scale line per row — the ~4× serve-bytes claim.
+        With ``use_kernel``: the Pallas path's bytes — the whole array
+        dequantized to f32 (codes and scales read, f32 written), then
+        robe's DMA gather over that f32 copy."""
+        from repro.kernels.robe_lookup import gather_bytes
+        z, m = spec.robe.block_size, spec.robe.size
+        pairs = batch * spec.n_fields
+        if spec.use_kernel:
+            fetched = (m + n_groups(m) * 4 + m * 4
+                       + gather_bytes(m, spec.dim, z, pairs))
+        else:
+            fetched = int(pairs * (analytic_max_fetches(spec.dim, z, bus)
+                                   * bus * 1 + 4))
+        flops = 10 * pairs * spec.dim
+        flops += pairs * spec.dim                      # the dequant multiply
         if spec.robe.use_sign:
-            flops += batch * spec.n_fields * spec.dim
+            flops += pairs * spec.dim
         return {"params": self.param_count(spec),
-                "bytes_fetched": int(batch * spec.n_fields
-                                     * (fetches * bus * 1 + 4)),
-                "flops": flops}
+                "bytes_fetched": fetched, "flops": flops}
 
 
 register_backend(QRobeBackend())

@@ -70,29 +70,6 @@ class RobeBackend(EmbeddingBackend):
         return robe_lookup(params["memory"], idx, tuple(fields), spec.dim,
                            spec.robe, spec.use_kernel)
 
-    def fused_serve(self, params, spec, idx, bot):
-        """One-pass serve super-kernel: multi-field lookup → bag pooling →
-        dot-interaction gram in a single Pallas pass (``kernels.ops.
-        serve_fused``) — the ROBE array is read once per batch tile and no
-        [B, F, D] intermediate touches HBM.
-
-        idx [B, F] (or [B, F, bag], −1-padded), bot [B, dim] dense bottom-
-        MLP output -> [B, (F+1)·F/2] interaction triangle in bot's dtype.
-        Returns None under the ZeRO-3 placement (the array is sharded over
-        ``model``; callers fall back to the gather-per-step lookup path).
-        """
-        if spec.placement == "model":
-            return None
-        from repro.dist import api as dist
-        from repro.kernels.ops import serve_fused
-        fields = tuple(range(spec.n_fields))
-        out = serve_fused(params["memory"], idx, bot, fields, spec.dim,
-                          spec.robe, spec.use_kernel)
-        ctx = dist.current()
-        if ctx is not None and idx.shape[0] % ctx.n_devices == 0:
-            out = dist.shard(out, "flat_batch", None)
-        return out
-
     def lookup_dist(self, params, spec, idx, *, compute_dtype=None):
         from repro.dist import api as dist
         ctx = dist.current()
@@ -115,6 +92,12 @@ class RobeBackend(EmbeddingBackend):
         def body(mem_shard, ix):
             from repro.kernels.ops import robe_lookup
             full = robe_allgather_body(mem_shard, "model")
+            # the gathered array is the same on every batch shard; marking
+            # it varying there makes its cotangent (which the custom_vjp
+            # computes per shard) psum over the batch axes on transpose
+            dp_axes = axes_tuple(dp)
+            if dp_axes:
+                full = jax.lax.pcast(full, dp_axes, to="varying")
             return robe_lookup(full, ix, fields, spec.dim, spec.robe,
                                spec.use_kernel)
 
@@ -138,18 +121,27 @@ class RobeBackend(EmbeddingBackend):
         return spec.robe.size
 
     def cost(self, spec, batch: int, bus: int = 16) -> dict:
-        # block-coalesced reads: ≤ analytic_max_fetches bus lines per row
-        # (paper Table 1); hashing is ~10 int ops per element, plus the
-        # optional sign multiply
+        """``bytes_fetched`` models the paper's access pattern on the jnp
+        path — block-coalesced reads, ≤ ``analytic_max_fetches`` bus lines
+        per row (Table 1), an ideal reader and not a measurement of XLA's
+        gather — and, with ``use_kernel``, counts what the Pallas DMA
+        gather moves (``kernels.robe_lookup.gather_bytes``: the per-call
+        padded f32 copy of the array plus 128-lane windows per row).
+        Hashing is ~10 int ops per element, plus the optional sign
+        multiply."""
+        from repro.kernels.robe_lookup import gather_bytes
         z = spec.robe.block_size
-        fetches = analytic_max_fetches(spec.dim, z, bus)
-        flops = 10 * batch * spec.n_fields * spec.dim
+        pairs = batch * spec.n_fields
+        if spec.use_kernel:
+            fetched = gather_bytes(spec.robe.size, spec.dim, z, pairs)
+        else:
+            fetched = int(pairs * analytic_max_fetches(spec.dim, z, bus)
+                          * bus * 4)
+        flops = 10 * pairs * spec.dim
         if spec.robe.use_sign:
-            flops += batch * spec.n_fields * spec.dim
+            flops += pairs * spec.dim
         return {"params": self.param_count(spec),
-                "bytes_fetched": int(batch * spec.n_fields * fetches
-                                     * bus * 4),
-                "flops": flops}
+                "bytes_fetched": fetched, "flops": flops}
 
 
 register_backend(RobeBackend())

@@ -17,8 +17,9 @@ einsums, batches shard over the whole mesh, same serving story as ROBE.
 Lookups go through the fused ``kernels/ops.tt_lookup`` op: with
 ``spec.use_kernel`` the mixed-radix index decomposition, the three
 VMEM-resident core gathers, and the chain contraction run in one Pallas
-pass (``kernels/tt_lookup.py``); otherwise the same math runs as the jnp
-reference path.
+pass (``kernels/tt_lookup.py``; interpret mode only — the TPU compiler
+refuses its in-kernel gathers, so it raises on a TPU); otherwise the same
+math runs as the jnp reference path.
 """
 
 from __future__ import annotations

@@ -17,9 +17,9 @@ of that idea:
   rows the sketch says are hottest.
 
 Which substrates opt in is the backends' call via the optional
-``cacheable_rows`` protocol hook (class attribute ``None`` on the base,
-like ``fused_serve``): ``full`` and ``hashed`` implement it — they are
-fetch-bound, their tables dwarf any cache level, and fronting them with a
+``cacheable_rows`` protocol hook (class attribute ``None`` on the base):
+``full`` and ``hashed`` implement it — they are fetch-bound, their tables
+dwarf any cache level, and fronting them with a
 hot-row store is how production DLRM serves a 100GB table.  ``robe``
 declines: the whole array is already cache-resident, which is the paper's
 entire point — declining keeps the full-vs-robe serving comparison honest

@@ -4,9 +4,8 @@ One ``EmbeddingServer`` holds a DLRM scoring model per registered
 embedding substrate (full / robe / hashed / tt) resident on the same mesh
 — the same trained architecture, four interchangeable embedding layouts —
 and routes each request to its substrate through one jitted
-``serve_scores`` per backend (the fused ``serve_fused`` super-kernel path
-when ``use_kernel`` and the backend offers it; see
-``models/recsys._dlrm_interaction``).
+``serve_scores`` per backend (through the Pallas lookup and
+dot-interaction kernels when ``use_kernel``).
 
 The fetch-bound substrates (``full``/``hashed``) are optionally fronted
 by a ``HotRowCache``: the server gathers their hot rows on the host
@@ -21,7 +20,7 @@ rows (the router/``stack_and_pad`` contract): the scorer returns only the
 real rows, and the cache never counts the padded tail.
 
 Under an active ``repro.dist`` context the jitted scorers pick up the
-mesh through each backend's own ``lookup_dist``/``fused_serve`` bodies —
+mesh through each backend's own ``lookup_dist`` bodies —
 the server adds no placement logic of its own.
 """
 
@@ -52,8 +51,8 @@ class ServerConfig:
     ``robe_compression`` sizes the ROBE array at 1/compression of the full
     table's parameters (the paper's 1000× knob, scaled to taste);
     ``cache_capacity`` rows per cacheable substrate (0 disables the hot
-    cache); ``use_kernel`` routes robe serving through the one-pass
-    ``serve_fused`` super-kernel (interpret mode off-TPU — slow but
+    cache); ``use_kernel`` routes serving through the Pallas lookup
+    and dot-interaction kernels (interpret mode off-TPU — slow but
     conformant, so benchmarks default it off on CPU).
     """
 

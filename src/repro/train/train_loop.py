@@ -20,6 +20,7 @@ and continues at the same global step on the surviving devices.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 from typing import Callable, Optional
 
@@ -31,6 +32,8 @@ from repro.dist import api as dist
 from repro.train import checkpoint as ckpt_lib
 from repro.train.compression import compressed_psum
 from repro.train.optimizer import Optimizer
+
+log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass
@@ -284,12 +287,13 @@ def run(state, step_fn: Callable, batch_at: Callable[[int], dict],
                 # here, monotonically
                 state, step_fn = reslice_fn(state, step)
                 reslices += 1
-        except KeyboardInterrupt:
-            raise
-        except BaseException:
+        except Exception:
             restarts += 1
             if restarts > cfg.max_restarts:
                 raise
+            # a restart must never hide its cause
+            log.warning("train step %d failed; restart %d of %d", step,
+                        restarts, cfg.max_restarts, exc_info=True)
             if ckpt_dir:
                 if saver:
                     try:

@@ -1,17 +1,10 @@
 """Shared test bootstrap.
 
-Two jobs, both of which must happen before any test module imports jax:
-
-1. Force 8 XLA host devices so the mesh-based tests (test_distributed.py
-   and any in-process mesh construction) can run on CPU CI.  ``setdefault``
-   keeps an operator's explicit XLA_FLAGS intact; the test_distributed
-   subprocesses overwrite the flag themselves, so they are unaffected.
-
-2. Gate the optional ``hypothesis`` dependency.  The CI container does not
-   ship it and nothing may be pip-installed, so when the import fails we
-   install a minimal, deterministic stand-in (seeded random sampling over
-   the same strategy surface: integers / booleans / lists / sampled_from).
-   With real hypothesis present the stub is never built.
+Forces 8 XLA host devices before any test module imports jax, so the
+mesh-based tests (test_distributed.py and any in-process mesh
+construction) run on CPU.  ``setdefault`` keeps an operator's explicit
+XLA_FLAGS intact; the test_distributed subprocesses overwrite the flag
+themselves, so they are unaffected.
 """
 
 import os
@@ -36,76 +29,10 @@ def run_forced_subprocess(body: str, n_devices: int = 8) -> str:
         import functools, shutil, tempfile
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=os.path.join(_ROOT, "src"))
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=600)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
     return r.stdout
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import random
-    import sys
-    import types
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def draw(self, rng):
-            return self._draw(rng)
-
-    def _integers(min_value=0, max_value=1 << 30):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def _booleans():
-        return _Strategy(lambda rng: rng.random() < 0.5)
-
-    def _sampled_from(seq):
-        seq = list(seq)
-        return _Strategy(lambda rng: seq[rng.randrange(len(seq))])
-
-    def _lists(elems, min_size=0, max_size=8):
-        def draw(rng):
-            n = rng.randint(min_size, max_size)
-            return [elems.draw(rng) for _ in range(n)]
-        return _Strategy(draw)
-
-    def _settings(max_examples=20, deadline=None, **_kw):
-        def deco(fn):
-            fn._stub_max_examples = max_examples
-            return fn
-        return deco
-
-    def _given(*arg_strats, **kw_strats):
-        def deco(fn):
-            # No functools.wraps: pytest would follow __wrapped__ to the
-            # original signature and treat the strategy params as fixtures.
-            def wrapper():
-                n = getattr(wrapper, "_stub_max_examples", 20)
-                rng = random.Random(fn.__qualname__)
-                for _ in range(n):
-                    drawn = [s.draw(rng) for s in arg_strats]
-                    drawn_kw = {k: s.draw(rng) for k, s in kw_strats.items()}
-                    fn(*drawn, **drawn_kw)
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            return wrapper
-        return deco
-
-    strategies = types.ModuleType("hypothesis.strategies")
-    strategies.integers = _integers
-    strategies.booleans = _booleans
-    strategies.sampled_from = _sampled_from
-    strategies.lists = _lists
-
-    hypothesis = types.ModuleType("hypothesis")
-    hypothesis.given = _given
-    hypothesis.settings = _settings
-    hypothesis.strategies = strategies
-    hypothesis.__stub__ = True
-
-    sys.modules["hypothesis"] = hypothesis
-    sys.modules["hypothesis.strategies"] = strategies

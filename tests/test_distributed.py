@@ -17,7 +17,7 @@ def test_moe_ep_matches_dense_oracle():
         from repro.dist import api as dist
         from repro.nn.moe import (MoeConfig, moe_init, moe_apply_dense,
                                   moe_apply_ep, moe_param_specs)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = dist.default_rules()
         cfg = MoeConfig(d_model=16, d_ff=32, n_experts=8, top_k=2,
                         n_shared=1, capacity_factor=8.0, dispatch="ep")
@@ -46,7 +46,7 @@ def test_full_embedding_sharded_lookup_matches_local():
         from repro.nn.embeddings import (EmbeddingSpec, embedding_init,
                                          embedding_lookup,
                                          full_lookup_sharded_body)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         spec = EmbeddingSpec(vocab_sizes=(40, 24, 64), dim=8, kind="full")
         params = embedding_init(jax.random.PRNGKey(0), spec, pad_rows_to=8)
         idx = jax.random.randint(jax.random.PRNGKey(1), (16, 3), 0, 24)
@@ -71,7 +71,7 @@ def test_full_embedding_sharded_lookup_matches_local():
 def test_grad_compression_error_feedback():
     _run("""
         from repro.train.compression import compressed_psum
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         g = {"w": jax.random.normal(jax.random.PRNGKey(0), (8, 64)) * 1e-3}
         res = {"w": jnp.zeros((8, 64))}
 
@@ -118,7 +118,7 @@ def test_recsys_dlrm_distributed_matches_single_device():
                  "sparse": jnp.asarray(rs.randint(0, 30, (16, 3)), jnp.int32),
                  "label": jnp.asarray(rs.randint(0, 2, (16,)), jnp.int32)}
         l_local, _ = loss_fn(params, cfg, batch)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
         with dist.use(ctx):
             l_dist, _ = jax.jit(lambda p, b: loss_fn(p, cfg, b))(params,
@@ -141,7 +141,7 @@ def test_lm_distributed_matches_single_device():
         toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
         batch = {"tokens": toks, "labels": toks}
         l_local, _ = loss_fn(p, cfg, batch)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
         with dist.use(ctx):
             l_dist, _ = jax.jit(lambda pp, b: loss_fn(pp, cfg, b))(p, batch)
@@ -163,7 +163,7 @@ def test_lm_decode_seq_sharded_cache_matches():
         p = init_params(jax.random.PRNGKey(0), cfg)
         toks = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, 64)
         full, _ = forward(p, cfg, toks)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
         from jax.sharding import NamedSharding
         cache = init_cache(cfg, 2, 8)
@@ -202,7 +202,7 @@ def test_gnn_edge_parallel_matches_single_device():
                  "edges": jnp.asarray(edges, jnp.int32),
                  "labels": jnp.zeros((1, n), jnp.int32)}
         o_local = forward(params, cfg, batch)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
         with dist.use(ctx):
             o_dist = jax.jit(lambda p, b: forward(p, cfg, b))(params, batch)
@@ -226,7 +226,7 @@ def test_recsys_2d_table_sharding_matches_local():
                  "sparse": jnp.asarray(rs.randint(0, 30, (16, 3)), jnp.int32),
                  "label": jnp.asarray(rs.randint(0, 2, (16,)), jnp.int32)}
         l_local, _ = loss_fn(params, cfg1, batch)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
         with dist.use(ctx):
             l2d, _ = jax.jit(lambda p, b: loss_fn(p, cfg2, b))(params, batch)
@@ -263,7 +263,7 @@ def test_robe_model_sharded_matches_replicated():
                  "label": jnp.asarray(rs.randint(0, 2, (16,)), jnp.int32)}
         l_rep, _ = loss_fn(params, cfg_rep, batch)
         g_rep = jax.grad(lambda p: loss_fn(p, cfg_rep, batch)[0])(params)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
         spec = cfg_z3.embedding_spec()
         pshapes = jax.eval_shape(
@@ -296,7 +296,7 @@ def test_recsys_cells_compile_every_backend():
     _run("""
         from repro.dist import api as dist
         from repro.launch.cells import build_recsys_cell
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
         for emb in ("full", "robe", "hashed", "tt"):
             with dist.use(ctx):
@@ -332,7 +332,7 @@ def test_lm_embed_shard_map_lookup_matches_local():
         p = init_params(jax.random.PRNGKey(0), cfg)
         toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 4096)
         l_local, _ = forward(p, cfg, toks)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
         with dist.use(ctx):
             l_dist, _ = jax.jit(lambda pp, t: forward(pp, cfg, t))(p, toks)

@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 
 from repro.data.synthetic_ctr import CtrDataConfig, CtrStream
+from repro.launch.mesh import make_mesh
 from repro.train import checkpoint as ck
 from repro.train import elastic
 from repro.train.optimizer import OptimizerConfig, make_optimizer
@@ -406,7 +407,7 @@ def test_degrade_mesh_and_prune_specs():
     from repro.dist import api as dist
     from repro.launch.mesh import degrade_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     half = degrade_mesh(mesh, "model")
     assert dict(half.shape) == {"data": 2, "model": 2}
     assert half.axis_names == ("data", "model")
@@ -463,7 +464,7 @@ def test_backend_param_specs_re_resolve_on_degraded_mesh():
     from repro.core.robe import RobeSpec
 
     rules = default_rules()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     robe = RobeSpec(size=512, block_size=8, seed=11)
     base = EmbeddingSpec(vocab_sizes=(64, 96, 32), dim=8, kind="robe",
                          robe=robe)
@@ -480,7 +481,7 @@ def test_backend_param_specs_re_resolve_on_degraded_mesh():
     assert get_backend("robe").param_specs(z3, rules, mesh=mesh) == \
         {"memory": P("model")}
     # a mesh with no model axis: sharded placements fall back
-    dp_only = jax.make_mesh((8,), ("data",))
+    dp_only = make_mesh((8,), ("data",))
     assert get_backend("robe").param_specs(z3, rules, mesh=dp_only) == \
         {"memory": P()}
     assert get_backend("full").param_specs(spec2d, rules, mesh=dp_only) == \
@@ -527,7 +528,7 @@ _E2E_BODY = """
         return build_train_step(lambda p, b: loss_fn(p, cfg, b), opt, tc)
 
     tmp = tempfile.mkdtemp()
-    mesh16 = jax.make_mesh((2, 8), ("data", "model"))
+    mesh16 = make_mesh((2, 8), ("data", "model"))
     ctx16 = dist.DistContext(mesh=mesh16, rules=dist.default_rules())
     # three consecutive slow steps at 7-9 trip patience=3 right at the
     # step-10 checkpoint boundary
@@ -604,7 +605,7 @@ def test_restore_onto_degraded_mesh_full_2d():
                  "label": jnp.asarray(rs.randint(0, 2, (16,)), jnp.int32)}
         l_ref, _ = loss_fn(params, cfg, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
         pspecs = recsys_specs(params, ctx.rules, embedding_spec=spec,
                               mesh=ctx.mesh)
@@ -665,7 +666,7 @@ def test_restore_onto_degraded_mesh_robe_z3():
                                kw.items()})
         l_ref, _ = loss_fn(params, cfg_rep, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
         pspecs = recsys_specs(params, ctx.rules, embedding_spec=spec,
                               mesh=ctx.mesh)

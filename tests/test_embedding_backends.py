@@ -279,32 +279,19 @@ def test_dlrm_config_sweeps_backend(kind):
 
 
 # ---------------------------------------------------------------------------
-# fused_serve protocol (the one-pass serve super-kernel hook)
+# the serve path with the kernels on
 # ---------------------------------------------------------------------------
 
-def test_fused_serve_default_none():
-    """Optional protocol member: backends without a fused serve path leave
-    the class attribute as None; robe implements it."""
-    for kind in ("full", "hashed", "tt", "qrobe"):
-        assert get_backend(kind).fused_serve is None
-    assert callable(get_backend("robe").fused_serve)
-
-
-def test_robe_fused_serve_declines_model_placement():
-    spec = _spec("robe", placement="model")
-    assert get_backend("robe").fused_serve(None, spec, None, None) is None
-
-
-def test_dlrm_serve_fused_path_matches_unfused():
-    """End-to-end parity: dlrm-rm2 smoke scoring through the one-pass
-    serve super-kernel (use_kernel=True → backend.fused_serve, no [B,F,D]
-    intermediate) equals the unfused lookup → concat → dot-interaction
-    path to 1e-5."""
+@pytest.mark.parametrize("kind", KERNEL_BACKENDS)
+def test_dlrm_serve_kernel_path_matches_jnp(kind):
+    """End-to-end parity: dlrm-rm2 smoke scoring with use_kernel=True (the
+    substrate's lookup kernel, then the dot-interaction kernel) equals the
+    jnp path to 1e-5."""
     import dataclasses
 
     from repro.configs import get_arch
     from repro.models import recsys as R
-    cfg = get_arch("dlrm-rm2").make_config("smoke", embedding="robe")
+    cfg = get_arch("dlrm-rm2").make_config("smoke", embedding=kind)
     cfg_k = dataclasses.replace(cfg, use_kernel=True)
     params = R.init_params(jax.random.PRNGKey(0), cfg)
     rs = np.random.RandomState(5)
@@ -325,3 +312,21 @@ def test_cost_model_shape(kind):
     assert set(c) == {"params", "bytes_fetched", "flops"}
     assert c["params"] == spec.param_count > 0
     assert c["bytes_fetched"] > 0
+
+
+@pytest.mark.parametrize("kind", ("robe", "qrobe"))
+def test_kernel_cost_counts_the_dma_gather(kind):
+    """With use_kernel, cost() counts what the Pallas gather moves: at
+    least the per-call f32 copy of the whole array (read and written) and
+    every pair's 128-lane windows — more than the paper's coalesced-read
+    model of the jnp path."""
+    from repro.kernels.robe_lookup import n_segments, window_rows
+    spec = _spec(kind, use_kernel=True)
+    batch = 1024
+    pairs = batch * spec.n_fields
+    got = get_backend(kind).cost(spec, batch)["bytes_fetched"]
+    m = spec.robe.size
+    windows = (pairs * n_segments(spec.dim, spec.robe.block_size)
+               * window_rows(spec.dim) * 128 * 4)
+    assert got >= 2 * m * 4 + windows + pairs * spec.dim * 4
+    assert got > get_backend(kind).cost(_spec(kind), batch)["bytes_fetched"]

@@ -2,7 +2,7 @@
 pass before it may ship (ROADMAP §Kernel conformance).
 
 One parametrized suite over the five fused ops — ``robe_lookup``,
-``dot_interaction``, ``qr_lookup``, ``tt_lookup``, ``serve_fused`` —
+``qrobe_lookup``, ``dot_interaction``, ``qr_lookup``, ``tt_lookup`` —
 asserting
 
   (a) Pallas-interpret forward == the jnp reference to 1e-5 (f32) /
@@ -30,7 +30,7 @@ import pytest
 from repro.core.robe import RobeSpec
 from repro.kernels import ref
 from repro.kernels.ops import (dot_interaction, qr_lookup, qrobe_lookup,
-                               robe_lookup, serve_fused, tt_lookup)
+                               robe_lookup, tt_lookup)
 from repro.nn.embedding_backends.hashed import qr_layout
 from repro.nn.embedding_backends.qrobe import GROUP_LOG2
 from repro.nn.embedding_backends.tt import factor_dim, factor_rows
@@ -97,23 +97,12 @@ def _case(name, dtype=jnp.float32, b=16, dim=24, vocabs=VOCABS, seed=0):
         reference = lambda p: ref.qrobe_lookup_ref(
             p[0], p[1], idx, jnp.arange(f, dtype=jnp.uint32), dim, spec,
             GROUP_LOG2)
-    elif name == "serve":
-        # the one-pass serve super-kernel: params = (ROBE array, bottom-MLP
-        # output); multi-field offsets exercised via per-field table ids
-        spec = RobeSpec(size=4096, block_size=16, seed=7, use_sign=True)
-        params = (jnp.asarray(rs.randn(4096), dtype),
-                  jnp.asarray(rs.randn(b, dim), dtype))
-        tids = tuple(range(f))
-        fused = lambda p, uk: serve_fused(p[0], idx, p[1], tids, dim, spec,
-                                          uk)
-        reference = lambda p: ref.serve_fused_ref(
-            p[0], idx, p[1], jnp.arange(f, dtype=jnp.uint32), dim, spec)
     else:
         raise AssertionError(name)
     return fused, reference, params
 
 
-CASES = ("robe", "dot", "qr", "tt", "qrobe", "serve")
+CASES = ("robe", "dot", "qr", "tt", "qrobe")
 #: every fused op carries a custom_vjp (explicit scatter-add / symmetric
 #: gram contraction) — the Pallas forwards have no autodiff rule
 VJP_CASES = CASES
@@ -243,31 +232,31 @@ def test_bag_lookup_flows_through_kernel(kind):
     _assert_close(got, want, jnp.float32)
 
 
-def test_serve_fused_bag_and_chunked_memory():
-    """The serve super-kernel's two hard modes at once: multi-hot bags with
-    −1 padding (including one fully-empty bag) pooled in-register, and a
-    ROBE array split across memory chunks (grid dim 1) so the gather has to
-    pick each slot's contribution from exactly one chunk revisit."""
-    from repro.kernels.serve_fused import serve_fused_pallas
-    spec = RobeSpec(size=4096, block_size=16, seed=7, use_sign=True)
-    b, f, bag, dim = 6, 4, 3, 24
+@pytest.mark.parametrize("kind", ("robe", "qrobe"))
+def test_dma_gather_bags_over_several_grid_steps(kind):
+    """Multi-hot bags with −1 padding (one bag fully empty) pooled through
+    the DMA gather, with enough (sample, bag slot, field) lookups that the
+    gather runs several grid steps over the array, the last one padded:
+    kernel-on equals kernel-off."""
+    from repro.kernels.tiling import PAIRS_PER_STEP
+    from repro.nn.embeddings import (EmbeddingSpec, embedding_init,
+                                     embedding_lookup_bag)
+    b, f, bag = 100, 4, 3
+    assert PAIRS_PER_STEP < b * f * bag < 2 * PAIRS_PER_STEP
+    kw = dict(vocab_sizes=(37,) * f, dim=24, kind=kind,
+              robe=RobeSpec(size=4096, block_size=16, seed=7, use_sign=True))
+    spec_jnp = EmbeddingSpec(**kw)
+    spec_ker = EmbeddingSpec(use_kernel=True, **kw)
+    params = embedding_init(jax.random.PRNGKey(3), spec_jnp)
     rs = np.random.RandomState(3)
     idx = rs.randint(0, 37, (b, f, bag)).astype(np.int32)
     idx[0, 0, 1:] = -1
     idx[3, 2, :] = -1                             # empty bag pools to zero
     idx = jnp.asarray(idx)
-    memory = jnp.asarray(rs.randn(4096), jnp.float32)
-    bot = jnp.asarray(rs.randn(b, dim), jnp.float32)
-    tids = tuple(range(f))
-    want = ref.serve_fused_ref(memory, idx, bot,
-                               jnp.arange(f, dtype=jnp.uint32), dim, spec)
-    # multi-chunk: 4096 / 512 = 8 memory revisits per batch tile
-    chunked = serve_fused_pallas(memory, idx, bot, tids, dim, spec,
-                                 interpret=True, mem_chunk=512)
-    _assert_close(chunked, want, jnp.float32)
-    # the op entry point (single chunk — whole array resident)
-    _assert_close(serve_fused(memory, idx, bot, tids, dim, spec, True),
-                  want, jnp.float32)
+    want = embedding_lookup_bag(params, spec_jnp, idx)
+    got = embedding_lookup_bag(params, spec_ker, idx)
+    assert not np.any(np.asarray(got)[3, 2])
+    _assert_close(got, want, jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -297,8 +286,7 @@ def test_tt_kernel_matches_materialized_table():
 
 
 # ---------------------------------------------------------------------------
-# hypothesis property tests for the in-kernel index math (runs against the
-# real package when installed, the deterministic conftest stub otherwise)
+# hypothesis property tests for the kernels' index math
 # ---------------------------------------------------------------------------
 
 from hypothesis import given, settings, strategies as st

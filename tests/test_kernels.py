@@ -77,17 +77,18 @@ def test_robe_lookup_grad_dtype_matches_memory_dtype():
 
 
 @pytest.mark.parametrize("b,z", [
-    (13, 16),       # general kernel (Z < d), tile 8 < batch → pads to 16
-    (13, 128),      # aligned kernel (Z % d == 0), same pad-and-slice path
-    (1, 16),        # degenerate batch
+    (13, 16),       # Z < d: eight blocks per row
+    (13, 128),      # Z > d: one block per row
+    (1, 16),        # degenerate batch: one short grid step
 ])
 def test_robe_lookup_kernel_prime_batch_pads_tile(b, z):
     """Prime batch sizes must not degrade the grid to one-row tiles: the
-    batch is padded to the tile and the output sliced back.  f·d is sized
-    so the VMEM budget makes tb < b and the pad branch actually runs."""
-    from repro.kernels.robe_lookup import _pick_batch_tile
-    f, d = 512, 128
-    assert _pick_batch_tile(13, f, d) == 8        # tile < batch: pads
+    kernel tiles (row, field) pairs at PAIRS_PER_STEP, pads the last tile
+    and slices the output back.  b·f is sized so that b=13 spans two grid
+    steps, the second one padded."""
+    from repro.kernels.tiling import PAIRS_PER_STEP
+    f, d = 100, 128
+    assert PAIRS_PER_STEP < 13 * f < 2 * PAIRS_PER_STEP   # pads step 2
     rs = np.random.RandomState(5)
     spec = RobeSpec(size=4096, block_size=z, seed=7, use_sign=True)
     mem = jnp.asarray(rs.randn(4096), jnp.float32)
@@ -100,12 +101,12 @@ def test_robe_lookup_kernel_prime_batch_pads_tile(b, z):
 
 
 def test_pick_batch_tile_no_prime_degradation():
-    from repro.kernels.robe_lookup import _pick_batch_tile
+    from repro.kernels.tiling import pick_batch_tile
     # prime batch: tile stays large (pad-and-slice), never collapses to 1
-    assert _pick_batch_tile(8191, 26, 64) > 1
-    assert _pick_batch_tile(8192, 26, 64) == _pick_batch_tile(8191, 26, 64)
-    # tiny batches are still clamped to the batch
-    assert _pick_batch_tile(3, 4, 16) == 3
+    assert pick_batch_tile(8191, 26, 64) > 1
+    assert pick_batch_tile(8192, 26, 64) == pick_batch_tile(8191, 26, 64)
+    # tiny batches are clamped to the batch rounded up to 8 sublanes
+    assert pick_batch_tile(3, 4, 16) == 8
 
 
 def test_robe_lookup_wraps_circularly():

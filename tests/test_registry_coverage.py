@@ -45,7 +45,7 @@ def _load_test_module(name: str):
 
 def test_every_custom_vjp_op_has_a_conformance_case():
     ops = _custom_vjp_ops(ROOT / "src" / "repro" / "kernels" / "ops.py")
-    assert len(ops) >= 6, ops       # robe/qrobe/dot/serve/qr/tt today
+    assert len(ops) >= 5, ops       # robe/qrobe/dot/qr/tt today
     conformance = (ROOT / "tests" / "test_kernel_conformance.py").read_text()
     missing = [op for op in ops if op not in conformance]
     assert not missing, (
@@ -60,7 +60,7 @@ def test_conformance_cases_cover_every_op_family():
     mod = _load_test_module("test_kernel_conformance")
     ops = _custom_vjp_ops(ROOT / "src" / "repro" / "kernels" / "ops.py")
     # each case name is a family keyed off its op prefix (robe_lookup →
-    # "robe", serve_fused → "serve", dot_interaction → "dot", ...)
+    # "robe", dot_interaction → "dot", qr_lookup → "qr", ...)
     families = {op.split("_")[0] for op in ops}
     assert families <= set(mod.CASES), (
         f"op families {families - set(mod.CASES)} missing from "

@@ -9,18 +9,24 @@ instead of implicitly in four kernels.
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.tiling import pad_batch, pick_batch_tile, round_up
+from repro.kernels.tiling import (PAIRS_PER_STEP, pad_batch, pair_tile,
+                                  pick_batch_tile, round_up)
 
 
 def test_pick_batch_tile_vmem_budget():
-    # budget is 2 MiB of f32: tb = (2·1024·1024/4) // (f·dim), clamped
-    assert pick_batch_tile(13, 8, 6000) == (2 * 1024 * 1024 // 4) // 48000
-    assert pick_batch_tile(13, 8, 6000) == 10          # < b → pad branch
+    # budget is 2 MiB of f32: tb = (2·1024·1024/4) // (f·dim), rounded
+    # down to a multiple of 8 sublanes, clamped
+    assert pick_batch_tile(13, 8, 6000) == (2 * 1024 * 1024 // 4) // 48000 \
+        // 8 * 8
+    assert pick_batch_tile(13, 8, 6000) == 8           # < b → pad branch
+    assert pick_batch_tile(4096, 26, 64) == 312        # 315 → 312
 
 
 def test_pick_batch_tile_clamps_to_batch():
     # tiny rows: budget allows a huge tile, but never exceed the batch
-    assert pick_batch_tile(3, 4, 16) == 3
+    # rounded up to 8 sublanes
+    assert pick_batch_tile(3, 4, 16) == 8
+    assert pick_batch_tile(20, 4, 16) == 24
     # ...and never exceed the 1024 hard cap even for huge batches
     assert pick_batch_tile(1 << 20, 1, 1) == 1024
 
@@ -32,8 +38,15 @@ def test_pick_batch_tile_depends_only_on_row_bytes():
 
 
 def test_pick_batch_tile_never_zero():
-    # a row bigger than the whole budget still yields a 1-row tile
-    assert pick_batch_tile(64, 4096, 4096) == 1
+    # a row bigger than the whole budget still yields an 8-row tile
+    assert pick_batch_tile(64, 4096, 4096) == 8
+
+
+def test_pick_batch_tile_is_sublane_aligned():
+    # the TPU compiler refuses a batch block that is not a multiple of 8
+    for b, f, d in ((512, 26, 64), (65536, 27, 128), (13, 8, 6000),
+                    (1, 1, 1), (8191, 3, 7)):
+        assert pick_batch_tile(b, f, d) % 8 == 0, (b, f, d)
 
 
 def test_round_up():
@@ -53,8 +66,10 @@ def test_pad_batch_round_trip():
     assert pad_batch(x, 13) is x
 
 
-def test_legacy_alias_still_exported():
-    # kernels historically exposed _pick_batch_tile from robe_lookup;
-    # the alias must keep resolving to the shared policy
-    from repro.kernels.robe_lookup import _pick_batch_tile
-    assert _pick_batch_tile is pick_batch_tile
+def test_pair_tile_whole_or_1024():
+    # up to PAIRS_PER_STEP pairs: one step, the whole (8-aligned) array
+    assert pair_tile(15) == (16, 16)
+    assert pair_tile(PAIRS_PER_STEP) == (PAIRS_PER_STEP, PAIRS_PER_STEP)
+    # beyond: whole 1024-word SMEM tiles, the last one padded
+    assert pair_tile(1300) == (1024, 2048)
+    assert pair_tile(512 * 26) == (1024, 13312)     # dlrm-rm2 serve_p99
