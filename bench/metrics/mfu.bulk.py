@@ -1,0 +1,13 @@
+"""Model FLOPs of the forward pass per sample (``flops.dlrm_forward_flops``)
+times the samples scored per second, over the chip's bf16 peak, in %."""
+
+from bench.flops import dlrm_forward_flops
+from bench.peaks import device_kind, peak
+
+
+def read(run):
+    if run.traffic["driver"] != "bulk" or not run.record.get("elapsed_s"):
+        return None
+    rate = run.record["samples"] / run.record["elapsed_s"]
+    return (dlrm_forward_flops(run.config) * rate
+            / peak(device_kind(), "bf16_flops_per_s") * 100)

@@ -1,0 +1,14 @@
+"""Three times the forward pass's model FLOPs per sample (forward, and the
+backward's two products per layer) times the samples trained per second,
+over the chip's bf16 peak, in %."""
+
+from bench.flops import dlrm_forward_flops
+from bench.peaks import device_kind, peak
+
+
+def read(run):
+    if run.traffic["driver"] != "train" or not run.record.get("elapsed_s"):
+        return None
+    rate = run.record["samples"] / run.record["elapsed_s"]
+    return (3 * dlrm_forward_flops(run.config) * rate
+            / peak(device_kind(), "bf16_flops_per_s") * 100)
