@@ -80,5 +80,6 @@ def dot_interaction_pallas(feats: jnp.ndarray, self_interaction: bool = False,
         out_specs=pl.BlockSpec((tb, 1, n_pairs), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b_pad, 1, n_pairs), feats.dtype),
         interpret=interpret,
+        name="dot_interaction",
     )(feats, r_sel, c_sel)
     return out[:b, 0]

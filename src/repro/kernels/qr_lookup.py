@@ -96,5 +96,6 @@ def qr_lookup_pallas(q_table: jnp.ndarray, r_table: jnp.ndarray,
                         pltpu.VMEM((pairs, lanes), jnp.float32),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
+        name="qr_gather",
     )(q_idx, r_idx, widen(q_table), widen(r_table))
     return out[:n, :dim].reshape(b, f, dim).astype(q_table.dtype)

@@ -173,9 +173,10 @@ def _gather_kernel(n_seg: int, log2_z: int, dim: int, n_rows: int,
 
 def gather_windows(mem_rows: jnp.ndarray, starts: jnp.ndarray,
                    off0: jnp.ndarray, dim: int, log2_z: int,
-                   interpret: bool) -> jnp.ndarray:
+                   interpret: bool, name: str) -> jnp.ndarray:
     """[N, S] window starts into the padded ``[R, 128]`` array -> [N, dim]
-    f32 rows (the Pallas call; ``N`` is padded internally)."""
+    f32 rows (the Pallas call, named ``name``; ``N`` is padded
+    internally)."""
     n, n_seg = starts.shape
     n_rows = window_rows(dim)
     pairs, n_pad = pair_tile(n)
@@ -200,14 +201,16 @@ def gather_windows(mem_rows: jnp.ndarray, starts: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((pairs, n_rows, LANES), jnp.float32),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
+        name=name,
     )(starts.reshape(-1), off0, mem_rows)
     return out[:n] if n_pad != n else out
 
 
 def robe_gather(memory: jnp.ndarray, rows: jnp.ndarray,
                 table_ids: Tuple[int, ...], dim: int, spec: RobeSpec,
-                interpret: bool) -> jnp.ndarray:
-    """[B, F] rows -> [B, F, dim] f32 (signs applied) from any-dtype M."""
+                interpret: bool, name: str) -> jnp.ndarray:
+    """[B, F] rows -> [B, F, dim] f32 (signs applied) from any-dtype M,
+    through the gather kernel named ``name``."""
     b, f = rows.shape
     starts, off0 = window_starts(spec, table_ids, rows, dim)
     front = starts.shape[1] * spec.block_size
@@ -215,7 +218,7 @@ def robe_gather(memory: jnp.ndarray, rows: jnp.ndarray,
                               _padded_len(memory.shape[0], dim,
                                           spec.block_size))
     out = gather_windows(mem_rows, starts, off0, dim, spec.log2_z,
-                         interpret).reshape(b, f, dim)
+                         interpret, name).reshape(b, f, dim)
     if spec.use_sign:
         tids = jnp.asarray(table_ids, jnp.uint32)[None, :]
         out = out * robe_signs(spec, tids, rows, dim)
@@ -228,8 +231,8 @@ def robe_lookup_pallas(memory: jnp.ndarray, rows: jnp.ndarray,
                        table_ids: Tuple[int, ...], dim: int, spec: RobeSpec,
                        interpret: bool = True) -> jnp.ndarray:
     """Fused ROBE lookup: [B, F] int rows -> [B, F, dim] in memory.dtype."""
-    return robe_gather(memory, rows, table_ids, dim, spec,
-                       interpret).astype(memory.dtype)
+    return robe_gather(memory, rows, table_ids, dim, spec, interpret,
+                       "robe_gather").astype(memory.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "dim", "table_ids",
@@ -250,5 +253,5 @@ def qrobe_lookup_pallas(codes: jnp.ndarray, scale: jnp.ndarray,
     m = codes.shape[0]
     group = jnp.repeat(scale.astype(jnp.float32), 1 << group_log2)[:m]
     deq = codes.astype(jnp.float32) * group
-    return robe_gather(deq, rows, table_ids, dim, spec,
-                       interpret).astype(scale.dtype)
+    return robe_gather(deq, rows, table_ids, dim, spec, interpret,
+                       "qrobe_gather").astype(scale.dtype)

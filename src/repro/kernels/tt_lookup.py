@@ -92,5 +92,6 @@ def tt_lookup_pallas(core0: jnp.ndarray, core1: jnp.ndarray,
         out_specs=pl.BlockSpec((tb, f, dim), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b_pad, f, dim), core0.dtype),
         interpret=interpret,
+        name="tt_lookup",
     )(idx, jnp.asarray(offsets, jnp.int32), core0, core1, core2)
     return out[:b] if b_pad != b else out

@@ -137,9 +137,10 @@ def _embed(params, cfg: RecsysConfig, sparse_ids: jnp.ndarray) -> jnp.ndarray:
     # the substrate owns its distributed lookup (shard_map bodies, batch
     # layout, collectives) — see repro.nn.embedding_backends
     spec = cfg.embedding_spec()
-    emb = embedding_lookup_dist(params["embedding"], spec, sparse_ids,
-                                compute_dtype=cfg.compute_dtype)
-    return emb.astype(cfg.compute_dtype)
+    with jax.named_scope("embedding"):
+        emb = embedding_lookup_dist(params["embedding"], spec, sparse_ids,
+                                    compute_dtype=cfg.compute_dtype)
+        return emb.astype(cfg.compute_dtype)
 
 
 def _batch_emb(params, cfg: RecsysConfig, batch: dict) -> jnp.ndarray:
@@ -154,18 +155,9 @@ def _batch_emb(params, cfg: RecsysConfig, batch: dict) -> jnp.ndarray:
     """
     emb = batch.get("emb")
     if emb is not None:
-        return jnp.asarray(emb).astype(cfg.compute_dtype)
+        with jax.named_scope("embedding"):
+            return jnp.asarray(emb).astype(cfg.compute_dtype)
     return _embed(params, cfg, batch["sparse"])
-
-
-def _dlrm_interaction(params, cfg: RecsysConfig, batch: dict,
-                      bot: jnp.ndarray) -> jnp.ndarray:
-    """[B, (F+1)·F/2] dot-interaction triangle of [bot; field embeddings]
-    (with ``use_kernel``: the substrate's lookup kernel, then the
-    dot-interaction kernel)."""
-    emb = _batch_emb(params, cfg, batch)
-    feats = jnp.concatenate([bot[:, None, :], emb], axis=1)
-    return dot_interaction_op(feats, use_kernel=cfg.use_kernel)
 
 
 def forward(params, cfg: RecsysConfig, batch: dict) -> jnp.ndarray:
@@ -175,14 +167,24 @@ def forward(params, cfg: RecsysConfig, batch: dict) -> jnp.ndarray:
     alongside) ``"sparse"`` — the serving tier's hot-row cache path
     (``serve/hot_cache.py``); it takes precedence over the substrate
     lookup.
+
+    Each DLRM layer runs under its own ``jax.named_scope`` (``embedding``,
+    ``bottom_mlp``, ``interaction``, ``top_mlp``), so a profile names the
+    layer of every device op, forward and backward.
     """
     a = cfg.arch
     if a == "dlrm":
-        dense = batch["dense"].astype(cfg.compute_dtype)
-        bot = mlp_apply(params["bot"], dense, final_act=jax.nn.relu)
-        inter = _dlrm_interaction(params, cfg, batch, bot)
-        top_in = jnp.concatenate([bot, inter], axis=-1)
-        return mlp_apply(params["top"], top_in)[:, 0]
+        with jax.named_scope("bottom_mlp"):
+            dense = batch["dense"].astype(cfg.compute_dtype)
+            bot = mlp_apply(params["bot"], dense, final_act=jax.nn.relu)
+        emb = _batch_emb(params, cfg, batch)
+        with jax.named_scope("interaction"):
+            # [B, (F+1)·F/2] triangle of the gram of [bot; embeddings]
+            feats = jnp.concatenate([bot[:, None, :], emb], axis=1)
+            inter = dot_interaction_op(feats, use_kernel=cfg.use_kernel)
+        with jax.named_scope("top_mlp"):
+            top_in = jnp.concatenate([bot, inter], axis=-1)
+            return mlp_apply(params["top"], top_in)[:, 0]
     emb = _batch_emb(params, cfg, batch)             # [B,F,D]
     b, f, d = emb.shape
     flat = emb.reshape(b, f * d)

@@ -1,7 +1,7 @@
 """The serving tier: async routing, hot-row caching, multi-substrate
 scoring, and traffic replay.
 
-* ``serving``   — sync ``MicroBatcher`` + ``latency_profile``/``percentile``
+* ``serving``   — sync ``MicroBatcher`` + ``percentile``
 * ``router``    — ``DeadlineBatcher``/``FixedBatcher`` policies and the
   ``AsyncRouter`` front-end (admission, deadline close-out, load shedding)
 * ``hot_cache`` — ``CountMinSketch`` + ``HotRowCache`` (fronts the
@@ -21,5 +21,4 @@ from repro.serve.router import (AsyncRouter, DeadlineBatcher,   # noqa: F401
                                 FixedBatcher, LoadShedError, RouterConfig,
                                 stack_and_pad)
 from repro.serve.hot_cache import CountMinSketch, HotRowCache   # noqa: F401
-from repro.serve.serving import (MicroBatcher, latency_profile,  # noqa: F401
-                                 percentile)
+from repro.serve.serving import MicroBatcher, percentile  # noqa: F401

@@ -22,6 +22,11 @@ real rows, and the cache never counts the padded tail.
 Under an active ``repro.dist`` context the jitted scorers pick up the
 mesh through each backend's own ``lookup_dist`` bodies —
 the server adds no placement logic of its own.
+
+Each ``score`` call opens three host spans (``jax.profiler.
+TraceAnnotation``): ``serve.h2d`` (the batch's copy to the device),
+``serve.run`` (the scorer's dispatch) and ``serve.d2h`` (the wait for the
+scores and their copy back).
 """
 
 from __future__ import annotations
@@ -33,8 +38,10 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from repro.models.recsys import RecsysConfig, init_params, serve_scores
+from repro.models import recsys
+from repro.models.recsys import RecsysConfig, init_params
 from repro.nn.embeddings import get_backend
 from repro.serve.hot_cache import HotRowCache
 from repro.train import checkpoint as ckpt_lib
@@ -42,6 +49,16 @@ from repro.train import checkpoint as ckpt_lib
 __all__ = ["ServerConfig", "EmbeddingServer", "PushReport"]
 
 DEFAULT_BACKENDS = ("full", "robe", "hashed", "tt")
+
+
+def _scorer(rc: RecsysConfig):
+    """``recsys.serve_scores`` bound to one substrate's config, jitted as a
+    program of its own that a profile names ``jit_serve_scores``."""
+
+    def serve_scores(params, batch):
+        return recsys.serve_scores(params, rc, batch)
+
+    return jax.jit(serve_scores)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,8 +137,7 @@ class EmbeddingServer:
             self._params[name] = (params[name] if params is not None
                                   else init_params(
                                       jax.random.PRNGKey(cfg.seed + i), rc))
-            self._jit[name] = jax.jit(
-                lambda p, b, c=rc: serve_scores(p, c, b))
+            self._jit[name] = _scorer(rc)
             cache = None
             if cfg.cache_capacity > 0:
                 # the cache gathers through the embedding-layer subtree —
@@ -173,13 +189,17 @@ class EmbeddingServer:
                            f"{sorted(self._cfgs)}")
         cache = self._caches[backend] if use_cache else None
         if cache is not None:
-            emb = cache.lookup(np.asarray(batch["sparse"]), n_valid)
-            jb = {"dense": jnp.asarray(batch["dense"]),
-                  "emb": jnp.asarray(emb)}
+            feats = {"dense": batch["dense"],
+                     "emb": cache.lookup(np.asarray(batch["sparse"]),
+                                         n_valid)}
         else:
-            jb = {"dense": jnp.asarray(batch["dense"]),
-                  "sparse": jnp.asarray(batch["sparse"])}
-        out = np.asarray(self._jit[backend](self._params[backend], jb))
+            feats = {"dense": batch["dense"], "sparse": batch["sparse"]}
+        with TraceAnnotation("serve.h2d"):
+            jb = {k: jnp.asarray(v) for k, v in feats.items()}
+        with TraceAnnotation("serve.run"):
+            scores = self._jit[backend](self._params[backend], jb)
+        with TraceAnnotation("serve.d2h"):
+            out = np.asarray(scores)
         return out[:n_valid] if n_valid is not None else out
 
     def score_fn(self, backend: str, *, use_cache: bool = True):

@@ -1,4 +1,4 @@
-"""Serving utilities: sync micro-batching front-end + latency profiling.
+"""Serving utilities: the sync micro-batching front-end.
 
 The serving subsystem proper lives in the sibling modules — ``router``
 (deadline-aware async batching), ``hot_cache`` (frequency-sketch hot-row
@@ -11,8 +11,7 @@ keeps the synchronous conveniences:
   logic (``poll()`` dispatches only batches that are due; ``flush()``
   force-closes everything), one shared padding path
   (``router.stack_and_pad``), so sync and async serving can never drift.
-* ``latency_profile`` — steady-state percentiles of a jitted scoring
-  function, compile time reported separately.
+* ``percentile`` — nearest-rank percentile of a sorted sample.
 """
 
 from __future__ import annotations
@@ -21,14 +20,13 @@ import math
 import time
 from typing import Callable, Dict, List, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.serve.router import (DeadlineBatcher, RouterConfig,
                                 accepts_n_valid, stack_and_pad)
 
-__all__ = ["MicroBatcher", "latency_profile", "percentile"]
+__all__ = ["MicroBatcher", "percentile"]
 
 
 def percentile(sorted_values, p: float) -> float:
@@ -117,33 +115,3 @@ class MicroBatcher:
         for reqs in self._batcher.drain():
             out.extend(self._score(reqs))
         return out
-
-
-def latency_profile(fn: Callable, batch: dict, iters: int = 32,
-                    warmup: int = 1) -> dict:
-    """Steady-state p50/p95/p99 wall latency of a jitted scoring function.
-
-    The first call — which includes trace + compile — is timed separately
-    and reported as ``compile_ms``, and ``warmup`` further iterations are
-    discarded (dispatch caches, allocator churn), so the percentiles
-    describe only the steady state a serving deployment actually sees.
-    Percentiles are nearest-rank (see ``percentile``): exact at small
-    ``iters`` instead of overshooting the rank.
-    """
-    jb = {k: jnp.asarray(v) for k, v in batch.items()}
-    t0 = time.monotonic()
-    r = fn(jb)
-    jax.tree.leaves(r)[0].block_until_ready()
-    compile_ms = (time.monotonic() - t0) * 1e3
-    for _ in range(warmup):                      # discarded warm-up iters
-        r = fn(jb)
-        jax.tree.leaves(r)[0].block_until_ready()
-    lats = []
-    for _ in range(iters):
-        t0 = time.monotonic()
-        r = fn(jb)
-        jax.tree.leaves(r)[0].block_until_ready()
-        lats.append((time.monotonic() - t0) * 1e3)
-    lats = np.sort(np.asarray(lats))
-    return {"p50_ms": percentile(lats, 0.5), "p95_ms": percentile(lats, 0.95),
-            "p99_ms": percentile(lats, 0.99), "compile_ms": compile_ms}

@@ -1,6 +1,6 @@
 """Train-step builder + fault-tolerant runner.
 
-``build_train_step`` returns one jitted function:
+``build_train_step`` returns one jitted function, ``train_step``:
     state, metrics = step_fn(state, batch)
 with gradient accumulation (microbatching via lax.scan), mixed precision
 (params fp32, compute bf16 per model config), NaN guarding, and — when a
@@ -15,6 +15,12 @@ steps trigger an elastic re-slice: the loop flushes a checkpoint, hands
 control to ``reslice_fn(state, step)`` (``repro.train.elastic`` builds the
 degraded mesh, re-resolves the sharding specs, restores onto it, re-jits),
 and continues at the same global step on the surviving devices.
+
+Profiler names: the optimizer's update runs under ``jax.named_scope
+("optimizer")``; each step of ``run`` opens three host spans
+(``jax.profiler.TraceAnnotation``): ``train.h2d`` (the batch and its copy
+to the device), ``train.run`` (the step's dispatch) and ``train.sync``
+(the wait for the step's loss).
 """
 
 from __future__ import annotations
@@ -27,6 +33,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.dist import api as dist
 from repro.train import checkpoint as ckpt_lib
@@ -91,7 +98,7 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
                                          allow_int=True)(params)
         return loss, grads
 
-    def step_fn(state, batch):
+    def train_step(state, batch):
         params, opt_state, step = state["params"], state["opt"], state["step"]
         if cfg.grad_compression != "none":
             ctx = dist.current()
@@ -120,26 +127,30 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
         else:
             loss, grads = grads_of(params, batch)
 
-        # NaN guard: skip the update if any grad is non-finite (float0
-        # cotangents carry no values to inspect)
-        finite = jnp.isfinite(loss)
-        for g in jax.tree.leaves(grads):
-            if g.dtype == jax.dtypes.float0:
-                continue
-            finite &= jnp.all(jnp.isfinite(g))
-        new_params, new_opt = optimizer.update(params, grads, opt_state, step)
-        params = jax.tree.map(
-            lambda new, old: jnp.where(finite, new, old), new_params, params)
-        opt_state = jax.tree.map(
-            lambda new, old: jnp.where(finite, new, old), new_opt, opt_state)
-        if project is not None:
-            # requantization fold (ALPT): idempotent on a skipped update —
-            # a between-steps state projects to itself
-            params = project(params)
+        with jax.named_scope("optimizer"):
+            # NaN guard: skip the update if any grad is non-finite (float0
+            # cotangents carry no values to inspect)
+            finite = jnp.isfinite(loss)
+            for g in jax.tree.leaves(grads):
+                if g.dtype == jax.dtypes.float0:
+                    continue
+                finite &= jnp.all(jnp.isfinite(g))
+            new_params, new_opt = optimizer.update(params, grads, opt_state,
+                                                   step)
+            params = jax.tree.map(
+                lambda new, old: jnp.where(finite, new, old), new_params,
+                params)
+            opt_state = jax.tree.map(
+                lambda new, old: jnp.where(finite, new, old), new_opt,
+                opt_state)
+            if project is not None:
+                # requantization fold (ALPT): idempotent on a skipped
+                # update — a between-steps state projects to itself
+                params = project(params)
         state = dict(state, params=params, opt=opt_state, step=step + 1)
         return state, {"loss": loss, "finite": finite.astype(jnp.float32)}
 
-    return jax.jit(step_fn, donate_argnums=(0,))
+    return jax.jit(train_step, donate_argnums=(0,))
 
 
 def init_state(params, optimizer: Optimizer, cfg: TrainConfig) -> dict:
@@ -230,9 +241,13 @@ def run(state, step_fn: Callable, batch_at: Callable[[int], dict],
                 injected["done"] = True
                 raise RuntimeError("injected node failure")
             t0 = timer()
-            batch = {k: jnp.asarray(v) for k, v in batch_at(step).items()}
-            state, metrics = step_fn(state, batch)
-            loss = float(jax.device_get(metrics["loss"]))
+            with TraceAnnotation("train.h2d"):
+                batch = {k: jnp.asarray(v)
+                         for k, v in batch_at(step).items()}
+            with TraceAnnotation("train.run"):
+                state, metrics = step_fn(state, batch)
+            with TraceAnnotation("train.sync"):
+                loss = float(jax.device_get(metrics["loss"]))
             dt = timer() - t0
             if warmup:
                 warmup = False

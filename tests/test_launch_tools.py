@@ -100,21 +100,6 @@ def test_micro_batcher_rejects_mismatched_keys():
     assert [float(o) for o in out] == [7]
 
 
-def test_latency_profile_separates_compile_from_steady_state():
-    """The first (trace+compile) call is reported as compile_ms, not mixed
-    into the steady-state percentiles; warm-up iterations are discarded."""
-    from repro.serve.serving import latency_profile
-    calls = []
-    fn = jax.jit(lambda b: b["x"] * 2.0)
-    counted = lambda b: (calls.append(1), fn(b))[1]
-    prof = latency_profile(counted, {"x": np.ones(8, np.float32)},
-                           iters=5, warmup=2)
-    assert set(prof) == {"p50_ms", "p95_ms", "p99_ms", "compile_ms"}
-    assert len(calls) == 1 + 2 + 5       # compile + warmup + timed
-    assert prof["compile_ms"] > 0
-    assert prof["p50_ms"] <= prof["p95_ms"] <= prof["p99_ms"]
-
-
 def test_elastic_checkpoint_resume_across_shapes():
     """A checkpoint written under one 'mesh' restores onto another: arrays
     are saved in logical shapes, the loader re-applies new shardings."""
