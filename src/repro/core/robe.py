@@ -12,9 +12,13 @@ with ``h`` a 2-universal hash into [0, |M|).  ``Z`` must be a power of two
 (every setting in the paper — 1/2/4/8/16/32 — is), which lets the 64-bit
 block-id computation be a limb-wise shift instead of a 64-bit division.
 
-The jnp path below is the reference implementation used everywhere off the
-hot path; ``repro.kernels.ops.robe_lookup`` is the Pallas TPU kernel with the
-same semantics (block-coalesced DMA reads), validated against this module.
+The jnp path below is the element-wise definition: the reference that the
+lookups of the model are tested against, and the path of the backward, the
+bag lookup and the LM stack.  The model's lookup, ``repro.kernels.ops.
+robe_lookup``, reads the same slots a whole Z-block at a time: by default
+the jnp block gather (``repro.kernels.robe_lookup.robe_lookup_blocks``: one
+hash and one gathered row per block), or the Pallas kernel (block-coalesced
+DMA reads); both are validated against this module.
 Models never call this module directly: the consumer-facing surface is the
 ``robe`` ``EmbeddingBackend`` (``repro.nn.embedding_backends.robe``), which
 owns placement, PartitionSpecs, and the roofline cost model on top of the

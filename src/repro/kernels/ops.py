@@ -1,10 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels, with autodiff.
 
-``robe_lookup``: forward = Pallas kernel (a DMA gather of Z-blocks from
-the array in HBM) or the jnp path; backward = the paper's Fig.-2
-scatter-add of output grads into the shared array, expressed as an XLA
-scatter (segment-sum over slots).  The scatter IS the semantics of weight
-sharing — every aliased parameter's gradient accumulates into its slot.
+``robe_lookup``: forward = the jnp block gather (the default:
+``kernels.robe_lookup.robe_lookup_blocks``, one hash and one gathered
+table row per Z-block, bit-identical to the element-wise definition in
+``core.robe``) or the Pallas kernel (a DMA gather of Z-blocks from the
+array in HBM); backward = the paper's Fig.-2 scatter-add of output grads
+into the shared array, expressed as an XLA scatter (segment-sum over
+slots).  The scatter IS the semantics of weight sharing — every aliased
+parameter's gradient accumulates into its slot.
 
 ``qr_lookup`` / ``tt_lookup`` follow the identical contract for the two
 baseline substrates: fused Pallas forward, custom-VJP backward as an XLA
@@ -32,6 +35,7 @@ import numpy as np
 from repro.core.robe import RobeSpec, robe_slots, robe_signs
 from repro.kernels import ref as _ref
 from repro.kernels.robe_lookup import (qrobe_lookup_pallas,
+                                       robe_lookup_blocks,
                                        robe_lookup_pallas)
 from repro.kernels.dot_interaction import dot_interaction_pallas
 from repro.kernels.qr_lookup import qr_lookup_pallas
@@ -75,8 +79,7 @@ def robe_lookup(memory: jnp.ndarray, rows: jnp.ndarray,
         return robe_lookup_pallas(memory, rows,
                                   table_ids, dim, spec,
                                   interpret=_interpret("robe_lookup"))
-    return _ref.robe_lookup_ref(memory, rows,
-                                jnp.asarray(table_ids, jnp.uint32), dim, spec)
+    return robe_lookup_blocks(memory, rows, table_ids, dim, spec)
 
 
 def _lookup_fwd(memory, rows, table_ids, dim, spec, use_kernel):

@@ -1,4 +1,5 @@
-"""Pallas TPU kernel: ROBE lookup as a DMA gather of Z-blocks from HBM.
+"""ROBE lookup as a gather of whole Z-blocks: the jnp block gather (the
+default forward) and the Pallas TPU kernel (a DMA gather from HBM).
 
 This is the paper's hot path (inference is memory-bound on embedding
 fetches; §2.3 Table 1).  Element ``i`` of row ``x`` lives at
@@ -26,6 +27,16 @@ gather over the dequantized array.
 Validated in interpret mode against ``repro.kernels.ref`` (tests/
 test_kernels.py, tests/test_kernel_conformance.py) and compiled for a
 v5e at published widths by tests/test_tpu_compile.py.
+
+The jnp block gather (``robe_lookup_blocks``) reads the same slots with
+the compiler's own gather, which is fast on a TPU only for an element or
+a whole row of a 2-D array: a slice of Z slots from the 1-D array, or two
+rows in one slice, becomes a loop of one dynamic slice per block, slower
+than the element gather.  So the array is laid out as a table of
+overlapping 128-slot rows (``strided_rows``), every block lies inside one
+row, and a block costs one hash, one gathered row and log2(ROW_STRIDE)
+select stages that align it.  The table is built anew on every call, so
+its 16 copies of the array are paid whatever the batch.
 """
 
 from __future__ import annotations
@@ -82,28 +93,28 @@ def gather_bytes(m: int, dim: int, z: int, n_pairs: int,
 
 def _circular_rows(memory: jnp.ndarray, front: int, min_len: int
                    ) -> jnp.ndarray:
-    """f32 copy of the circular array as ``[R, 128]``: padded index ``j``
-    holds slot ``(j − front) mod |M|`` for ``j < R·128`` (R·128 ≥
-    ``min_len``)."""
+    """Copy of the circular array as ``[R, 128]``, in its dtype: padded
+    index ``j`` holds slot ``(j − front) mod |M|`` for ``j < R·128``
+    (R·128 ≥ ``min_len``)."""
     m = memory.shape[0]
     total = round_up(max(min_len, front + m), LANES)
     back = total - front - m
     reps = -(-max(front, back) // m)
     ext = jnp.tile(memory, reps) if reps > 1 else memory
     flat = jnp.concatenate([ext[ext.shape[0] - front:], memory, ext[:back]])
-    return flat.astype(jnp.float32).reshape(total // LANES, LANES)
+    return flat.reshape(total // LANES, LANES)
 
 
-def window_starts(spec: RobeSpec, table_ids: Tuple[int, ...],
-                  rows: jnp.ndarray, dim: int
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Window starts [N, S] (int32, into the padded copy) and each pair's
-    offset inside its first block [N] for rows [B, F] (N = B·F, row-major).
+def block_starts(spec: RobeSpec, table_ids: Tuple[int, ...],
+                 rows: jnp.ndarray, dim: int
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """First slots [N, S] (int32, in [0, |M|)) of the Z-blocks each pair
+    touches, and each pair's offset inside its first block [N], for rows
+    [B, F] (N = B·F, row-major): one hash per block.
 
     Block ``k`` of row ``x`` holds elements ``i`` with
-    ``(off0 + i) >> log2 Z == k``; its window start is
-    ``front + h(e, b0 + k) + off0 − k·Z`` so that window lane ``i`` is
-    element ``i`` (``front = S·Z`` keeps every start ≥ 0)."""
+    ``(off0 + i) >> log2 Z == k``, at slots ``h(e, b0 + k) + j`` for
+    ``j < Z`` (mod |M|); ``off0`` is 0 when Z | d."""
     s = n_segments(dim, spec.block_size)
     lz = spec.log2_z
     x = rows.astype(jnp.uint32).reshape(-1)
@@ -117,13 +128,27 @@ def window_starts(spec: RobeSpec, table_ids: Tuple[int, ...],
         b_hi = hi >> lz
     off0 = (lo & jnp.uint32(spec.block_size - 1)).astype(jnp.int32)
     h = spec.hash_fn()
-    front = s * spec.block_size
     starts = []
     for k in range(s):
         k_hi, k_lo = add64(b_hi, b_lo, jnp.uint32(k))
-        base = h(t, k_hi, k_lo).astype(jnp.int32)
-        starts.append(front + base + off0 - k * spec.block_size)
+        starts.append(h(t, k_hi, k_lo).astype(jnp.int32))
     return jnp.stack(starts, axis=1), off0
+
+
+def window_starts(spec: RobeSpec, table_ids: Tuple[int, ...],
+                  rows: jnp.ndarray, dim: int
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Window starts [N, S] (int32, into the padded copy) and each pair's
+    offset inside its first block [N] for rows [B, F].
+
+    Window ``k`` starts at ``front + h(e, b0 + k) + off0 − k·Z`` so that
+    window lane ``i`` is element ``i`` (``front = S·Z`` keeps every start
+    ≥ 0)."""
+    base, off0 = block_starts(spec, table_ids, rows, dim)
+    z = spec.block_size
+    s = base.shape[1]
+    k = jnp.arange(s, dtype=jnp.int32) * z
+    return s * z + base + off0[:, None] - k, off0
 
 
 def _gather_kernel(n_seg: int, log2_z: int, dim: int, n_rows: int,
@@ -214,7 +239,7 @@ def robe_gather(memory: jnp.ndarray, rows: jnp.ndarray,
     b, f = rows.shape
     starts, off0 = window_starts(spec, table_ids, rows, dim)
     front = starts.shape[1] * spec.block_size
-    mem_rows = _circular_rows(memory, front,
+    mem_rows = _circular_rows(memory.astype(jnp.float32), front,
                               _padded_len(memory.shape[0], dim,
                                           spec.block_size))
     out = gather_windows(mem_rows, starts, off0, dim, spec.log2_z,
@@ -222,6 +247,104 @@ def robe_gather(memory: jnp.ndarray, rows: jnp.ndarray,
     if spec.use_sign:
         tids = jnp.asarray(table_ids, jnp.uint32)[None, :]
         out = out * robe_signs(spec, tids, rows, dim)
+    return out
+
+
+#: bytes of gathered table rows above which a batch is gathered in pieces
+CHUNK_BYTES = 1 << 31
+
+
+#: slots between neighbouring rows of the block gather's table, which then
+#: holds the array 128 / ROW_STRIDE = 16 times; a block takes
+#: log2(ROW_STRIDE) = 3 select stages (on a v5e, 8 was faster than 16 at
+#: 65,536 rows of d = 128 and 262,144 rows of d = 64)
+ROW_STRIDE = 8
+
+
+def strided_rows(memory: jnp.ndarray, stride: int, width: int
+                 ) -> jnp.ndarray:
+    """The circular array as overlapping rows, in memory.dtype: row
+    ``i·R + a`` holds slots ``128·a + stride·i + j`` (mod |M|) for
+    ``j < width``, ``a < R = ceil(|M| / 128)`` and ``i < 128 / stride``
+    (``stride`` divides 128, ``width`` is a multiple of 128).  Each of the
+    ``128 / stride`` parts is a lane-dense copy of the ``[R, 128]``
+    array, shifted by ``stride·i`` slots."""
+    m = memory.shape[0]
+    q = width // LANES
+    n = (m - 1) // LANES + 1                   # 128-slot rows holding a start
+    flat = _circular_rows(memory, 0, (n + q) * LANES)
+    ext = jnp.concatenate([flat[j:j + n] for j in range(q + 1)], axis=1)
+    return jnp.concatenate([ext[:, stride * i:stride * i + width]
+                            for i in range(LANES // stride)])
+
+
+def _shift_lanes(x: jnp.ndarray, shift: jnp.ndarray, width: int, top: int,
+                 unit: int = 1) -> jnp.ndarray:
+    """``out[n, j] = x[n, shift[n] + j]`` for ``j < width``, where each
+    ``shift[n]`` is a multiple of ``unit`` below ``top`` (powers of two)
+    and ``x`` has at least ``width + top − unit`` lanes: one select stage
+    per bit of the shift, each narrowing ``x`` to what is left."""
+    s = top // 2
+    while s >= unit:
+        need = width + s - unit
+        x = jnp.where((shift & s)[:, None] != 0, x[:, s:s + need],
+                      x[:, :need])
+        s //= 2
+    return x[:, :width]
+
+
+def gather_blocks(table: jnp.ndarray, starts: jnp.ndarray, z: int,
+                  stride: int) -> jnp.ndarray:
+    """[K] block starts in [0, |M|) -> [K, Z]: slots ``start + j`` (mod
+    |M|) for ``j < Z``, one row of ``strided_rows`` per block (the row
+    that begins at ``start`` rounded down to ``stride``), aligned by
+    ``start % stride``."""
+    log2_lanes = LANES.bit_length() - 1
+    part = (starts & (LANES - 1)) >> (stride.bit_length() - 1)
+    row = part * (table.shape[0] * stride // LANES) + (starts >> log2_lanes)
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=(1,), collapsed_slice_dims=(0,), start_index_map=(0,))
+    win = jax.lax.gather(table, row[:, None], dnums, (1, table.shape[1]),
+                         mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    return _shift_lanes(win, starts & (stride - 1), z, stride)
+
+
+def robe_lookup_blocks(memory: jnp.ndarray, rows: jnp.ndarray,
+                       table_ids: Tuple[int, ...], dim: int,
+                       spec: RobeSpec) -> jnp.ndarray:
+    """[B, F] rows -> [B, F, dim] in memory.dtype by whole Z-blocks: one
+    hash and one gathered table row per block, bit-identical to
+    ``repro.core.robe.robe_lookup``.  When Z | d every row is its
+    ``d / Z`` blocks end to end; otherwise its ``d`` elements start at
+    ``off0`` inside the blocks laid end to end.  A batch whose gathered
+    rows pass ``CHUNK_BYTES`` is gathered in pieces of rows, which bounds
+    the temporaries."""
+    b, f = rows.shape
+    z = spec.block_size
+    s = n_segments(dim, z)
+    width = round_up(z + ROW_STRIDE - 1, LANES)
+    pieces = -(-b * f * s * width * memory.dtype.itemsize // CHUNK_BYTES)
+    b_c = -(-b // pieces)
+
+    def piece(part):                                    # [b_c, F]
+        starts, off0 = block_starts(spec, table_ids, part, dim)
+        n = starts.shape[0]
+        # block-major order: the starts and the gathered rows stay in the
+        # layout the hash computes them in
+        out = gather_blocks(table, starts.T.reshape(-1), z, ROW_STRIDE)
+        out = out.reshape(s, n, z).transpose(1, 0, 2).reshape(n, s * z)
+        if dim % z:
+            out = _shift_lanes(out, off0, dim, z, math.gcd(dim, z))
+        return out.reshape(part.shape[0], f * dim)
+
+    with jax.named_scope("robe_blocks"):
+        table = strided_rows(memory, ROW_STRIDE, width)
+        out = [piece(rows[k:k + b_c]) for k in range(0, b, b_c)]
+        out = (jnp.concatenate(out) if len(out) > 1 else out[0]
+               ).reshape(b, f, dim)
+    if spec.use_sign:
+        tids = jnp.asarray(table_ids, jnp.uint32)[None, :]
+        out = out * robe_signs(spec, tids, rows, dim).astype(out.dtype)
     return out
 
 

@@ -1,13 +1,20 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype/Z sweeps (interpret mode)."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype/Z sweeps (interpret mode),
+and the jnp block gather against the element-wise definition."""
+
+import importlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.robe import RobeSpec
+from repro.core.robe import RobeSpec, robe_signs, robe_slots
+from repro.core.robe import robe_lookup as core_robe_lookup
 from repro.kernels import ref
 from repro.kernels.ops import dot_interaction, robe_lookup
+
+# ``repro.kernels`` re-exports the op under the module's name
+robe_lookup_module = importlib.import_module("repro.kernels.robe_lookup")
 
 
 @pytest.mark.parametrize("b,f,d,z,sign,dtype", [
@@ -117,6 +124,109 @@ def test_robe_lookup_wraps_circularly():
     want = ref.robe_lookup_ref(mem, rows, jnp.zeros(1, jnp.uint32), 32, spec)
     got = robe_lookup(mem, rows, (0,), 32, spec, True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want))
+
+
+def _block_gather_case(d, z, dtype, sign, b=13, f=3):
+    """A prime batch over an array of 3·Z + 5 slots, so that blocks run
+    past its end; ids span the 64-bit element index."""
+    rs = np.random.RandomState(d * 1000 + z)
+    m = 3 * z + 5
+    spec = RobeSpec(size=m, block_size=z, seed=5, use_sign=sign)
+    mem = jnp.asarray(rs.randn(m), dtype)
+    rows = jnp.asarray(rs.randint(0, 2 ** 31 - 1, (b, f)), jnp.int32)
+    want = core_robe_lookup(mem, spec, jnp.arange(f, dtype=jnp.uint32)[None],
+                            rows, d)
+    return mem, rows, tuple(range(f)), spec, want
+
+
+@pytest.mark.parametrize("sign", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("d,z", [(64, 32), (128, 32), (32, 32), (16, 8),
+                                 (16, 32), (48, 32)])
+def test_robe_lookup_block_gather_is_exact(d, z, dtype, sign):
+    """The jnp forward gathers whole Z-blocks (Z | d: the blocks end to
+    end; Z ∤ d: d elements from inside them) and returns exactly the
+    element-wise definition's values, in the memory's dtype."""
+    mem, rows, tids, spec, want = _block_gather_case(d, z, dtype, sign)
+    got = robe_lookup(mem, rows, tids, d, spec, False)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("d,z", [(64, 32), (48, 32)])
+def test_robe_lookup_block_gather_in_pieces_is_exact(monkeypatch, d, z):
+    """A batch whose gathered rows pass ``CHUNK_BYTES`` is gathered in
+    pieces of rows, the last one shorter: still exact."""
+    monkeypatch.setattr(robe_lookup_module, "CHUNK_BYTES", 1 << 14)
+    mem, rows, tids, spec, want = _block_gather_case(d, z, jnp.float32,
+                                                     True, b=37)
+    pieces = -(-37 * 3 * 2 * 128 * 4 // robe_lookup_module.CHUNK_BYTES)
+    assert 1 < pieces and 37 % pieces
+    got = robe_lookup(mem, rows, tids, d, spec, False)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("b", [1, 100, 130, 300])
+def test_robe_lookup_block_gather_batch_sizes(b):
+    """From a single row to a batch whose blocks outnumber the array's
+    slots, the block gather's table of strided rows gives every block
+    exactly."""
+    rs = np.random.RandomState(b)
+    spec = RobeSpec(size=4099, block_size=32, seed=9)
+    mem = jnp.asarray(rs.randn(spec.size), jnp.float32)
+    rows = jnp.asarray(rs.randint(0, 2 ** 31 - 1, (b, 1)), jnp.int32)
+    want = core_robe_lookup(mem, spec, 0, rows, 64)
+    got = robe_lookup(mem, rows, (0,), 64, spec, False)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _lookup_probe():
+    """``tools/lookup_probe.py``, which times the forward's formulations
+    on a chip, loaded as a module."""
+    import importlib.util
+    import pathlib
+    path = pathlib.Path(__file__).parents[1] / "tools" / "lookup_probe.py"
+    spec = importlib.util.spec_from_file_location("lookup_probe", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("name", ["blocks", "blocks-bf16", "a", "b", "f",
+                                  "s8", "s16", "s32", "s64"])
+def test_lookup_probe_formulations_are_exact(name):
+    """Every formulation the probe times returns the element gather's
+    values, blocks running past the array's end included."""
+    probe = _lookup_probe()
+    spec = RobeSpec(size=4099, block_size=32, seed=11)
+    vocab = np.array([1000, 50000, 7, 300000])
+    mem = jax.random.normal(jax.random.PRNGKey(0), (spec.size,)) * 0.01
+    ids = jnp.asarray(probe.draw_ids(vocab, 37, 3))
+    want = probe.variant("elem", spec, len(vocab), 64)(mem, ids)
+    got = jax.jit(probe.variant(name, spec, len(vocab), 64))(mem, ids)
+    assert got.shape == want.shape == (37, 4, 64)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32),
+        np.asarray(want.astype(got.dtype), np.float32))
+
+
+def test_robe_lookup_grad_scatter_adds_the_cotangent():
+    """The VJP scatter-adds every element's signed cotangent into its
+    slot, whichever forward ran."""
+    rs = np.random.RandomState(6)
+    spec = RobeSpec(size=101, block_size=32, seed=2, use_sign=True)
+    mem = jnp.asarray(rs.randn(101), jnp.float32)
+    rows = jnp.asarray(rs.randint(0, 2 ** 31 - 1, (7, 3)), jnp.int32)
+    ct = rs.randn(7, 3, 64)
+    g = jax.grad(lambda m: (robe_lookup(m, rows, (0, 1, 2), 64, spec, False)
+                            * ct).sum())(mem)
+    tids = jnp.arange(3, dtype=jnp.uint32)[None]
+    slots = np.asarray(robe_slots(spec, tids, rows, 64)).reshape(-1)
+    signs = np.asarray(robe_signs(spec, tids, rows, 64)).reshape(-1)
+    want = np.zeros(101)
+    np.add.at(want, slots, ct.reshape(-1) * signs)
+    np.testing.assert_allclose(np.asarray(g), want, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("b,f,d,self_i,dtype", [

@@ -3,8 +3,9 @@ programs carry as op metadata, and the host spans that serving and
 training write into a ``jax.profiler`` trace.
 
 Device scopes (``jax.named_scope``): ``embedding``, ``bottom_mlp``,
-``interaction``, ``top_mlp`` in ``models/recsys.py`` and ``optimizer`` in
-``train/train_loop.py``.  Host spans (``jax.profiler.TraceAnnotation``):
+``interaction``, ``top_mlp`` in ``models/recsys.py``, ``optimizer`` in
+``train/train_loop.py``, and ``robe_blocks`` (the ROBE lookup's block
+gather, inside ``embedding``) in ``kernels/robe_lookup.py``.  Host spans (``jax.profiler.TraceAnnotation``):
 ``serve.h2d``/``serve.run``/``serve.d2h`` in ``EmbeddingServer.score`` and
 ``train.h2d``/``train.run``/``train.sync`` in ``train_loop.run``.
 """
@@ -71,21 +72,29 @@ def server():
 
 
 @pytest.fixture(scope="module")
-def compiled(server):
-    """{"serve" | "train": (module name, [(opcode, op_name), ...])}."""
+def programs(server):
+    """{"serve" | "train": (module name, the compiled program's text)}."""
     b = {k: jnp.asarray(v) for k, v in _batch().items()}
     serve = server._jit["robe"].lower(
         server.params("robe"), {"dense": b["dense"], "sparse": b["sparse"]})
     step, state, _, _ = _train_step()
+    return {kind: (lowered.as_text().split()[1], lowered.compile().as_text())
+            for kind, lowered in (("serve", serve),
+                                  ("train", step.lower(state, b)))}
+
+
+@pytest.fixture(scope="module")
+def compiled(programs):
+    """{"serve" | "train": (module name, [(opcode, op_name), ...])}."""
     out = {}
-    for kind, lowered in (("serve", serve), ("train", step.lower(state, b))):
+    for kind, (module, text) in programs.items():
         ops = []
-        for line in lowered.compile().as_text().splitlines():
+        for line in text.splitlines():
             m = _INSTR.match(line)
             if m:
                 name = _OP_NAME.search(line)
                 ops.append((m.group(1), name.group(1) if name else None))
-        out[kind] = (lowered.as_text().split()[1], ops)
+        out[kind] = (module, ops)
     return out
 
 
@@ -123,6 +132,30 @@ def test_embedding_forward_and_backward_split_by_transpose(compiled,
     assert "gather" in fwd and "scatter" not in fwd
     assert "scatter" in bwd and "gather" not in bwd
     assert all("transpose(" in n for op, n in emb if op == "scatter")
+
+
+@pytest.mark.parametrize("kind", ["serve", "train"])
+def test_lookup_gathers_whole_blocks(programs, innermost, kind):
+    """The lookup's forward gathers whole Z-blocks: its ops lie under
+    ``embedding/robe_blocks``, which the benchmark counts under
+    ``embedding``, and no gather fetches single slots, one per element
+    of the [B, F, d] embeddings."""
+    _, text = programs[kind]
+    blocks = {n for n in _OP_NAME.findall(text)
+              if re.search(r"embedding\)?/robe_blocks/", n)}
+    assert blocks
+    assert {innermost(n) for n in blocks} == {("embedding", False)}
+    per_slot = [line for line in text.splitlines()
+                if " gather(" in line and "slice_sizes={1}" in line
+                and _elements(line) == BATCH * len(VOCABS) * 16]
+    assert not per_slot, per_slot
+
+
+def _elements(line: str) -> int:
+    """Elements of the array an instruction line of a compiled module
+    defines."""
+    dims = re.search(r"= \w+\[([\d,]*)\]", line).group(1)
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
 
 
 @pytest.mark.parametrize("kind", ["serve", "train"])

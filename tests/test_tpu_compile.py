@@ -76,6 +76,23 @@ def test_robe_lookup_compiles_for_v5e(one_chip, width):
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_robe_lookup_block_gather_compiles_for_v5e(one_chip, width):
+    """The default (jnp) forward: the compiler keeps it a gather of whole
+    table rows, one per Z-block, and gathers no single slots."""
+    from repro.kernels.ops import robe_lookup
+    cfg, b, f, d = _recsys(width)
+    spec = cfg.embedding_spec().robe
+    tids = tuple(range(f))
+    text = jax.jit(lambda m, r: robe_lookup(m, r, tids, d, spec, False)).lower(
+        _shape((spec.size,), jnp.float32, one_chip),
+        _shape((b, f), jnp.int32, one_chip)).compile().as_text()
+    gathers = [line for line in text.splitlines() if " gather(" in line]
+    assert any("slice_sizes={1,128}" in line and "robe_blocks" in line
+               for line in gathers), gathers
+    assert not [line for line in gathers if "slice_sizes={1}" in line]
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
 def test_dot_interaction_compiles_for_v5e(one_chip, width):
     from repro.kernels.dot_interaction import dot_interaction_pallas
     _, b, f, d = _recsys(width)
