@@ -1,4 +1,5 @@
-"""The four assigned recsys architectures + the paper's CriteoTB DLRM.
+"""The four assigned recsys architectures + the paper's CriteoTB DLRM and
+the MLPerf DLRM-DCNv2.
 
 Vocab layouts:
 * CriteoTB (MLPerf, 40M row cap — the paper's 100 GB model): 26 fields,
@@ -36,6 +37,12 @@ CRITEO_39 = tuple([64] * 13) + CRITEO_KAGGLE_VOCABS
 
 TWO_TOWER_VOCABS = (100_000_000, 1_000_000, 100_000, 10_000,   # user side
                     10_000_000, 1_000_000, 100_000, 1_000)     # item side
+
+# MLPerf DLRM-DCNv2 multi-hot ids per sample of each CriteoTB field (214 in
+# all; mlcommons/training recommendation_v2/torchrec_dlrm, --multi_hot_sizes)
+CRITEO_TB_MULTIHOT = (
+    3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100, 27, 10,
+    3, 1, 1)
 
 SMOKE_VOCABS = (1000, 500, 2000, 100, 50, 300)
 
@@ -110,3 +117,22 @@ _bundle("dlrm-criteo-tb",
                       robe_size=8192, robe_block=16),
         notes="paper §4.1: official MLPerf DLRM; target AUC 0.8025; "
               "ROBE 1000× ⇒ 26.1M slots ≈ 100MB.")
+
+# --- MLPerf DLRM-DCNv2: multi-hot CriteoTB, 3 low-rank cross layers ------
+_bundle("dlrm-dcnv2",
+        full_kw=dict(arch="dlrm_dcnv2", vocab_sizes=CRITEO_TB_VOCABS,
+                     embed_dim=128, n_dense=13, bot_mlp=(512, 256, 128),
+                     top_mlp=(1024, 1024, 512, 256, 1), cross_layers=3,
+                     cross_rank=512, bag_sizes=CRITEO_TB_MULTIHOT),
+        smoke_kw=dict(arch="dlrm_dcnv2", vocab_sizes=SMOKE_VOCABS,
+                      embed_dim=16, n_dense=13, bot_mlp=(64, 16),
+                      top_mlp=(32, 1), cross_layers=2, cross_rank=8,
+                      bag_sizes=(3, 1, 2, 1, 5, 2), robe_size=8192,
+                      robe_block=16),
+        shapes={"train_batch": dict(kind="train", batch=65536),
+                "serve_bulk": dict(kind="serve", batch=16384)},
+        notes="MLPerf Training v3.0+ / Inference v3.1+ recommendation "
+              "model (mlcommons/training recommendation_v2/torchrec_dlrm): "
+              "214 multi-hot ids a sample over the 26 fields, x0 of 3,456 "
+              "through 3 cross layers of rank 512; ROBE 1000× ⇒ 26.1M "
+              "slots, the array of dlrm-criteo-tb.")

@@ -178,7 +178,7 @@ _RS_OPT = {
 
 
 def _recsys_batch(cfg, batch: int, ctx, spec_axes):
-    shapes = {"sparse": SDS((batch, cfg.n_fields), jnp.int32)}
+    shapes = {"sparse": SDS((batch, cfg.n_columns), jnp.int32)}
     specs = {"sparse": P(spec_axes, None)}
     if cfg.n_dense:
         shapes["dense"] = SDS((batch, cfg.n_dense), jnp.float32)

@@ -1,9 +1,17 @@
-"""RecSys family: DLRM, AutoInt, xDeepFM, DeepFM, DCN, FiBiNET, Two-Tower.
+"""RecSys family: DLRM, DLRM-DCNv2, AutoInt, xDeepFM, DeepFM, DCN,
+FiBiNET, Two-Tower.
 
 All share the embedding front-end (``EmbeddingSpec`` + a registered
 ``EmbeddingBackend``: full / robe / hashed / tt — the paper's comparison
 axis as a pluggable substrate) and differ in the interaction op.
-Batch layout: dense features [B, n_dense] float, sparse ids [B, F] int32.
+Batch layout: dense features [B, n_dense] float, sparse ids [B, F] int32
+— or, for multi-hot fields (``RecsysConfig.bag_sizes``), [B, ΣL] id
+columns, field ``f``'s ``bag_sizes[f]`` ids side by side, pooled by sum.
+
+DLRM-DCNv2 (the MLPerf recommendation model; Wang et al., arXiv:2008.13535)
+keeps DLRM's bottom and top MLPs and replaces the dot interaction with
+``cross_layers`` low-rank cross layers over ``x0 = [bottom output,
+flattened pooled embeddings]``.
 
 Outputs are logits [B] (CTR models) or (user_vec, item_vec) (two-tower).
 """
@@ -20,18 +28,20 @@ from repro.core.robe import RobeSpec
 from repro.dist import api as dist
 from repro.nn.core import dense_apply, dense_init, mlp_apply, mlp_init
 from repro.nn.embeddings import EmbeddingSpec, embedding_init, \
-    embedding_lookup, embedding_lookup_dist, get_backend
+    embedding_lookup, embedding_lookup_dist, get_backend, pool_bags
 from repro.nn.interactions import (autoint_layer_apply, autoint_layer_init,
                                    bilinear_apply, bilinear_init, cin_apply,
                                    cin_init, cross_net_apply, cross_net_init,
                                    dot_interaction_op, fm_interaction,
+                                   low_rank_cross_apply, low_rank_cross_init,
                                    senet_apply, senet_init)
 
 
 @dataclasses.dataclass(frozen=True)
 class RecsysConfig:
     name: str
-    arch: str                        # dlrm|autoint|xdeepfm|deepfm|dcn|fibinet|two_tower
+    arch: str                        # dlrm|dlrm_dcnv2|autoint|xdeepfm|deepfm|
+    #   dcn|fibinet|two_tower
     vocab_sizes: Tuple[int, ...]
     embed_dim: int
     n_dense: int = 0
@@ -40,6 +50,7 @@ class RecsysConfig:
     dnn: Tuple[int, ...] = ()        # deep branch (deepfm/xdeepfm/dcn/…)
     cin_layers: Tuple[int, ...] = ()
     cross_layers: int = 0
+    cross_rank: int = 0              # dlrm_dcnv2: rank of each cross layer
     attn_layers: int = 0
     attn_dim: int = 0
     attn_heads: int = 0
@@ -57,6 +68,8 @@ class RecsysConfig:
     full_table_shard: str = "model"  # "model" | "2d" (rows over ALL devices;
     # kills the data-axis dense table-grad all-reduce — §Perf iteration)
     compute_dtype: object = jnp.float32
+    bag_sizes: Tuple[int, ...] = ()  # multi-hot ids per field, summed
+    #   within the field; () = one id per field
 
     def embedding_spec(self) -> EmbeddingSpec:
         robe = None
@@ -73,11 +86,16 @@ class RecsysConfig:
                              robe=robe, use_kernel=self.use_kernel,
                              placement=placement,
                              hashed_buckets=self.hashed_buckets,
-                             tt_rank=self.tt_rank)
+                             tt_rank=self.tt_rank, bag_sizes=self.bag_sizes)
 
     @property
     def n_fields(self) -> int:
         return len(self.vocab_sizes)
+
+    @property
+    def n_columns(self) -> int:
+        """Id columns of a batch's ``sparse``: ΣL, or one per field."""
+        return sum(self.bag_sizes) or self.n_fields
 
 
 # ---------------------------------------------------------------------------
@@ -95,6 +113,12 @@ def init_params(key, cfg: RecsysConfig) -> dict:
         p["bot"] = mlp_init(ks[1], (cfg.n_dense,) + cfg.bot_mlp)
         n_pairs = (f + 1) * f // 2          # F embeddings + bottom output
         p["top"] = mlp_init(ks[2], (cfg.bot_mlp[-1] + n_pairs,) + cfg.top_mlp)
+    elif a == "dlrm_dcnv2":
+        p["bot"] = mlp_init(ks[1], (cfg.n_dense,) + cfg.bot_mlp)
+        x0 = cfg.bot_mlp[-1] + f * d
+        p["cross"] = low_rank_cross_init(ks[2], x0, cfg.cross_rank,
+                                         cfg.cross_layers)
+        p["top"] = mlp_init(ks[3], (x0,) + cfg.top_mlp)
     elif a == "autoint":
         p["attn"] = [autoint_layer_init(
             jax.random.fold_in(ks[1], i),
@@ -156,8 +180,15 @@ def _batch_emb(params, cfg: RecsysConfig, batch: dict) -> jnp.ndarray:
     emb = batch.get("emb")
     if emb is not None:
         with jax.named_scope("embedding"):
-            return jnp.asarray(emb).astype(cfg.compute_dtype)
+            emb = pool_bags(jnp.asarray(emb), cfg.embedding_spec())
+            return emb.astype(cfg.compute_dtype)
     return _embed(params, cfg, batch["sparse"])
+
+
+def _bottom(params, cfg: RecsysConfig, batch: dict) -> jnp.ndarray:
+    with jax.named_scope("bottom_mlp"):
+        dense = batch["dense"].astype(cfg.compute_dtype)
+        return mlp_apply(params["bot"], dense, final_act=jax.nn.relu)
 
 
 def forward(params, cfg: RecsysConfig, batch: dict) -> jnp.ndarray:
@@ -170,13 +201,22 @@ def forward(params, cfg: RecsysConfig, batch: dict) -> jnp.ndarray:
 
     Each DLRM layer runs under its own ``jax.named_scope`` (``embedding``,
     ``bottom_mlp``, ``interaction``, ``top_mlp``), so a profile names the
-    layer of every device op, forward and backward.
+    layer of every device op, forward and backward; DLRM-DCNv2's cross
+    layers run under ``interaction/cross``, its pooling under
+    ``embedding/bag_pool``.
     """
     a = cfg.arch
+    if a == "dlrm_dcnv2":
+        bot = _bottom(params, cfg, batch)
+        emb = _batch_emb(params, cfg, batch)
+        with jax.named_scope("interaction"):
+            x0 = jnp.concatenate([bot, emb.reshape(emb.shape[0], -1)], -1)
+            with jax.named_scope("cross"):
+                x = low_rank_cross_apply(params["cross"], x0)
+        with jax.named_scope("top_mlp"):
+            return mlp_apply(params["top"], x)[:, 0]
     if a == "dlrm":
-        with jax.named_scope("bottom_mlp"):
-            dense = batch["dense"].astype(cfg.compute_dtype)
-            bot = mlp_apply(params["bot"], dense, final_act=jax.nn.relu)
+        bot = _bottom(params, cfg, batch)
         emb = _batch_emb(params, cfg, batch)
         with jax.named_scope("interaction"):
             # [B, (F+1)·F/2] triangle of the gram of [bot; embeddings]

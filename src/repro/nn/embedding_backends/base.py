@@ -11,9 +11,9 @@ a substrate hangs off the backend object:
 * ``init(key, spec, pad_rows_to)``      -> parameter pytree
 * ``lookup(params, spec, idx, fields)`` -> [B, F', dim] embeddings
 * ``lookup_bag(params, spec, idx, ...)``-> pooled multi-hot lookups
-* ``lookup_dist(params, spec, idx)``    -> the distributed lookup under the
-  active ``repro.dist`` context (shard_map bodies live in the backend, not
-  in the model)
+* ``lookup_dist(params, spec, idx)``    -> the distributed lookup of every
+  id column (``spec.columns``) under the active ``repro.dist`` context
+  (shard_map bodies live in the backend, not in the model)
 * ``param_specs(spec, rules, mesh=None)`` -> PartitionSpec tree for the
   parameter pytree (consumed by ``repro.dist.param_specs.recsys_specs``);
   ``mesh`` re-resolves the layout against a concrete — possibly degraded —
@@ -135,10 +135,12 @@ class EmbeddingBackend:
 
         Default: parameters are replicated and lookups purely local, so the
         batch (and the [B, F, dim] activation) shards over the whole mesh
-        when divisible — zero embedding collectives.
+        when divisible — zero embedding collectives.  ``idx`` holds one
+        column per id (``spec.columns`` names each column's field); the
+        caller pools multi-hot fields.
         """
         from repro.dist import api as dist
-        emb = self.lookup(params, spec, idx)
+        emb = self.lookup(params, spec, idx, spec.columns)
         ctx = dist.current()
         if ctx is not None and idx.shape[0] % ctx.n_devices == 0:
             emb = dist.shard(emb, "flat_batch", None, None)

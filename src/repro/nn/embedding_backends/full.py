@@ -81,8 +81,9 @@ class FullTableBackend(EmbeddingBackend):
         from repro.dist import api as dist
         ctx = dist.current()
         batch = idx.shape[0]
+        cols = spec.columns
         if ctx is None:
-            return self.lookup(params, spec, idx)
+            return self.lookup(params, spec, idx, cols)
         n_model = ctx.mesh.shape["model"]
         n_data = ctx.dp_size
         table = params["table"]
@@ -101,7 +102,8 @@ class FullTableBackend(EmbeddingBackend):
                 # shards' rows so this device can serve the whole global
                 # batch
                 ix_all = jax.lax.all_gather(ix, dp_t, axis=0, tiled=True)
-                g = jnp.asarray(spec.offsets, jnp.int32)[None, :] + ix_all
+                g = jnp.asarray(spec.column_offsets, jnp.int32)[None, :] \
+                    + ix_all
                 lin = jax.lax.axis_index(all_axes)
                 local = g - lin * shard_rows
                 hit = (local >= 0) & (local < shard_rows)
@@ -126,9 +128,10 @@ class FullTableBackend(EmbeddingBackend):
 
             def body(tb, ix):
                 if scatter_ok:
-                    return full_lookup_sharded_body(tb, ix, spec.offsets,
+                    return full_lookup_sharded_body(tb, ix,
+                                                    spec.column_offsets,
                                                     "model", shard_rows)
-                g = jnp.asarray(spec.offsets, jnp.int32)[None, :] + ix
+                g = jnp.asarray(spec.column_offsets, jnp.int32)[None, :] + ix
                 m_idx = jax.lax.axis_index("model")
                 local = g - m_idx * shard_rows
                 hit = (local >= 0) & (local < shard_rows)
@@ -144,7 +147,7 @@ class FullTableBackend(EmbeddingBackend):
                 in_specs=(P("model", None), P(dp, None)),
                 out_specs=out_spec)(table, idx)
 
-        return self.lookup(params, spec, idx)
+        return self.lookup(params, spec, idx, cols)
 
     def param_specs(self, spec, rules, mesh=None) -> dict:
         dp = axes_tuple(rules.get("batch"))

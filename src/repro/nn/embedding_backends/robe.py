@@ -6,9 +6,9 @@ Pallas lookup).  Placement (``spec.placement``):
 
 * ``"default"`` / ``"replicated"`` — the array is tiny (~100 MB for the
   paper's CriteoTB model), so it is replicated and lookups are purely
-  local: the embedding-exchange collective disappears and only the
-  |M|-sized gradient all-reduce remains.  Batches shard over the whole
-  mesh.
+  local (a ``shard_map`` over the batch axes): the embedding-exchange
+  collective disappears and only the |M|-sized gradient all-reduce
+  remains.  Batches shard over the whole mesh.
 * ``"model"`` — ZeRO-3 style, for ROBE arrays beyond a replica's HBM
   (beyond-paper extension): the array is sharded over `model` and
   all-gathered once per step before the (still-local) lookups; the
@@ -73,9 +73,11 @@ class RobeBackend(EmbeddingBackend):
     def lookup_dist(self, params, spec, idx, *, compute_dtype=None):
         from repro.dist import api as dist
         ctx = dist.current()
-        if ctx is None or spec.placement != "model":
+        if ctx is None:
             return super().lookup_dist(params, spec, idx,
                                        compute_dtype=compute_dtype)
+        if spec.placement != "model":
+            return self._lookup_replicated(params, spec, idx, ctx)
         # ZeRO-3 path: memory sharded over `model`, gathered per step
         mem = params["memory"]
         n_model = ctx.mesh.shape["model"]
@@ -87,7 +89,7 @@ class RobeBackend(EmbeddingBackend):
                                        compute_dtype=compute_dtype)
         dp = ctx.rules.get("batch")
         every = axes_tuple(dp) + ("model",)
-        fields = tuple(range(spec.n_fields))
+        fields = spec.columns
 
         def body(mem_shard, ix):
             from repro.kernels.ops import robe_lookup
@@ -105,6 +107,31 @@ class RobeBackend(EmbeddingBackend):
             body, mesh=ctx.mesh,
             in_specs=(P("model"), P(every, None)),
             out_specs=P(every, None, None))(mem, idx)
+
+    def _lookup_replicated(self, params, spec, idx, ctx):
+        """Each device looks up its own rows of the batch in its own copy
+        of the array: nothing crosses devices forward, and on transpose
+        the per-device gradients of the array psum over the mesh (the
+        data-parallel all-reduce).  A batch that does not split over every
+        device is left to the compiler."""
+        from repro.dist import api as dist
+        every = axes_on_mesh(axes_tuple(ctx.rules.get("flat_batch")),
+                             ctx.mesh)
+        if not every or idx.shape[0] % ctx.n_devices:
+            return super().lookup_dist(params, spec, idx)
+
+        def body(mem, ix):
+            from repro.kernels.ops import robe_lookup
+            return robe_lookup(mem, ix, spec.columns, spec.dim, spec.robe,
+                               spec.use_kernel)
+
+        # unchecked: the transpose then psums the array's per-device
+        # cotangent over the mesh (the Pallas lookup states no varying axes)
+        emb = jax.shard_map(
+            body, mesh=ctx.mesh, in_specs=(P(), P(every, None)),
+            out_specs=P(every, None, None),
+            check_vma=False)(params["memory"], idx)
+        return dist.shard(emb, "flat_batch", None, None)
 
     def param_specs(self, spec, rules, mesh=None) -> dict:
         if spec.placement == "model":

@@ -27,6 +27,12 @@ cost model — ``get_backend(spec.kind)`` is the only dispatch point.
 are thin wrappers over the backend so existing callers keep working.  JAX
 has no EmbeddingBag: multi-hot lookups are gather + segment reduction in
 every backend, as the assignment requires.
+
+Multi-hot fields (``EmbeddingSpec.bag_sizes``): a model whose field ``f``
+carries ``bag_sizes[f]`` ids a sample takes its ids as ``[B, ΣL]``
+columns, each tied to its field (``EmbeddingSpec.columns``).  The backend
+looks every column up under its own field, and ``pool_bags`` sums each
+field's columns (``embedding_lookup_dist``) to ``[B, F, dim]``.
 """
 
 from __future__ import annotations
@@ -46,8 +52,8 @@ from repro.nn.embedding_backends import (backend_names,            # noqa: F401
                                          robe_allgather_body)
 
 __all__ = ["EmbeddingSpec", "embedding_init", "embedding_lookup",
-           "embedding_lookup_bag", "embedding_lookup_dist", "get_backend",
-           "backend_names", "full_lookup_sharded_body",
+           "embedding_lookup_bag", "embedding_lookup_dist", "pool_bags",
+           "get_backend", "backend_names", "full_lookup_sharded_body",
            "robe_allgather_body"]
 
 
@@ -64,10 +70,14 @@ class EmbeddingSpec:
     #   robe: "default" replicated | "model" ZeRO-3 sharded + all-gather
     hashed_buckets: int = 0               # QR remainder buckets (0 = auto)
     tt_rank: int = 0                      # TT core rank (0 = default 8)
+    bag_sizes: Tuple[int, ...] = ()       # ids a sample carries per field,
+    #   summed within the field; () = one id per field
 
     def __post_init__(self):
         object.__setattr__(self, "vocab_sizes",
                            tuple(int(v) for v in self.vocab_sizes))
+        object.__setattr__(self, "bag_sizes",
+                           tuple(int(n) for n in self.bag_sizes))
         if not self.vocab_sizes:
             raise ValueError("vocab_sizes must be non-empty")
         if any(v <= 0 for v in self.vocab_sizes):
@@ -75,6 +85,11 @@ class EmbeddingSpec:
                              f"{self.vocab_sizes}")
         if self.dim <= 0:
             raise ValueError(f"dim must be positive, got {self.dim}")
+        if self.bag_sizes and (len(self.bag_sizes) != len(self.vocab_sizes)
+                               or min(self.bag_sizes) < 1):
+            raise ValueError(f"bag_sizes must give every one of the "
+                             f"{len(self.vocab_sizes)} fields at least one "
+                             f"id, got {self.bag_sizes}")
         get_backend(self.kind).validate(self)
 
     @property
@@ -91,6 +106,18 @@ class EmbeddingSpec:
         Cached: lookups index this on every trace."""
         return np.concatenate([[0], np.cumsum(self.vocab_sizes)[:-1]]
                               ).astype(np.int64)
+
+    @functools.cached_property
+    def columns(self) -> Tuple[int, ...]:
+        """The field of each id column of a batch: ``range(n_fields)``, or
+        field ``f`` repeated ``bag_sizes[f]`` times."""
+        sizes = self.bag_sizes or (1,) * self.n_fields
+        return tuple(f for f, n in enumerate(sizes) for _ in range(n))
+
+    @functools.cached_property
+    def column_offsets(self) -> np.ndarray:
+        """Per-column row offsets into the concatenated logical table."""
+        return self.offsets[list(self.columns)]
 
     @property
     def param_count(self) -> int:
@@ -133,10 +160,40 @@ def embedding_lookup_bag(params: dict, spec: EmbeddingSpec,
                                              weights=weights)
 
 
+def pool_bags(emb: jnp.ndarray, spec: EmbeddingSpec) -> jnp.ndarray:
+    """[B, ΣL, dim] rows of every id column -> [B, F, dim]: the sum, in
+    float32 and column order, of each field's ``bag_sizes[f]`` columns (a
+    static segment sum, under the ``bag_pool`` scope).  One-hot specs pass
+    ``emb`` through.
+
+    The sum adds ``dim``-wide slices of the rows' flat ``[B, ΣL·dim]``
+    view (on a TPU a slice of the 3-D array along its column axis takes a
+    layout padded up to 128 times), and writes each field's sum into its
+    slot of the output: the compiler then keeps the sums in fusions of
+    their own under this scope, where it merges a concatenation of them
+    into the consumer's with no scope at all."""
+    if not spec.bag_sizes:
+        return emb
+    with jax.named_scope("bag_pool"):
+        b, c, d = emb.shape
+        flat = emb.astype(jnp.float32).reshape(b, c * d)
+        out = jnp.zeros((b, spec.n_fields * d), jnp.float32)
+        col = 0
+        for f, n in enumerate(spec.bag_sizes):
+            acc = flat[:, col * d:(col + 1) * d]
+            for j in range(col + 1, col + n):
+                acc = acc + flat[:, j * d:(j + 1) * d]
+            out = jax.lax.dynamic_update_slice(out, acc, (0, f * d))
+            col += n
+        return out.reshape(b, spec.n_fields, d)
+
+
 def embedding_lookup_dist(params: dict, spec: EmbeddingSpec,
                           idx: jnp.ndarray,
                           compute_dtype=None) -> jnp.ndarray:
-    """Distributed lookup under the active ``repro.dist`` context (local
-    lookup outside one).  The shard_map bodies live in the backends."""
-    return get_backend(spec.kind).lookup_dist(params, spec, idx,
-                                              compute_dtype=compute_dtype)
+    """idx [B, ΣL] id columns -> [B, F, dim] under the active ``repro.dist``
+    context (local lookup outside one).  The shard_map bodies live in the
+    backends; multi-hot fields are pooled after the lookup."""
+    emb = get_backend(spec.kind).lookup_dist(params, spec, idx,
+                                             compute_dtype=compute_dtype)
+    return pool_bags(emb, spec)

@@ -1,8 +1,9 @@
 """Feature-interaction operators for the recsys family.
 
-dot (DLRM), FM (DeepFM), CIN (xDeepFM), cross network (DCN),
-SENET + bilinear (FiBiNET), multi-head self-attention over fields (AutoInt).
-All take field embeddings [B, F, D].
+dot (DLRM), FM (DeepFM), CIN (xDeepFM), cross network (DCN), low-rank
+cross network (DCN-V2, in DLRM-DCNv2), SENET + bilinear (FiBiNET),
+multi-head self-attention over fields (AutoInt).  All take field
+embeddings [B, F, D], but the cross networks, which take the flat [B, N].
 """
 
 from __future__ import annotations
@@ -13,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.ops import dot_interaction
-from repro.nn.core import dense_apply, dense_init
+from repro.nn.core import dense_apply, dense_init, he_uniform
 
 
 # ---------------------------------------------------------------------------
@@ -39,6 +40,33 @@ def cross_net_apply(layers: list, x0: jnp.ndarray) -> jnp.ndarray:
     x = x0
     for p in layers:
         x = x0 * dense_apply(p, x) + x
+    return x
+
+
+# ---------------------------------------------------------------------------
+# DCN-V2 low-rank cross network (Wang et al. 2020, arXiv:2008.13535, Eq. 2
+# with W = U·V): x_{l+1} = x0 ⊙ (W_l (V_l x_l) + b_l) + x_l, V_l: N -> r
+# without bias, W_l: r -> N with bias
+# ---------------------------------------------------------------------------
+
+def low_rank_cross_init(key, dim: int, rank: int, n_layers: int) -> list:
+    """Per layer {"v": [dim, rank], "w": [rank, dim]} He-uniform over
+    their fan-in, {"b": [dim]} zero."""
+    out = []
+    for k in jax.random.split(key, n_layers):
+        kv, kw = jax.random.split(k)
+        out.append({"v": he_uniform(kv, (dim, rank)),
+                    "w": he_uniform(kw, (rank, dim)),
+                    "b": jnp.zeros((dim,), jnp.float32)})
+    return out
+
+
+def low_rank_cross_apply(layers: list, x0: jnp.ndarray) -> jnp.ndarray:
+    """x0 [B, N] -> [B, N] through every layer."""
+    x = x0
+    for p in layers:
+        low = x @ p["v"].astype(x.dtype)
+        x = x0 * (low @ p["w"].astype(x.dtype) + p["b"].astype(x.dtype)) + x
     return x
 
 

@@ -136,7 +136,8 @@ class HotRowCache:
 
     def lookup(self, idx: np.ndarray,
                n_valid: Optional[int] = None) -> np.ndarray:
-        """idx [B, F] int ids -> [B, F, dim] float32 rows (bit-exact).
+        """idx [B, C] int ids -> [B, C, dim] float32 rows (bit-exact), one
+        per id column (``spec.columns`` names each column's field).
 
         Rows ``>= n_valid`` are padding: gathered (the compiled shape
         downstream needs them) but never counted.
@@ -144,11 +145,12 @@ class HotRowCache:
         idx = np.asarray(idx, np.int64)
         b, f = idx.shape
         n_valid = b if n_valid is None else int(n_valid)
-        gids = idx + self._offsets[None, :f]
+        gids = idx + self.spec.column_offsets[None, :f]
         self.sketch.update(gids[:n_valid])
         out = np.empty((b, f, self.spec.dim), np.float32)
-        for field in range(f):
-            uniq, inv = np.unique(idx[:, field], return_inverse=True)
+        for col in range(f):
+            field = self.spec.columns[col]
+            uniq, inv = np.unique(idx[:, col], return_inverse=True)
             guniq = uniq + self._offsets[field]
             rows = np.empty((uniq.size, self.spec.dim), np.float32)
             cached = np.fromiter((int(g) in self._rows for g in guniq),
@@ -162,7 +164,7 @@ class HotRowCache:
                     np.float32)
                 rows[miss_ix] = fetched
                 self._admit(guniq[miss_ix], fetched)
-            out[:, field] = rows[inv]
+            out[:, col] = rows[inv]
             # per-occurrence accounting over the real rows only
             occ = inv[:n_valid]
             nh = int(cached[occ].sum())

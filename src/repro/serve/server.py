@@ -63,7 +63,9 @@ def _scorer(rc: RecsysConfig):
 
 @dataclasses.dataclass(frozen=True)
 class ServerConfig:
-    """One scoring model per substrate, shared architecture.
+    """One scoring model per substrate, shared architecture: DLRM, or
+    DLRM-DCNv2 (``arch``, ``cross_layers``, ``cross_rank``), with one id
+    per field or the multi-hot ``bag_sizes``.
 
     ``robe_compression`` sizes the ROBE array at 1/compression of the full
     table's parameters (the paper's 1000× knob, scaled to taste);
@@ -78,6 +80,11 @@ class ServerConfig:
     n_dense: int = 8
     bot_mlp: Tuple[int, ...] = ()        # () -> (64, embed_dim)
     top_mlp: Tuple[int, ...] = (64, 1)
+    arch: str = "dlrm"                   # "dlrm" | "dlrm_dcnv2"
+    cross_layers: int = 0                # dlrm_dcnv2's low-rank cross
+    cross_rank: int = 0
+    bag_sizes: Tuple[int, ...] = ()      # multi-hot ids per field; ()
+    #   = one id per field
     backends: Tuple[str, ...] = DEFAULT_BACKENDS
     robe_compression: int = 1000
     robe_block: int = 32
@@ -94,10 +101,11 @@ class ServerConfig:
         bot = self.bot_mlp or (64, self.embed_dim)
         n_emb = sum(self.vocab_sizes) * self.embed_dim
         return RecsysConfig(
-            name=f"serve-{backend}", arch="dlrm",
+            name=f"serve-{backend}", arch=self.arch,
             vocab_sizes=self.vocab_sizes, embed_dim=self.embed_dim,
             n_dense=self.n_dense, bot_mlp=bot, top_mlp=self.top_mlp,
-            embedding=backend,
+            cross_layers=self.cross_layers, cross_rank=self.cross_rank,
+            bag_sizes=self.bag_sizes, embedding=backend,
             robe_size=max(512, n_emb // self.robe_compression),
             robe_block=self.robe_block, use_kernel=self.use_kernel)
 
@@ -178,9 +186,10 @@ class EmbeddingServer:
               use_cache: bool = True) -> np.ndarray:
         """Route one padded batch to ``backend``; returns [n_valid] scores.
 
-        ``batch``: ``{"dense": [B, n_dense], "sparse": [B, F]}`` (numpy or
-        jax).  With a hot cache resident for this substrate (and
-        ``use_cache``), the sparse gather happens host-side through the
+        ``batch``: ``{"dense": [B, n_dense], "sparse": [B, ΣL]}`` (numpy
+        or jax; one id column per field unless ``cfg.bag_sizes``).  With a
+        hot cache resident for this substrate (and ``use_cache``), the
+        sparse gather happens host-side through the
         cache and the jitted scorer receives precomputed ``"emb"`` — the
         scores are bit-identical either way (``cacheable_rows`` contract).
         """

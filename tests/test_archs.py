@@ -128,10 +128,11 @@ def test_full_configs_construct():
             assert len(jax.tree.leaves(shapes)) > 10
 
 
-@pytest.mark.parametrize("arch", ["dcn", "deepfm", "fibinet"])
+@pytest.mark.parametrize("arch", ["dcn", "deepfm", "fibinet", "dlrm_dcnv2"])
 def test_paper_extra_families_smoke(arch):
     """The paper's Table-3 families beyond the assigned four (DCN, DeepFM,
-    FiBiNET) — exercised by benchmarks, smoke-tested here."""
+    FiBiNET), and the MLPerf DLRM-DCNv2 with multi-hot fields — exercised
+    by benchmarks, smoke-tested here."""
     from repro.models import recsys as R
     kw = dict(name=arch, vocab_sizes=(500, 300, 800, 100), embed_dim=8,
               embedding="robe", robe_size=2048, robe_block=8)
@@ -139,11 +140,18 @@ def test_paper_extra_families_smoke(arch):
         cfg = R.RecsysConfig(arch="dcn", cross_layers=2, dnn=(16,), **kw)
     elif arch == "deepfm":
         cfg = R.RecsysConfig(arch="deepfm", dnn=(16,), **kw)
+    elif arch == "dlrm_dcnv2":
+        cfg = R.RecsysConfig(arch="dlrm_dcnv2", n_dense=5, bot_mlp=(16, 8),
+                             top_mlp=(16, 1), cross_layers=2, cross_rank=4,
+                             bag_sizes=(2, 1, 3, 1), **kw)
     else:
         cfg = R.RecsysConfig(arch="fibinet", dnn=(16,), **kw)
     rs = np.random.RandomState(0)
-    batch = {"sparse": jnp.asarray(rs.randint(0, 90, (8, 4)), jnp.int32),
+    batch = {"sparse": jnp.asarray(rs.randint(0, 90, (8, cfg.n_columns)),
+                                   jnp.int32),
              "label": jnp.asarray(rs.randint(0, 2, (8,)), jnp.int32)}
+    if cfg.n_dense:
+        batch["dense"] = jnp.asarray(rs.randn(8, cfg.n_dense), jnp.float32)
     loss, grads = jax.value_and_grad(
         lambda p: R.loss_fn(p, cfg, batch)[0]
     )(R.init_params(jax.random.PRNGKey(0), cfg))
@@ -160,3 +168,24 @@ def test_paper_model_config_exists():
     assert 95 < full_gb < 115, full_gb            # the "100GB" model
     assert 95 < robe_mb < 115, robe_mb            # the "100MB" array
     assert spec.compression >= 999
+
+
+def test_dlrm_dcnv2_config_is_the_mlperf_model():
+    """DLRM-DCNv2 at its published widths: 214 multi-hot ids over the 26
+    CriteoTB fields, x0 = 128 + 26·128 = 3,456 through 3 cross layers of
+    rank 512, top MLP 3456-1024-1024-512-256-1, and the 1000× ROBE array
+    of dlrm-criteo-tb."""
+    from repro.models import recsys as R
+    cfg = get_arch("dlrm-dcnv2").make_config("full")
+    assert cfg.n_fields == 26 and cfg.n_columns == 214
+    assert cfg.robe_size == 26_135_627
+    assert cfg.robe_size == get_arch("dlrm-criteo-tb").make_config(
+        "full").robe_size
+    shapes = jax.eval_shape(lambda k: R.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    assert [layer["v"].shape for layer in shapes["cross"]] == \
+        [(3456, 512)] * 3
+    assert [layer["w"].shape for layer in shapes["top"]] == [
+        (3456, 1024), (1024, 1024), (1024, 512), (512, 256), (256, 1)]
+    assert [layer["w"].shape for layer in shapes["bot"]] == [
+        (13, 512), (512, 256), (256, 128)]

@@ -289,6 +289,43 @@ def test_robe_model_sharded_matches_replicated():
     """)
 
 
+def test_robe_replicated_lookup_is_local():
+    """The replicated ROBE array under a mesh: each device looks up its
+    own rows of a multi-hot DLRM-DCNv2 batch; loss and gradients match one
+    device, and the compiled gradient step exchanges nothing but the
+    all-reduce of the gradients."""
+    _run("""
+        from repro.dist import api as dist
+        from repro.models.recsys import RecsysConfig, init_params, loss_fn
+        cfg = RecsysConfig(name="d", arch="dlrm_dcnv2", n_dense=4,
+                           bot_mlp=(16, 8), top_mlp=(16, 1), embed_dim=8,
+                           vocab_sizes=(64, 96, 32), bag_sizes=(2, 1, 3),
+                           cross_layers=2, cross_rank=4, embedding="robe",
+                           robe_size=512, robe_block=8,
+                           compute_dtype=jnp.float32)
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        rs = np.random.RandomState(0)
+        batch = {"dense": jnp.asarray(rs.randn(16, 4), jnp.float32),
+                 "sparse": jnp.asarray(rs.randint(0, 30, (16, 6)), jnp.int32),
+                 "label": jnp.asarray(rs.randint(0, 2, (16,)), jnp.int32)}
+        grad = jax.value_and_grad(lambda p, b: loss_fn(p, cfg, b)[0])
+        l_one, g_one = grad(params, batch)
+        mesh = make_mesh((2, 4), ("data", "model"))
+        ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
+        with dist.use(ctx):
+            step = jax.jit(grad)
+            l_mesh, g_mesh = step(params, batch)
+            hlo = step.lower(params, batch).compile().as_text()
+        assert "all-reduce" in hlo
+        for other in ("all-gather", "all-to-all", "collective-permute"):
+            assert other not in hlo, other
+        assert abs(float(l_one) - float(l_mesh)) < 1e-5
+        err = jax.tree.reduce(max, jax.tree.map(
+            lambda a, b: float(jnp.max(jnp.abs(a - b))), g_one, g_mesh))
+        assert err < 1e-5, err
+    """)
+
+
 def test_recsys_cells_compile_every_backend():
     """The dlrm-rm2 serve cell compiles for all four substrates with each
     backend's own param_specs (mesh scaled to the CI host's 8 devices;

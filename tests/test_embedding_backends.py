@@ -13,7 +13,8 @@ from repro.kernels.ref import qr_materialize_ref, tt_materialize_ref
 from repro.nn.embedding_backends.qrobe import _expand
 from repro.nn.embeddings import (EmbeddingSpec, backend_names,
                                  embedding_init, embedding_lookup,
-                                 embedding_lookup_bag, get_backend)
+                                 embedding_lookup_bag, embedding_lookup_dist,
+                                 get_backend)
 
 VOCABS = (40, 24, 64)
 DIM = 8
@@ -203,6 +204,129 @@ def test_lookup_bag_sum_unweighted_masks_padding():
                                                    jnp.int32))
     np.testing.assert_allclose(np.asarray(got[0]),
                                np.asarray(e[0] + e[1]), rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# multi-hot fields (EmbeddingSpec.bag_sizes): [B, ΣL] id columns, pooled
+# ---------------------------------------------------------------------------
+
+BAGS = (3, 1, 4)
+
+
+def _multi_hot(spec: EmbeddingSpec, b: int, seed: int) -> np.ndarray:
+    rs = np.random.RandomState(seed)
+    return np.stack([rs.randint(0, spec.vocab_sizes[f], b)
+                     for f in spec.columns], 1).astype(np.int32)
+
+
+def test_multi_hot_columns():
+    spec = _spec("robe", bag_sizes=BAGS)
+    assert spec.columns == (0, 0, 0, 1, 2, 2, 2, 2)
+    np.testing.assert_array_equal(spec.column_offsets,
+                                  spec.offsets[[0, 0, 0, 1, 2, 2, 2, 2]])
+    assert _spec("robe").columns == (0, 1, 2)
+
+
+@pytest.mark.parametrize("bad", [(1, 2), (1, 0, 2), (2, 2, 2, 2)])
+def test_bag_sizes_validated(bad):
+    with pytest.raises(ValueError, match="bag_sizes"):
+        _spec("robe", bag_sizes=bad)
+
+
+@pytest.mark.parametrize("kind", BACKENDS)
+def test_multi_hot_pooling_matches_reference(kind):
+    """The pooled lookup equals, per field, the sum of the reference
+    table's rows of the field's ids (so each column is read as a row of
+    its own field)."""
+    spec = _spec(kind, bag_sizes=BAGS)
+    params = embedding_init(jax.random.PRNGKey(0), spec)
+    idx = _multi_hot(spec, 16, 5)
+    got = embedding_lookup_dist(params, spec, jnp.asarray(idx))
+    rows = np.asarray(jnp.take(_reference_table(params, spec),
+                               jnp.asarray(idx + spec.column_offsets[None]),
+                               axis=0))
+    want = np.stack([rows[:, np.asarray(spec.columns) == f].sum(1)
+                     for f in range(spec.n_fields)], 1)
+    assert got.shape == (16, 3, DIM)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("kind", BACKENDS)
+def test_multi_hot_pooling_matches_lookup_bag(kind):
+    """The ragged pooling equals ``lookup_bag`` on the same ids, each
+    field padded with −1 to the widest bag."""
+    spec = _spec(kind, bag_sizes=BAGS)
+    params = embedding_init(jax.random.PRNGKey(0), spec)
+    idx = _multi_hot(spec, 8, 6)
+    padded = np.full((8, spec.n_fields, max(BAGS)), -1, np.int32)
+    cols = np.asarray(spec.columns)
+    for f, n in enumerate(BAGS):
+        padded[:, f, :n] = idx[:, cols == f]
+    got = embedding_lookup_dist(params, spec, jnp.asarray(idx))
+    want = embedding_lookup_bag(params, spec, jnp.asarray(padded))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_multi_hot_columns_hash_under_their_field():
+    """Every ROBE id is hashed under its column's field: the lookup before
+    pooling equals the element-wise ROBE lookup with table ids
+    ``spec.columns``, and not with one table id per column."""
+    spec = _spec("robe", bag_sizes=BAGS)
+    params = embedding_init(jax.random.PRNGKey(0), spec)
+    idx = jnp.asarray(_multi_hot(spec, 8, 7))
+    got = np.asarray(get_backend("robe").lookup_dist(params, spec, idx))
+
+    def core(tids):
+        return np.asarray(robe_lookup_core(
+            params["memory"], spec.robe,
+            jnp.asarray(tids, jnp.uint32)[None, :], idx, DIM))
+
+    np.testing.assert_array_equal(got, core(spec.columns))
+    wrong = core(np.arange(len(spec.columns)))
+    assert not np.allclose(got[:, 1:3], wrong[:, 1:3])
+
+
+@pytest.mark.parametrize("kind", BACKENDS)
+def test_multi_hot_grad_matches_reference(kind):
+    spec = _spec(kind, bag_sizes=BAGS)
+    params = embedding_init(jax.random.PRNGKey(0), spec)
+    idx = _multi_hot(spec, 8, 8)
+    ct = jnp.asarray(np.random.RandomState(9).randn(8, 3, DIM), jnp.float32)
+    rows = jnp.asarray(idx + spec.column_offsets[None])
+    onehot = jnp.asarray(np.asarray(spec.columns)[:, None]
+                         == np.arange(spec.n_fields)[None], jnp.float32)
+
+    def loss_backend(p):
+        return (embedding_lookup_dist(p, spec, jnp.asarray(idx)) * ct).sum()
+
+    def loss_reference(p):
+        per_col = jnp.take(_reference_table(p, spec), rows, axis=0)
+        return jnp.einsum("bcd,cf,bfd->", per_col, onehot, ct,
+                          precision="highest")
+
+    gb = jax.grad(loss_backend, allow_int=True)(params)
+    gr = jax.grad(loss_reference, allow_int=True)(params)
+    for a, b in zip(jax.tree.leaves(gb), jax.tree.leaves(gr)):
+        assert a.dtype == b.dtype
+        if a.dtype != jax.dtypes.float0:        # qrobe's int8 codes
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("kind", BACKENDS)
+def test_one_hot_lookup_dist_adds_nothing(kind):
+    """With one id per field (``bag_sizes == ()``) the layer's lookup
+    lowers to exactly the backend's plain lookup: no pooling, the default
+    fields."""
+    spec = _spec(kind)
+    params = embedding_init(jax.random.PRNGKey(0), spec)
+    idx = jnp.zeros((4, 3), jnp.int32)
+    dist_jaxpr = jax.make_jaxpr(
+        lambda p, i: embedding_lookup_dist(p, spec, i))(params, idx)
+    plain = jax.make_jaxpr(
+        lambda p, i: get_backend(kind).lookup(p, spec, i))(params, idx)
+    assert str(dist_jaxpr) == str(plain)
 
 
 # ---------------------------------------------------------------------------

@@ -601,6 +601,31 @@ def test_server_cached_hashed_path_bit_exact(server):
             server.score("hashed", batch, use_cache=False))
 
 
+@pytest.mark.parametrize("kind,rtol", [("full", 0), ("hashed", 2e-6)])
+def test_server_cached_multi_hot_path_bit_exact(kind, rtol):
+    """A multi-hot DLRM-DCNv2 server: the cache gathers every id column
+    under its own field, and the scorer pools the cached rows as it pools
+    the looked-up ones, to the bit for ``full``.  A ``hashed`` row is a
+    product of two table rows, which the compiler fuses into the pooling
+    sum on the direct path: there the scores agree to rounding."""
+    from repro.serve.server import EmbeddingServer, ServerConfig
+    bags = (2, 1, 3) + (1,) * (len(VOCABS) - 3)
+    srv = EmbeddingServer(ServerConfig(
+        vocab_sizes=VOCABS, embed_dim=8, n_dense=4, bot_mlp=(16, 8),
+        top_mlp=(16, 1), arch="dlrm_dcnv2", cross_layers=2, cross_rank=4,
+        bag_sizes=bags, backends=(kind,), robe_compression=100))
+    rs = np.random.RandomState(0)
+    cols = srv.recsys_config(kind).embedding_spec().columns
+    batch = {"dense": rs.randn(16, 4).astype(np.float32),
+             "sparse": np.stack([rs.randint(0, VOCABS[f], 16)
+                                 for f in cols], 1).astype(np.int32)}
+    direct = srv.score(kind, batch, use_cache=False)
+    for _ in range(2):                 # misses, then hits
+        np.testing.assert_allclose(srv.score(kind, batch), direct,
+                                   rtol=rtol, atol=0)
+    assert srv.cache(kind).hits > 0
+
+
 def test_server_score_fn_slices_to_valid(server):
     fn = server.score_fn("full")
     batch, n = stack_and_pad(_mini_requests(5), 16)

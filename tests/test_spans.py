@@ -4,8 +4,10 @@ training write into a ``jax.profiler`` trace.
 
 Device scopes (``jax.named_scope``): ``embedding``, ``bottom_mlp``,
 ``interaction``, ``top_mlp`` in ``models/recsys.py``, ``optimizer`` in
-``train/train_loop.py``, and ``robe_blocks`` (the ROBE lookup's block
-gather, inside ``embedding``) in ``kernels/robe_lookup.py``.  Host spans (``jax.profiler.TraceAnnotation``):
+``train/train_loop.py``, ``robe_blocks`` (the ROBE lookup's block
+gather, inside ``embedding``) in ``kernels/robe_lookup.py``, and for
+DLRM-DCNv2 ``bag_pool`` (inside ``embedding``, ``nn/embeddings.py``) and
+``cross`` (inside ``interaction``).  Host spans (``jax.profiler.TraceAnnotation``):
 ``serve.h2d``/``serve.run``/``serve.d2h`` in ``EmbeddingServer.score`` and
 ``train.h2d``/``train.run``/``train.sync`` in ``train_loop.run``.
 """
@@ -171,6 +173,50 @@ def test_every_heavy_op_lies_under_a_scope(compiled, innermost, kind):
     assert heavy
     bare = [(op, n) for op, n in heavy if innermost(n)[0] is None]
     assert not bare, bare
+
+
+@pytest.mark.parametrize("kind", ["serve", "train"])
+def test_dcnv2_scopes_reach_the_compiled_program(innermost, kind):
+    """DLRM-DCNv2's multi-hot pooling runs under ``embedding/bag_pool``
+    and its cross layers under ``interaction/cross``: the op metadata of
+    the compiled program carries both, and the benchmark's reader counts
+    them under ``embedding`` and ``interaction``."""
+    bags = (2, 1, 3, 2)
+    rc = RecsysConfig(name="spans", arch="dlrm_dcnv2", vocab_sizes=VOCABS,
+                      embed_dim=16, n_dense=13, bot_mlp=(32, 16),
+                      top_mlp=(16, 1), cross_layers=2, cross_rank=8,
+                      bag_sizes=bags, embedding="robe", robe_size=512,
+                      robe_block=8)
+    params = init_params(jax.random.PRNGKey(0), rc)
+    rng = np.random.default_rng(1)
+    b = {"dense": jnp.asarray(rng.normal(size=(BATCH, 13)), jnp.float32),
+         "sparse": jnp.asarray(np.stack(
+             [rng.integers(0, VOCABS[f], BATCH)
+              for f in rc.embedding_spec().columns], 1), jnp.int32),
+         "label": jnp.asarray(rng.integers(0, 2, BATCH), jnp.float32)}
+    if kind == "serve":
+        server = EmbeddingServer(ServerConfig(
+            vocab_sizes=VOCABS, embed_dim=16, n_dense=13, bot_mlp=(32, 16),
+            top_mlp=(16, 1), arch="dlrm_dcnv2", cross_layers=2,
+            cross_rank=8, bag_sizes=bags, backends=("robe",)),
+            params={"robe": params})
+        lowered = server._jit["robe"].lower(
+            params, {"dense": b["dense"], "sparse": b["sparse"]})
+    else:
+        opt = make_optimizer(OptimizerConfig(kind="adagrad", lr=0.01))
+        tc = TrainConfig(max_restarts=0)
+        step = build_train_step(lambda p, bb: loss_fn(p, rc, bb), opt, tc)
+        lowered = step.lower(init_state(params, opt, tc), b)
+    names = _OP_NAME.findall(lowered.compile().as_text())
+    pool = [n for n in names if "/bag_pool/" in n]
+    cross = [n for n in names if "/cross/" in n]
+    assert pool and cross
+    assert all(re.search(r"embedding\)*/bag_pool/", n) for n in pool)
+    assert all(re.search(r"interaction\)*/cross/", n) for n in cross)
+    assert {innermost(n)[0] for n in pool} == {"embedding"}
+    assert {innermost(n)[0] for n in cross} == {"interaction"}
+    if kind == "train":
+        assert {innermost(n)[1] for n in pool + cross} == {False, True}
 
 
 def _host_events(trace_dir: str, names) -> list:

@@ -6,8 +6,9 @@ tests compile each kernel ahead of time for one chip of a described
 ``v5e:2x2`` topology at published widths — dlrm-rm2 ``serve_p99`` (B=512,
 d=64, a 13,067,813-slot ROBE array) and dlrm-criteo-tb ``train_batch``
 (B=65,536, d=128, 26,135,627 slots) — and check that the program holds
-the kernel (``tpu_custom_call``).  Nothing runs, so they say nothing about
-results or times.
+the kernel (``tpu_custom_call``); and the whole serve program of the
+``dcnv2-bulk`` benchmark cell.  Nothing runs, so they say nothing about
+results or times on the chip.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU compiler's library, and every
@@ -130,6 +131,41 @@ def test_qr_lookup_compiles_for_v5e(one_chip, width):
              _shape((int(np.sum(q_rows)), d), jnp.float32, one_chip),
              _shape((m * f, d), jnp.float32, one_chip),
              _shape((b, f), jnp.int32, one_chip))
+
+
+#: a compile of the ``dcnv2-bulk`` serve program that takes longer points
+#: at a relayout whose code generation takes minutes (PERF.md §7); it took
+#: 7.9–8.6 s on the CPU that describes the chip
+DCNV2_COMPILE_S = 120
+
+
+def test_dcnv2_bulk_serve_program_compiles_for_v5e(one_chip):
+    """The whole DLRM-DCNv2 serve program of the ``dcnv2-bulk`` cell
+    (16,384 rows of 214 multi-hot ids, the 26,135,627-slot array, 3 cross
+    layers of rank 512), with the default jnp lookup: it compiles for one
+    v5e chip within ``DCNV2_COMPILE_S``, fits its 16 GB, and gathers whole
+    blocks."""
+    import time
+
+    from repro.models.recsys import init_params
+    from repro.serve.server import _scorer
+    cfg = get_arch("dlrm-dcnv2").make_config()
+    assert cfg.n_columns == 214
+    params = jax.tree.map(
+        lambda x: _shape(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
+    batch = {"dense": _shape((16384, 13), jnp.float32, one_chip),
+             "sparse": _shape((16384, 214), jnp.int32, one_chip)}
+    t0 = time.perf_counter()
+    compiled = _scorer(cfg).lower(params, batch).compile()
+    took = time.perf_counter() - t0
+    print(f"dcnv2-bulk serve program: compiled in {took:.1f} s")
+    assert took < DCNV2_COMPILE_S
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+    text = compiled.as_text()
+    assert any("slice_sizes={1,128}" in line and "robe_blocks" in line
+               for line in text.splitlines() if " gather(" in line)
 
 
 def test_refused_kernels_raise_on_tpu(monkeypatch):
