@@ -5,9 +5,11 @@
 table row per Z-block, bit-identical to the element-wise definition in
 ``core.robe``) or the Pallas kernel (a DMA gather of Z-blocks from the
 array in HBM); backward = the paper's Fig.-2 scatter-add of output grads
-into the shared array, expressed as an XLA scatter (segment-sum over
-slots).  The scatter IS the semantics of weight sharing — every aliased
-parameter's gradient accumulates into its slot.
+into the shared array, for either forward the transpose of the block
+gather (``kernels.robe_lookup.robe_scatter_blocks``: one hash and one
+scatter-added table row per Z-block, summed in f32).  The scatter IS the
+semantics of weight sharing — every aliased parameter's gradient
+accumulates into its slot.
 
 ``qr_lookup`` / ``tt_lookup`` follow the identical contract for the two
 baseline substrates: fused Pallas forward, custom-VJP backward as an XLA
@@ -36,7 +38,8 @@ from repro.core.robe import RobeSpec, robe_slots, robe_signs
 from repro.kernels import ref as _ref
 from repro.kernels.robe_lookup import (qrobe_lookup_pallas,
                                        robe_lookup_blocks,
-                                       robe_lookup_pallas)
+                                       robe_lookup_pallas,
+                                       robe_scatter_blocks)
 from repro.kernels.dot_interaction import dot_interaction_pallas
 from repro.kernels.qr_lookup import qr_lookup_pallas
 from repro.kernels.tt_lookup import tt_lookup_pallas
@@ -90,18 +93,12 @@ def _lookup_fwd(memory, rows, table_ids, dim, spec, use_kernel):
 def _lookup_bwd(table_ids, dim, spec, use_kernel, res, g):
     rows, m = res
     # the cotangent's dtype IS the memory dtype: custom_vjp cotangents match
-    # the primal output aval, and both lookup paths emit memory.dtype
-    mem_dtype = g.dtype
-    tids = jnp.asarray(table_ids, jnp.uint32)[None, :]
-    slots = robe_slots(spec, tids, rows, dim)            # [B, F, dim]
-    g = g.astype(jnp.float32)
-    if spec.use_sign:
-        g = g * robe_signs(spec, tids, rows, dim)
-    # scatter-add of every element's grad into its shared slot (paper Fig. 2);
-    # accumulate in f32, deliver in the memory's dtype (custom_vjp contract)
-    gmem = jnp.zeros((m,), jnp.float32).at[slots.reshape(-1).astype(jnp.int32)
-                                           ].add(g.reshape(-1))
-    return gmem.astype(mem_dtype), None
+    # the primal output aval, and both lookup paths emit memory.dtype.
+    # Every element's grad adds into its shared slot (paper Fig. 2), a whole
+    # Z-block at a time (the transpose of the block gather); accumulate in
+    # f32, deliver in the memory's dtype (custom_vjp contract)
+    gmem = robe_scatter_blocks(g, rows, table_ids, dim, spec, m)
+    return gmem.astype(g.dtype), None
 
 
 robe_lookup.defvjp(_lookup_fwd, _lookup_bwd)

@@ -36,7 +36,11 @@ than the element gather.  So the array is laid out as a table of
 overlapping 128-slot rows (``strided_rows``), every block lies inside one
 row, and a block costs one hash, one gathered row and log2(ROW_STRIDE)
 select stages that align it.  The table is built anew on every call, so
-its 16 copies of the array are paid whatever the batch.
+its 16 copies of the array are paid whatever the batch.  The backward
+(``robe_scatter_blocks``) is its transpose: each block's cotangent
+un-shifted into a row and scatter-added into an f32 table (the compiler
+sorts the block keys and adds the rows in that order), whose copies are
+then folded back into the array.
 """
 
 from __future__ import annotations
@@ -293,20 +297,70 @@ def _shift_lanes(x: jnp.ndarray, shift: jnp.ndarray, width: int, top: int,
     return x[:, :width]
 
 
+def _transpose(fn, x_shape, ct: jnp.ndarray) -> jnp.ndarray:
+    """The transpose of the linear ``fn`` at an f32 input of ``x_shape``,
+    applied to the cotangent ``ct``: its VJP at zeros that vary over the
+    mesh axes ``ct`` varies over inside a ``shard_map`` (the unused
+    forward is dead code)."""
+    x = jnp.zeros(x_shape, jnp.float32)
+    vma = tuple(jax.typeof(ct).vma)
+    if vma:
+        x = jax.lax.pcast(x, vma, to="varying")
+    return jax.vjp(fn, x)[1](ct)[0]
+
+
+def _block_rows(table_rows: int, starts: jnp.ndarray, stride: int
+                ) -> jnp.ndarray:
+    """[K] block starts -> the row of a ``table_rows``-row table of
+    ``strided_rows`` that begins at each start rounded down to ``stride``."""
+    log2_lanes = LANES.bit_length() - 1
+    part = (starts & (LANES - 1)) >> (stride.bit_length() - 1)
+    return part * (table_rows * stride // LANES) + (starts >> log2_lanes)
+
+
+_ROW_GATHER = jax.lax.GatherDimensionNumbers(
+    offset_dims=(1,), collapsed_slice_dims=(0,), start_index_map=(0,))
+_ROW_SCATTER = jax.lax.ScatterDimensionNumbers(
+    update_window_dims=(1,), inserted_window_dims=(0,),
+    scatter_dims_to_operand_dims=(0,))
+
+
 def gather_blocks(table: jnp.ndarray, starts: jnp.ndarray, z: int,
                   stride: int) -> jnp.ndarray:
     """[K] block starts in [0, |M|) -> [K, Z]: slots ``start + j`` (mod
     |M|) for ``j < Z``, one row of ``strided_rows`` per block (the row
     that begins at ``start`` rounded down to ``stride``), aligned by
     ``start % stride``."""
-    log2_lanes = LANES.bit_length() - 1
-    part = (starts & (LANES - 1)) >> (stride.bit_length() - 1)
-    row = part * (table.shape[0] * stride // LANES) + (starts >> log2_lanes)
-    dnums = jax.lax.GatherDimensionNumbers(
-        offset_dims=(1,), collapsed_slice_dims=(0,), start_index_map=(0,))
-    win = jax.lax.gather(table, row[:, None], dnums, (1, table.shape[1]),
+    row = _block_rows(table.shape[0], starts, stride)
+    win = jax.lax.gather(table, row[:, None], _ROW_GATHER,
+                         (1, table.shape[1]),
                          mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
     return _shift_lanes(win, starts & (stride - 1), z, stride)
+
+
+def scatter_blocks(table: jnp.ndarray, starts: jnp.ndarray,
+                   blocks: jnp.ndarray, stride: int) -> jnp.ndarray:
+    """The transpose of ``gather_blocks``: adds each of the [K, Z] f32
+    ``blocks`` into the row of the f32 ``table`` that its start's block
+    was gathered from, un-shifted into the row's lanes (the transpose of
+    the select stages), one row scatter-add per block."""
+    z = blocks.shape[1]
+    win = _transpose(lambda w: _shift_lanes(w, starts & (stride - 1), z,
+                                            stride),
+                     (blocks.shape[0], table.shape[1]), blocks)
+    row = _block_rows(table.shape[0], starts, stride)
+    return jax.lax.scatter_add(
+        table, row[:, None], win, _ROW_SCATTER,
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def _pieces(b: int, f: int, dim: int, z: int, itemsize: int) -> int:
+    """Rows per piece of a batch of ``b`` rows, so that a piece's table
+    rows of ``itemsize``-byte slots stay within ``CHUNK_BYTES``."""
+    s = n_segments(dim, z)
+    width = round_up(z + ROW_STRIDE - 1, LANES)
+    pieces = -(-b * f * s * width * itemsize // CHUNK_BYTES)
+    return -(-b // pieces)
 
 
 def robe_lookup_blocks(memory: jnp.ndarray, rows: jnp.ndarray,
@@ -323,8 +377,7 @@ def robe_lookup_blocks(memory: jnp.ndarray, rows: jnp.ndarray,
     z = spec.block_size
     s = n_segments(dim, z)
     width = round_up(z + ROW_STRIDE - 1, LANES)
-    pieces = -(-b * f * s * width * memory.dtype.itemsize // CHUNK_BYTES)
-    b_c = -(-b // pieces)
+    b_c = _pieces(b, f, dim, z, memory.dtype.itemsize)
 
     def piece(part):                                    # [b_c, F]
         starts, off0 = block_starts(spec, table_ids, part, dim)
@@ -346,6 +399,49 @@ def robe_lookup_blocks(memory: jnp.ndarray, rows: jnp.ndarray,
         tids = jnp.asarray(table_ids, jnp.uint32)[None, :]
         out = out * robe_signs(spec, tids, rows, dim).astype(out.dtype)
     return out
+
+
+def robe_scatter_blocks(g: jnp.ndarray, rows: jnp.ndarray,
+                        table_ids: Tuple[int, ...], dim: int, spec: RobeSpec,
+                        m: int) -> jnp.ndarray:
+    """[B, F, dim] cotangent -> [m] f32: the transpose of
+    ``robe_lookup_blocks`` with respect to the array, summed in f32.
+
+    The signed cotangent of each Z-block is un-shifted into a 128-lane row
+    and scatter-added into an f32 table of ``strided_rows`` (one hash and
+    one row per block, the batch in the forward's pieces at f32 slots, each
+    piece adding into the same table); the table's ``128 / ROW_STRIDE``
+    shifted copies and its circular padding are then folded back into the
+    array."""
+    b, f = rows.shape
+    z = spec.block_size
+    s = n_segments(dim, z)
+    width = round_up(z + ROW_STRIDE - 1, LANES)
+    b_c = _pieces(b, f, dim, z, 4)
+    g = g.astype(jnp.float32)
+    if spec.use_sign:
+        tids = jnp.asarray(table_ids, jnp.uint32)[None, :]
+        g = g * robe_signs(spec, tids, rows, dim)
+    g = g.reshape(b, f * dim)
+
+    def piece(table, part, gp):                         # [b_c, F]
+        starts, off0 = block_starts(spec, table_ids, part, dim)
+        n = starts.shape[0]
+        gp = gp.reshape(n, dim)
+        if dim % z:
+            gp = _transpose(lambda o: _shift_lanes(o, off0, dim, z,
+                                                   math.gcd(dim, z)),
+                            (n, s * z), gp)
+        gp = gp.reshape(n, s, z).transpose(1, 0, 2).reshape(s * n, z)
+        return scatter_blocks(table, starts.T.reshape(-1), gp, ROW_STRIDE)
+
+    with jax.named_scope("robe_blocks"):
+        n_rows = ((m - 1) // LANES + 1) * (LANES // ROW_STRIDE)
+        table = jnp.zeros((n_rows, width), jnp.float32)
+        for k in range(0, b, b_c):
+            table = piece(table, rows[k:k + b_c], g[k:k + b_c])
+        return _transpose(lambda mem: strided_rows(mem, ROW_STRIDE, width),
+                          (m,), table)
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "dim", "table_ids",

@@ -211,6 +211,25 @@ def test_lookup_probe_formulations_are_exact(name):
         np.asarray(want.astype(got.dtype), np.float32))
 
 
+@pytest.mark.parametrize("name", ["blocks-bwd", "sorted-bwd", "s8-bwd",
+                                  "s16-bwd", "s32-bwd", "s64-bwd"])
+def test_lookup_probe_backward_variants_match_element_scatter(name):
+    """Every backward the probe times returns the element scatter's
+    gradient of the array to f32 tolerance, blocks running past the
+    array's end included."""
+    probe = _lookup_probe()
+    spec = RobeSpec(size=4099, block_size=32, seed=11)
+    vocab = np.array([1000, 50000, 7, 300000])
+    mem = jnp.zeros((spec.size,), jnp.float32)
+    ids = jnp.asarray(probe.draw_ids(vocab, 37, 3))
+    want = np.asarray(probe.variant("elem-bwd", spec, len(vocab), 64)(
+        mem, ids))
+    got = jax.jit(probe.variant(name, spec, len(vocab), 64))(mem, ids)
+    assert got.shape == want.shape == (spec.size,)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                               atol=1e-6 * np.abs(want).max())
+
+
 def test_robe_lookup_grad_scatter_adds_the_cotangent():
     """The VJP scatter-adds every element's signed cotangent into its
     slot, whichever forward ran."""
@@ -227,6 +246,54 @@ def test_robe_lookup_grad_scatter_adds_the_cotangent():
     want = np.zeros(101)
     np.add.at(want, slots, ct.reshape(-1) * signs)
     np.testing.assert_allclose(np.asarray(g), want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("d,sign,dtype,ids,m,chunk", [
+    (16, False, jnp.float32, "drawn", 4099, None),     # d | Z
+    (64, False, jnp.float32, "drawn", 4099, None),     # Z | d
+    (128, False, jnp.float32, "drawn", 4099, None),
+    (48, False, jnp.float32, "drawn", 4099, None),     # Z ∤ d
+    (128, True, jnp.float32, "drawn", 4099, None),     # signs
+    (64, True, jnp.bfloat16, "drawn", 4099, None),     # bf16 array
+    (128, False, jnp.float32, "equal", 4099, None),    # one table row
+    (64, True, jnp.float32, "drawn", 101, None),       # blocks wrap
+    (48, True, jnp.float32, "drawn", 4099, 1 << 14),   # in pieces
+], ids=["d16", "d64", "d128", "d48", "sign", "bf16", "equal-ids", "wrap",
+        "pieces"])
+def test_robe_lookup_block_scatter_matches_element_scatter(
+        monkeypatch, d, sign, dtype, ids, m, chunk):
+    """The VJP adds the signed cotangent into the array a whole Z-block
+    at a time (the transpose of the block gather): it equals the
+    element-by-element scatter-add to f32 tolerance, is summed in f32 and
+    delivered in the array's dtype, for any Z and d, a batch of one id
+    (each field's blocks all add into the same few table rows), blocks
+    that run past the array's end, and a batch scattered in pieces."""
+    b, f, z = 37, 3, 32
+    if chunk:
+        monkeypatch.setattr(robe_lookup_module, "CHUNK_BYTES", chunk)
+        assert 1 < -(-b * f * 2 * 128 * 4 // chunk) < b
+    rs = np.random.RandomState(d + m)
+    spec = RobeSpec(size=m, block_size=z, seed=4, use_sign=sign)
+    mem = jnp.asarray(rs.randn(m), dtype)
+    rows = (np.full((b, f), 123457) if ids == "equal"
+            else rs.randint(0, 2 ** 31 - 1, (b, f)))
+    rows = jnp.asarray(rows, jnp.int32)
+    # the cotangent arrives in the array's dtype
+    ct = np.asarray(jnp.asarray(rs.randn(b, f, d), dtype), np.float64)
+    g = jax.jit(jax.grad(lambda mm: (robe_lookup(
+        mm, rows, tuple(range(f)), d, spec, False).astype(jnp.float32)
+        * ct).sum()))(mem)
+    assert g.dtype == dtype and g.shape == (m,)
+    tids = jnp.arange(f, dtype=jnp.uint32)[None]
+    slots = np.asarray(robe_slots(spec, tids, rows, d)).reshape(-1)
+    signs = (np.asarray(robe_signs(spec, tids, rows, d)).reshape(-1)
+             if sign else 1.0)
+    want = np.zeros(m)
+    np.add.at(want, slots, ct.reshape(-1) * signs)
+    # f32 sums in another order; a bf16 gradient is their one rounding
+    rtol = 2.0 ** -8 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(g, np.float64), want, rtol=rtol,
+                               atol=1e-6 * np.abs(want).max())
 
 
 @pytest.mark.parametrize("b,f,d,self_i,dtype", [

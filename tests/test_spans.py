@@ -5,7 +5,8 @@ training write into a ``jax.profiler`` trace.
 Device scopes (``jax.named_scope``): ``embedding``, ``bottom_mlp``,
 ``interaction``, ``top_mlp`` in ``models/recsys.py``, ``optimizer`` in
 ``train/train_loop.py``, ``robe_blocks`` (the ROBE lookup's block
-gather, inside ``embedding``) in ``kernels/robe_lookup.py``, and for
+gather, and in the backward its transpose, the block scatter, inside
+``embedding``) in ``kernels/robe_lookup.py``, and for
 DLRM-DCNv2 ``bag_pool`` (inside ``embedding``, ``nn/embeddings.py``) and
 ``cross`` (inside ``interaction``).  Host spans (``jax.profiler.TraceAnnotation``):
 ``serve.h2d``/``serve.run``/``serve.d2h`` in ``EmbeddingServer.score`` and
@@ -150,6 +151,28 @@ def test_lookup_gathers_whole_blocks(programs, innermost, kind):
     per_slot = [line for line in text.splitlines()
                 if " gather(" in line and "slice_sizes={1}" in line
                 and _elements(line) == BATCH * len(VOCABS) * 16]
+    assert not per_slot, per_slot
+
+
+def test_lookup_backward_scatters_whole_blocks_under_embedding(programs,
+                                                               innermost):
+    """The train step's lookup backward (the transpose of the block
+    gather) runs under ``embedding/robe_blocks`` inside ``transpose(``:
+    the benchmark counts all of it as the embedding layer's backward
+    (``embed_bwd_step_ms.train``).  It scatter-adds whole table rows, one
+    per Z-block, and no single slots."""
+    _, text = programs["train"]
+    blocks = {n for n in _OP_NAME.findall(text)
+              if re.search(r"transpose\(.*embedding\)*/robe_blocks/", n)}
+    assert blocks
+    assert {innermost(n) for n in blocks} == {("embedding", True)}
+    scatters = [line for line in text.splitlines()
+                if " scatter(" in line
+                and "robe_blocks" in _OP_NAME.search(line).group(1)]
+    assert scatters
+    assert all(re.search(r"= f32\[\d+,128\]", line) for line in scatters)
+    per_slot = [line for line in text.splitlines() if " scatter(" in line
+                and re.search(r"= f32\[%d\]" % _config().robe_size, line)]
     assert not per_slot, per_slot
 
 

@@ -6,7 +6,8 @@ tests compile each kernel ahead of time for one chip of a described
 ``v5e:2x2`` topology at published widths — dlrm-rm2 ``serve_p99`` (B=512,
 d=64, a 13,067,813-slot ROBE array) and dlrm-criteo-tb ``train_batch``
 (B=65,536, d=128, 26,135,627 slots) — and check that the program holds
-the kernel (``tpu_custom_call``); and the whole serve program of the
+the kernel (``tpu_custom_call``); the default jnp lookup's block gather
+and its VJP's block scatter; and the whole serve program of the
 ``dcnv2-bulk`` benchmark cell.  Nothing runs, so they say nothing about
 results or times on the chip.
 
@@ -91,6 +92,53 @@ def test_robe_lookup_block_gather_compiles_for_v5e(one_chip, width):
     assert any("slice_sizes={1,128}" in line and "robe_blocks" in line
                for line in gathers), gathers
     assert not [line for line in gathers if "slice_sizes={1}" in line]
+
+
+#: a compile of the lookup's backward alone that takes longer points at a
+#: relayout whose code generation takes minutes (PERF.md §7); at
+#: dlrm-criteo-tb ``train_batch`` widths the block scatter took 28.5 s on
+#: the CPU that describes the chip, the element scatter before it 235 s
+BWD_COMPILE_S = 120
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_robe_lookup_block_scatter_compiles_for_v5e(one_chip, width):
+    """The default backward (the jnp lookup's VJP with respect to the
+    array): the compiler keeps it a scatter-add of whole 128-lane table
+    rows, one per Z-block, never of single slots, and sorts no key per
+    element of the [B, F, d] embeddings; it compiles within
+    ``BWD_COMPILE_S`` and fits the 16 GB chip."""
+    import re
+    import time
+
+    from repro.kernels.ops import robe_lookup
+    cfg, b, f, d = _recsys(width)
+    spec = cfg.embedding_spec().robe
+    tids = tuple(range(f))
+
+    def bwd(m, r, ct):
+        _, vjp = jax.vjp(lambda mm: robe_lookup(mm, r, tids, d, spec, False),
+                         m)
+        return vjp(ct)[0]
+
+    t0 = time.perf_counter()
+    compiled = jax.jit(bwd).lower(
+        _shape((spec.size,), jnp.float32, one_chip),
+        _shape((b, f), jnp.int32, one_chip),
+        _shape((b, f, d), jnp.float32, one_chip)).compile()
+    took = time.perf_counter() - t0
+    print(f"{width} lookup VJP: compiled in {took:.1f} s")
+    assert took < BWD_COMPILE_S
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+    lines = compiled.as_text().splitlines()
+    scatters = [line for line in lines if " scatter(" in line]
+    assert scatters and all(
+        re.search(r"= f32\[\d+,128\]", line) and "robe_blocks" in line
+        and "update_window_dims={1}" in line for line in scatters), scatters
+    per_element = f"[{b * f * d}]"
+    assert not [line for line in lines
+                if " sort(" in line and per_element in line]
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
