@@ -70,8 +70,8 @@ def push_with_parity(server: EmbeddingServer, trainer: OnlineTrainer,
 
 def serve_through_pushes(server: EmbeddingServer, trainer: OnlineTrainer,
                          publish_dir: str):
-    """The replay cell behind the BENCH ``+push`` row: drifting traffic,
-    publishes hot-swapped in mid-replay on the virtual clock."""
+    """The push replay cell: drifting traffic, publishes hot-swapped in
+    mid-replay on the virtual clock."""
     row = run_push_cell(
         server, "full", ReplayConfig(n_requests=1024, rate_hz=2000.0),
         publish_dir=publish_dir,

@@ -8,11 +8,11 @@ Three shapes:
    ``full`` substrate through its hot-row cache.
 2. ``replay_policies`` — the virtual-clock traffic replay comparing the
    deadline policy against fixed-size batching at equal offered load
-   (the measurement behind ``BENCH_serving.json``).
+   (measured service times, simulated queueing).
 3. ``fleet_replay`` — a ``ReplicaFleet`` of three replicas behind one
    admission path, replayed at 3x the single-server offered load on one
    virtual clock, then a staggered-vs-synchronized model rollout on the
-   same trace (the fleet cells of ``BENCH_serving.json``).
+   same trace.
 4. ``retrieval`` — the one-query-vs-many two-tower shape.
 
     PYTHONPATH=src python examples/serve_recsys.py

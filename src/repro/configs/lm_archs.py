@@ -50,7 +50,7 @@ _lm_bundle(
         d_ff=32, vocab=512, n_experts=8, top_k=2, n_shared=1,
         first_k_dense=1, d_ff_dense=96, moe_dispatch="dense", q_chunk=8,
         compute_dtype=jnp.float32, remat=False),
-    notes="1T-param MoE; FSDP over data axis required (see dryrun).")
+    notes="1T-param MoE; FSDP over data axis required.")
 
 # --- qwen3-moe-30b-a3b [moe] 48L d2048 32H (GQA kv=4) d_ff=768 (expert)
 #     vocab 151936, MoE 128e top-8, qk-norm ------------------------------

@@ -2,8 +2,9 @@
 
 Each bundle carries the exact full-scale config from the assignment, a
 reduced smoke config (same structural features, tiny dims), and its shape
-cells.  The dry-run (launch/cells.py) builds (fn, input_specs, shardings)
-per (arch × shape × mesh) from these bundles.
+cells.  ``launch.cells.build_recsys_cell`` builds (fn, input_specs,
+shardings) per recsys (arch × shape × mesh) from these bundles; the LM and
+GNN shape cells have no builder.
 """
 
 from __future__ import annotations
@@ -20,8 +21,8 @@ ARCH_IDS = (
     "gatedgcn",
     # RecSys
     "autoint", "dlrm-rm2", "two-tower-retrieval", "xdeepfm",
-    # beyond the assigned ten, used by benchmarks: the paper's own model
-    # and the MLPerf DLRM-DCNv2
+    # beyond the assigned ten, used by the benchmark's cells: the paper's
+    # own model and the MLPerf DLRM-DCNv2
     "dlrm-criteo-tb", "dlrm-dcnv2",
 )
 N_ASSIGNED = 10

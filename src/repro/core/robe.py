@@ -23,8 +23,7 @@ whole Z-block at a time too (``robe_scatter_blocks``); all are validated
 against this module.
 Models never call this module directly: the consumer-facing surface is the
 ``robe`` ``EmbeddingBackend`` (``repro.nn.embedding_backends.robe``), which
-owns placement, PartitionSpecs, and the roofline cost model on top of the
-hash math here.
+owns placement and PartitionSpecs on top of the hash math here.
 
 Backward pass: JAX autodiff through the gather produces exactly the paper's
 Fig. 2 scatter-add — gradients of all aliased parameters accumulate into the

@@ -82,19 +82,6 @@ def _padded_len(m: int, dim: int, z: int) -> int:
     return round_up(front + m + z + LANES * window_rows(dim), LANES)
 
 
-def gather_bytes(m: int, dim: int, z: int, n_pairs: int,
-                 itemsize: int = 4) -> int:
-    """HBM bytes one ``robe_gather`` call moves for ``n_pairs`` (row,
-    field) pairs over an ``m``-slot array of ``itemsize``-byte slots: the
-    per-call padded f32 copy (the array read, the copy written), every
-    pair's ``n_segments`` windows of ``window_rows · 128`` f32 read by DMA,
-    its SMEM starts, and the ``[n_pairs, dim]`` f32 result written."""
-    s = n_segments(dim, z)
-    copy = m * itemsize + _padded_len(m, dim, z) * 4
-    windows = n_pairs * s * window_rows(dim) * LANES * 4
-    return copy + windows + n_pairs * (s + 1) * 4 + n_pairs * dim * 4
-
-
 def _circular_rows(memory: jnp.ndarray, front: int, min_len: int
                    ) -> jnp.ndarray:
     """Copy of the circular array as ``[R, 128]``, in its dtype: padded

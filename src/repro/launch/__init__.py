@@ -1,2 +1,2 @@
-"""Production-mesh launch tooling: mesh/context builders, cell registry,
-multi-pod dry-run, roofline reports."""
+"""Launch helpers: mesh and context builders, the compile cache, and the
+recsys cell builder."""

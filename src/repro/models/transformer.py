@@ -63,7 +63,6 @@ class TransformerConfig:
     compute_dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32     # bf16 for the 1T cell (FSDP + bf16)
     remat: bool = True
-    scan_layers: bool = True           # False: unrolled (roofline probes)
     cache_dtype: Any = jnp.bfloat16
 
     @property
@@ -339,18 +338,8 @@ def forward(params, cfg: TransformerConfig, tokens: jnp.ndarray,
         return (xx, aux_acc + aux), _shard_kv(cfg, kv)
 
     body = jax.checkpoint(scan_body) if cfg.remat else scan_body
-    if cfg.scan_layers:
-        (x, aux_total), kv_stack = jax.lax.scan(body, (x, aux_total),
-                                                params["layers"])
-    else:       # unrolled: exact per-layer HLO cost (roofline probes)
-        n_scan = cfg.n_layers - cfg.first_k_dense
-        kvs = []
-        for i in range(n_scan):
-            layer_p = jax.tree.map(lambda a: a[i], params["layers"])
-            (x, aux_total), kv_i = body((x, aux_total), layer_p)
-            kvs.append(kv_i)
-        kv_stack = jax.tree.map(lambda *xs: jnp.stack(xs), *kvs) \
-            if (kvs and kvs[0] is not None) else None
+    (x, aux_total), kv_stack = jax.lax.scan(body, (x, aux_total),
+                                            params["layers"])
     x = rms_norm_apply(params["final_norm"], x)
     if logits_mode == "last":
         x = x[:, -1]
@@ -443,18 +432,8 @@ def decode_step(params, cfg: TransformerConfig, caches, tokens: jnp.ndarray,
                                     pos, kv_len)
         return xx, layer_c
 
-    if cfg.scan_layers:
-        x, new_cache = jax.lax.scan(scan_body, x,
-                                    (params["layers"], caches["layers"]))
-    else:
-        n_scan = cfg.n_layers - cfg.first_k_dense
-        ncs = []
-        for i in range(n_scan):
-            args_i = jax.tree.map(lambda a: a[i],
-                                  (params["layers"], caches["layers"]))
-            x, nc = scan_body(x, args_i)
-            ncs.append(nc)
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
+    x, new_cache = jax.lax.scan(scan_body, x,
+                                (params["layers"], caches["layers"]))
     x = rms_norm_apply(params["final_norm"], x)
     logits = x[:, -1] @ params["lm_head"].astype(x.dtype)
     logits = dist.shard(logits, "batch", "vocab")

@@ -25,9 +25,8 @@ from repro.nn.embedding_backends import hashed as _hashed    # noqa: F401
 from repro.nn.embedding_backends import tt as _tt            # noqa: F401
 from repro.nn.embedding_backends import qrobe as _qrobe      # noqa: F401
 from repro.nn.embedding_backends.full import full_lookup_sharded_body
-from repro.nn.embedding_backends.robe import (analytic_max_fetches,
-                                              robe_allgather_body)
+from repro.nn.embedding_backends.robe import robe_allgather_body
 
 __all__ = ["EmbeddingBackend", "get_backend", "register_backend",
            "backend_names", "full_lookup_sharded_body",
-           "robe_allgather_body", "analytic_max_fetches"]
+           "robe_allgather_body"]

@@ -18,8 +18,8 @@ a substrate hangs off the backend object:
   parameter pytree (consumed by ``repro.dist.param_specs.recsys_specs``);
   ``mesh`` re-resolves the layout against a concrete — possibly degraded —
   mesh (the elastic re-slice contract, see ``repro.train.elastic``)
-* ``cost(spec, batch)``                 -> {"params", "bytes_fetched",
-  "flops"} — the roofline/benchmark cost model, owned by the substrate
+* ``param_count(spec)``                 -> trained parameter count (what
+  ``EmbeddingSpec.param_count`` and ``.compression`` read)
 * ``local_batch``                       — True when lookups need no
   model-axis exchange, so recsys batches may shard over the WHOLE mesh
 
@@ -160,12 +160,6 @@ class EmbeddingBackend:
         raise NotImplementedError
 
     def param_count(self, spec) -> int:
-        raise NotImplementedError
-
-    def cost(self, spec, batch: int) -> dict:
-        """Per-step cost model for ``batch`` examples (each example reads
-        ``n_fields`` rows of ``dim``): trained parameter count, HBM bytes
-        fetched by the lookups, and lookup arithmetic FLOPs."""
         raise NotImplementedError
 
 

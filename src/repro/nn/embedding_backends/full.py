@@ -161,12 +161,5 @@ class FullTableBackend(EmbeddingBackend):
     def param_count(self, spec) -> int:
         return spec.total_rows * spec.dim
 
-    def cost(self, spec, batch: int) -> dict:
-        # one dim-row fetch per (example, field); dense tables stream from
-        # HBM — the embedding exchange's wire bytes live in the dryrun
-        return {"params": self.param_count(spec),
-                "bytes_fetched": batch * spec.n_fields * spec.dim * 4,
-                "flops": 0}
-
 
 register_backend(FullTableBackend())

@@ -125,11 +125,5 @@ class HashedBackend(EmbeddingBackend):
         q_rows, _, _ = qr_layout(spec.vocab_sizes, m)
         return (sum(q_rows) + m * spec.n_fields) * spec.dim
 
-    def cost(self, spec, batch: int) -> dict:
-        # two dim-row fetches + one elementwise product per (example, field)
-        return {"params": self.param_count(spec),
-                "bytes_fetched": batch * spec.n_fields * 2 * spec.dim * 4,
-                "flops": batch * spec.n_fields * spec.dim}
-
 
 register_backend(HashedBackend())

@@ -11,7 +11,7 @@ codes plus one learned f32 scale per ``GROUP_SIZE``-slot group, ALPT-style:
   delivery into ``scale.dtype``).  The jnp path gathers the int8 codes
   themselves, so its row reads are ~4× fewer bytes; the Pallas path
   dequantizes the whole array to f32 before its DMA gather and saves
-  nothing yet (see ``cost``).
+  nothing yet.
 * **scale training** — the scales are ordinary float leaves; the op's
   custom_vjp delivers their analytic gradient, so quantization is learned,
   not calibrated.
@@ -47,7 +47,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.robe import init_memory, robe_signs, robe_slots
 from repro.nn.embedding_backends.base import EmbeddingBackend, \
     register_backend
-from repro.nn.embedding_backends.robe import analytic_max_fetches
 
 #: slots per learned scale (power of two — the kernel indexes scales with a
 #: shift, never a divide)
@@ -153,28 +152,6 @@ class QRobeBackend(EmbeddingBackend):
         # training-time carrier that is identically zero between steps and
         # never ships.
         return spec.robe.size + n_groups(spec.robe.size)
-
-    def cost(self, spec, batch: int, bus: int = 16) -> dict:
-        """jnp path: robe's coalesced-fetch model at 1 byte/element instead
-        of 4, plus ~one f32 scale line per row — the ~4× serve-bytes claim.
-        With ``use_kernel``: the Pallas path's bytes — the whole array
-        dequantized to f32 (codes and scales read, f32 written), then
-        robe's DMA gather over that f32 copy."""
-        from repro.kernels.robe_lookup import gather_bytes
-        z, m = spec.robe.block_size, spec.robe.size
-        pairs = batch * spec.n_fields
-        if spec.use_kernel:
-            fetched = (m + n_groups(m) * 4 + m * 4
-                       + gather_bytes(m, spec.dim, z, pairs))
-        else:
-            fetched = int(pairs * (analytic_max_fetches(spec.dim, z, bus)
-                                   * bus * 1 + 4))
-        flops = 10 * pairs * spec.dim
-        flops += pairs * spec.dim                      # the dequant multiply
-        if spec.robe.use_sign:
-            flops += pairs * spec.dim
-        return {"params": self.param_count(spec),
-                "bytes_fetched": fetched, "flops": flops}
 
 
 register_backend(QRobeBackend())

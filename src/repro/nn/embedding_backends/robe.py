@@ -38,16 +38,6 @@ def robe_allgather_body(mem_shard: jnp.ndarray, model_axis: str
     return jax.lax.all_gather(mem_shard, model_axis, axis=0, tiled=True)
 
 
-def analytic_max_fetches(d: int, z: int, bus: int) -> float:
-    """Paper Table 1 bound: max B-sized bus fetches per d-dim row at block
-    size Z.  The substrate's memory-traffic model (see ``cost``)."""
-    if z >= d:
-        return d / bus + 2
-    if z >= bus:
-        return d / bus + d / z
-    return 2 * d / z
-
-
 class RobeBackend(EmbeddingBackend):
     name = "robe"
     local_batch = True           # lookups never exchange over `model`
@@ -146,29 +136,6 @@ class RobeBackend(EmbeddingBackend):
 
     def param_count(self, spec) -> int:
         return spec.robe.size
-
-    def cost(self, spec, batch: int, bus: int = 16) -> dict:
-        """``bytes_fetched`` models the paper's access pattern on the jnp
-        path — block-coalesced reads, ≤ ``analytic_max_fetches`` bus lines
-        per row (Table 1), an ideal reader and not a measurement of XLA's
-        gather — and, with ``use_kernel``, counts what the Pallas DMA
-        gather moves (``kernels.robe_lookup.gather_bytes``: the per-call
-        padded f32 copy of the array plus 128-lane windows per row).
-        Hashing is ~10 int ops per element, plus the optional sign
-        multiply."""
-        from repro.kernels.robe_lookup import gather_bytes
-        z = spec.robe.block_size
-        pairs = batch * spec.n_fields
-        if spec.use_kernel:
-            fetched = gather_bytes(spec.robe.size, spec.dim, z, pairs)
-        else:
-            fetched = int(pairs * analytic_max_fetches(spec.dim, z, bus)
-                          * bus * 4)
-        flops = 10 * pairs * spec.dim
-        if spec.robe.use_sign:
-            flops += pairs * spec.dim
-        return {"params": self.param_count(spec),
-                "bytes_fetched": fetched, "flops": flops}
 
 
 register_backend(RobeBackend())

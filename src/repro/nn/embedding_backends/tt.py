@@ -106,13 +106,5 @@ class TensorTrainBackend(EmbeddingBackend):
         (n1, n2, n3), (d1, d2, d3), r = self._dims(spec)
         return n1 * d1 * r + n2 * r * d2 * r + n3 * r * d3
 
-    def cost(self, spec, batch: int) -> dict:
-        (n1, n2, n3), (d1, d2, d3), r = self._dims(spec)
-        per_row_bytes = (d1 * r + r * d2 * r + r * d3) * 4
-        per_row_flops = 2 * (d1 * d2 * r * r + d1 * d2 * d3 * r)
-        return {"params": self.param_count(spec),
-                "bytes_fetched": batch * spec.n_fields * per_row_bytes,
-                "flops": batch * spec.n_fields * per_row_flops}
-
 
 register_backend(TensorTrainBackend())

@@ -20,8 +20,8 @@ name and selected via ``EmbeddingSpec.kind``:
   small cores, rows contracted on the fly.
 
 Each backend owns its init, lookups, PartitionSpec tree (consumed by
-``repro.dist.param_specs``), distributed shard_map bodies, and roofline
-cost model — ``get_backend(spec.kind)`` is the only dispatch point.
+``repro.dist.param_specs``), distributed shard_map bodies, and parameter
+count — ``get_backend(spec.kind)`` is the only dispatch point.
 
 ``embedding_init`` / ``embedding_lookup`` / ``embedding_lookup_bag`` below
 are thin wrappers over the backend so existing callers keep working.  JAX

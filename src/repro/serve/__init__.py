@@ -11,7 +11,7 @@ scoring, and traffic replay.
 * ``fleet``     — ``ReplicaFleet``: N replicas behind one admission path
   (shed → retry-on-replica) with staggered model rollouts
 * ``replay``    — virtual-clock open-loop traffic replay (single server or
-  fleet); the measurement harness behind ``BENCH_serving.json``
+  fleet), the harness the serving and online tests drive
 
 The light names are re-exported here; ``server``/``fleet``/``replay`` stay
 submodule imports (they pull in the full model stack).

@@ -1,12 +1,11 @@
 """Skewed traffic replay: open-loop Poisson load against the serving tier.
 
-The measurement harness behind ``BENCH_serving.json``: replay a
-deterministic synthetic-CTR request trace (``data.synthetic_ctr``:
-zipf-skewed ids, Poisson arrivals) through a batching policy
-(``router.DeadlineBatcher`` vs ``router.FixedBatcher``) into a substrate
-of the ``EmbeddingServer``, and record p50/p99 latency, delivered
-throughput, shed counts, and hot-cache hit rate per backend × policy ×
-zipf cell.
+A test harness for the serving tier: replay a deterministic
+synthetic-CTR request trace (``data.synthetic_ctr``: zipf-skewed ids,
+Poisson arrivals) through a batching policy (``router.DeadlineBatcher``
+vs ``router.FixedBatcher``) into a substrate of the ``EmbeddingServer``,
+and record p50/p99 latency, delivered throughput, shed counts, and
+hot-cache hit rate per backend × policy × zipf cell.
 
 The replay runs on a **virtual clock** — the event loop advances time to
 the next arrival or forced batch close-out; nothing ever sleeps:
@@ -16,10 +15,10 @@ the next arrival or forced batch close-out; nothing ever sleeps:
   distributions to the float with ``service="synthetic"``;
 * with ``service="measured"`` each dispatched batch really executes the
   jitted scorer and its wall time becomes the batch's service time on the
-  virtual timeline — real compute, simulated waiting.  This is how the
-  benchmark rows are produced: the percentiles combine measured service
-  with exactly-modeled queueing at the configured offered load, without
-  an hour of wall-clock replay (and without wall-clock sleeps in CI).
+  virtual timeline — real compute, simulated waiting: the percentiles
+  combine measured service with exactly-modeled queueing at the
+  configured offered load, without wall-clock sleeps.  They are not
+  device readings.
 
 Single-server semantics: dispatched batches execute in order on one
 model; a batch closed while the scorer is busy queues for the device.
@@ -36,10 +35,7 @@ sequence of per-replica swaps serialized on their measured end times, so
 at most one replica is ever mid-swap on the virtual timeline while the
 rest keep serving.
 
-Layering: this module returns plain row dicts; the benchmarks layer
-(``benchmarks/table4_inference_throughput.serving_rows``) stamps them
-with provenance (``benchmarks.common.stamp_row``) and writes
-``BENCH_serving.json``.
+The ``run_*`` helpers return plain row dicts, one per cell.
 """
 
 from __future__ import annotations
@@ -115,13 +111,12 @@ class ReplayReport:
         r["offered_qps"] = round(r["offered_qps"], 1)
         r["mean_batch"] = round(r["mean_batch"], 2)
         r["makespan_s"] = round(r["makespan_s"], 4)
-        # fleet diagnostics stay off the row — existing single-server row
-        # schemas must not drift (check_bench gates per-name key sets);
-        # run_fleet_cell adds n_replicas/retried columns explicitly
+        # fleet diagnostics stay off the row, so a single-server row keeps
+        # one schema; run_fleet_cell adds n_replicas/retried explicitly
         for k in ("n_replicas", "retried", "replica_batches", "push_log"):
             r.pop(k)
         # push columns only exist on push-schedule rows — plain cells keep
-        # their schema (check_bench treats per-name key drift as failure)
+        # their schema
         if r.pop("has_pushes"):
             r["push_p50_ms"] = round(r["push_p50_ms"], 3)
             r["push_max_ms"] = round(r["push_max_ms"], 3)

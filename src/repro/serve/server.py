@@ -72,7 +72,7 @@ class ServerConfig:
     ``cache_capacity`` rows per cacheable substrate (0 disables the hot
     cache); ``use_kernel`` routes serving through the Pallas lookup
     and dot-interaction kernels (interpret mode off-TPU — slow but
-    conformant, so benchmarks default it off on CPU).
+    conformant; no benchmark cell turns it on).
     """
 
     vocab_sizes: Tuple[int, ...]
@@ -112,7 +112,8 @@ class ServerConfig:
 
 @dataclasses.dataclass(frozen=True)
 class PushReport:
-    """What one ``EmbeddingServer.push`` did (the BENCH push-row feed)."""
+    """What one ``EmbeddingServer.push`` did (the push cell's row reads
+    it)."""
 
     backend: str
     step: int

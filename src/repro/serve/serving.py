@@ -3,7 +3,7 @@
 The serving subsystem proper lives in the sibling modules — ``router``
 (deadline-aware async batching), ``hot_cache`` (frequency-sketch hot-row
 cache), ``server`` (multi-substrate ``EmbeddingServer``), ``replay``
-(virtual-clock traffic replay → ``BENCH_serving.json``).  This module
+(virtual-clock traffic replay).  This module
 keeps the synchronous conveniences:
 
 * ``MicroBatcher`` — a thin sync wrapper over the router's

@@ -326,6 +326,94 @@ def test_robe_replicated_lookup_is_local():
     """)
 
 
+def test_robe_replicated_lookup_uneven_batch_matches_local():
+    """A batch of 13 rows does not split over the 8 devices: the
+    replicated ROBE array's lookup leaves it to the compiler instead of
+    the per-device ``shard_map``, and the embeddings, the loss and the
+    gradients still match one device."""
+    _run("""
+        from repro.dist import api as dist
+        from repro.models.recsys import RecsysConfig, init_params, loss_fn
+        from repro.nn.embeddings import embedding_lookup_dist
+        cfg = RecsysConfig(name="d", arch="dlrm", n_dense=4,
+                           bot_mlp=(16, 8), top_mlp=(16, 1), embed_dim=8,
+                           vocab_sizes=(64, 96, 32), embedding="robe",
+                           robe_size=512, robe_block=8,
+                           compute_dtype=jnp.float32)
+        spec = cfg.embedding_spec()
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        rs = np.random.RandomState(1)
+        batch = {"dense": jnp.asarray(rs.randn(13, 4), jnp.float32),
+                 "sparse": jnp.asarray(rs.randint(0, 30, (13, 3)), jnp.int32),
+                 "label": jnp.asarray(rs.randint(0, 2, (13,)), jnp.int32)}
+        grad = jax.value_and_grad(lambda p, b: loss_fn(p, cfg, b)[0])
+        emb_one = embedding_lookup_dist(params["embedding"], spec,
+                                        batch["sparse"])
+        l_one, g_one = grad(params, batch)
+        mesh = make_mesh((2, 4), ("data", "model"))
+        ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
+        assert 13 % ctx.n_devices
+        with dist.use(ctx):
+            emb_mesh = jax.jit(lambda p, ix: embedding_lookup_dist(
+                p, spec, ix))(params["embedding"], batch["sparse"])
+            l_mesh, g_mesh = jax.jit(grad)(params, batch)
+        np.testing.assert_array_equal(np.asarray(emb_one),
+                                      np.asarray(emb_mesh))
+        assert abs(float(l_one) - float(l_mesh)) < 1e-5
+        err = jax.tree.reduce(max, jax.tree.map(
+            lambda a, b: float(jnp.max(jnp.abs(a - b))), g_one, g_mesh))
+        assert err < 1e-5, err
+    """)
+
+
+def test_zero3_robe_uneven_array_matches_replicated():
+    """ZeRO-3 ROBE whose array does not split over `model` (509 slots over
+    4, as the registry's 13,067,813 do not split): the layout falls back
+    to replicated, the lookup to the compiler's, and loss and gradients
+    match the replicated array on one device."""
+    _run("""
+        from repro.dist import api as dist
+        from repro.dist.param_specs import recsys_specs
+        from repro.models.recsys import RecsysConfig, init_params, loss_fn
+        from jax.sharding import NamedSharding
+        kw = dict(name="d", arch="dlrm", n_dense=4, bot_mlp=(16, 8),
+                  top_mlp=(16, 1), embed_dim=8, vocab_sizes=(64, 96, 32),
+                  robe_size=509, robe_block=8, compute_dtype=jnp.float32)
+        cfg_rep = RecsysConfig(embedding="robe", **kw)
+        cfg_z3 = RecsysConfig(embedding="robe", robe_shard_model=True, **kw)
+        params = init_params(jax.random.PRNGKey(0), cfg_rep)
+        assert params["embedding"]["memory"].shape == (509,)
+        rs = np.random.RandomState(0)
+        batch = {"dense": jnp.asarray(rs.randn(16, 4), jnp.float32),
+                 "sparse": jnp.asarray(rs.randint(0, 30, (16, 3)), jnp.int32),
+                 "label": jnp.asarray(rs.randint(0, 2, (16,)), jnp.int32)}
+        l_rep, _ = loss_fn(params, cfg_rep, batch)
+        g_rep = jax.grad(lambda p: loss_fn(p, cfg_rep, batch)[0])(params)
+        mesh = make_mesh((2, 4), ("data", "model"))
+        ctx = dist.DistContext(mesh=mesh, rules=dist.default_rules())
+        pshapes = jax.eval_shape(
+            functools.partial(init_params, cfg=cfg_z3),
+            jax.random.PRNGKey(0))
+        pspecs = recsys_specs(pshapes, ctx.rules,
+                              embedding_spec=cfg_z3.embedding_spec())
+        assert pspecs["embedding"]["memory"] == P("model"), pspecs
+        pspecs = dist.prune_specs(pspecs, pshapes, mesh)
+        assert pspecs["embedding"]["memory"] == P(None), pspecs
+        shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
+                                 is_leaf=lambda x: isinstance(x, P))
+        with dist.use(ctx):
+            l_z3, _ = jax.jit(lambda p, b: loss_fn(p, cfg_z3, b),
+                              in_shardings=(shardings, None))(params, batch)
+            g_z3 = jax.jit(jax.grad(
+                lambda p: loss_fn(p, cfg_z3, batch)[0]),
+                in_shardings=(shardings,))(params)
+        assert abs(float(l_rep) - float(l_z3)) < 1e-5
+        err = jax.tree.reduce(max, jax.tree.map(
+            lambda a, b: float(jnp.max(jnp.abs(a - b))), g_rep, g_z3))
+        assert err < 1e-5, err
+    """)
+
+
 def test_recsys_cells_compile_every_backend():
     """The dlrm-rm2 serve cell compiles for all four substrates with each
     backend's own param_specs (mesh scaled to the CI host's 8 devices;

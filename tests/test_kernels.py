@@ -126,28 +126,39 @@ def test_robe_lookup_wraps_circularly():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want))
 
 
-def _block_gather_case(d, z, dtype, sign, b=13, f=3):
+#: the table of each id column when fields are multi-hot: a field's
+#: columns repeat its id, as ``EmbeddingSpec.columns`` gives them
+MULTI_HOT_FIELDS = (0, 0, 1, 2, 2, 2)
+
+
+def _block_gather_case(d, z, dtype, sign, b=13, f=3, tids=None):
     """A prime batch over an array of 3·Z + 5 slots, so that blocks run
     past its end; ids span the 64-bit element index."""
+    tids = tuple(range(f)) if tids is None else tids
     rs = np.random.RandomState(d * 1000 + z)
     m = 3 * z + 5
     spec = RobeSpec(size=m, block_size=z, seed=5, use_sign=sign)
     mem = jnp.asarray(rs.randn(m), dtype)
-    rows = jnp.asarray(rs.randint(0, 2 ** 31 - 1, (b, f)), jnp.int32)
-    want = core_robe_lookup(mem, spec, jnp.arange(f, dtype=jnp.uint32)[None],
+    rows = jnp.asarray(rs.randint(0, 2 ** 31 - 1, (b, len(tids))), jnp.int32)
+    want = core_robe_lookup(mem, spec, jnp.asarray(tids, jnp.uint32)[None],
                             rows, d)
-    return mem, rows, tuple(range(f)), spec, want
+    return mem, rows, tids, spec, want
 
 
 @pytest.mark.parametrize("sign", [False, True])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("d,z", [(64, 32), (128, 32), (32, 32), (16, 8),
-                                 (16, 32), (48, 32)])
-def test_robe_lookup_block_gather_is_exact(d, z, dtype, sign):
+@pytest.mark.parametrize("d,z,tids", [
+    (64, 32, None), (128, 32, None), (32, 32, None), (16, 8, None),
+    (16, 32, None), (48, 32, None), (128, 32, MULTI_HOT_FIELDS),
+], ids=["64-32", "128-32", "32-32", "16-8", "16-32", "48-32",
+        "128-32-multi-hot"])
+def test_robe_lookup_block_gather_is_exact(d, z, tids, dtype, sign):
     """The jnp forward gathers whole Z-blocks (Z | d: the blocks end to
     end; Z ∤ d: d elements from inside them) and returns exactly the
-    element-wise definition's values, in the memory's dtype."""
-    mem, rows, tids, spec, want = _block_gather_case(d, z, dtype, sign)
+    element-wise definition's values, in the memory's dtype, also where
+    several id columns belong to one field (multi-hot)."""
+    mem, rows, tids, spec, want = _block_gather_case(d, z, dtype, sign,
+                                                     tids=tids)
     got = robe_lookup(mem, rows, tids, d, spec, False)
     assert got.dtype == want.dtype and got.shape == want.shape
     np.testing.assert_array_equal(np.asarray(got, np.float32),
@@ -248,27 +259,30 @@ def test_robe_lookup_grad_scatter_adds_the_cotangent():
     np.testing.assert_allclose(np.asarray(g), want, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("d,sign,dtype,ids,m,chunk", [
-    (16, False, jnp.float32, "drawn", 4099, None),     # d | Z
-    (64, False, jnp.float32, "drawn", 4099, None),     # Z | d
-    (128, False, jnp.float32, "drawn", 4099, None),
-    (48, False, jnp.float32, "drawn", 4099, None),     # Z ∤ d
-    (128, True, jnp.float32, "drawn", 4099, None),     # signs
-    (64, True, jnp.bfloat16, "drawn", 4099, None),     # bf16 array
-    (128, False, jnp.float32, "equal", 4099, None),    # one table row
-    (64, True, jnp.float32, "drawn", 101, None),       # blocks wrap
-    (48, True, jnp.float32, "drawn", 4099, 1 << 14),   # in pieces
+@pytest.mark.parametrize("d,sign,dtype,ids,m,chunk,fields", [
+    (16, False, jnp.float32, "drawn", 4099, None, None),     # d | Z
+    (64, False, jnp.float32, "drawn", 4099, None, None),     # Z | d
+    (128, False, jnp.float32, "drawn", 4099, None, None),
+    (48, False, jnp.float32, "drawn", 4099, None, None),     # Z ∤ d
+    (128, True, jnp.float32, "drawn", 4099, None, None),     # signs
+    (64, True, jnp.bfloat16, "drawn", 4099, None, None),     # bf16 array
+    (128, False, jnp.float32, "equal", 4099, None, None),    # one table row
+    (64, True, jnp.float32, "drawn", 101, None, None),       # blocks wrap
+    (48, True, jnp.float32, "drawn", 4099, 1 << 14, None),   # in pieces
+    (128, True, jnp.float32, "drawn", 4099, None, MULTI_HOT_FIELDS),
 ], ids=["d16", "d64", "d128", "d48", "sign", "bf16", "equal-ids", "wrap",
-        "pieces"])
+        "pieces", "multi-hot"])
 def test_robe_lookup_block_scatter_matches_element_scatter(
-        monkeypatch, d, sign, dtype, ids, m, chunk):
+        monkeypatch, d, sign, dtype, ids, m, chunk, fields):
     """The VJP adds the signed cotangent into the array a whole Z-block
     at a time (the transpose of the block gather): it equals the
     element-by-element scatter-add to f32 tolerance, is summed in f32 and
     delivered in the array's dtype, for any Z and d, a batch of one id
     (each field's blocks all add into the same few table rows), blocks
-    that run past the array's end, and a batch scattered in pieces."""
-    b, f, z = 37, 3, 32
+    that run past the array's end, a batch scattered in pieces, and id
+    columns of which several belong to one field (multi-hot)."""
+    fields = (0, 1, 2) if fields is None else fields
+    b, f, z = 37, len(fields), 32
     if chunk:
         monkeypatch.setattr(robe_lookup_module, "CHUNK_BYTES", chunk)
         assert 1 < -(-b * f * 2 * 128 * 4 // chunk) < b
@@ -281,10 +295,10 @@ def test_robe_lookup_block_scatter_matches_element_scatter(
     # the cotangent arrives in the array's dtype
     ct = np.asarray(jnp.asarray(rs.randn(b, f, d), dtype), np.float64)
     g = jax.jit(jax.grad(lambda mm: (robe_lookup(
-        mm, rows, tuple(range(f)), d, spec, False).astype(jnp.float32)
+        mm, rows, fields, d, spec, False).astype(jnp.float32)
         * ct).sum()))(mem)
     assert g.dtype == dtype and g.shape == (m,)
-    tids = jnp.arange(f, dtype=jnp.uint32)[None]
+    tids = jnp.asarray(fields, jnp.uint32)[None]
     slots = np.asarray(robe_slots(spec, tids, rows, d)).reshape(-1)
     signs = (np.asarray(robe_signs(spec, tids, rows, d)).reshape(-1)
              if sign else 1.0)

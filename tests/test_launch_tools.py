@@ -1,5 +1,5 @@
-"""Dry-run tooling units: HLO collective parsing, wire model, cell registry,
-serving batcher, elastic checkpoint resume."""
+"""Launch and serving units: cell registry, serving batcher, elastic
+checkpoint resume, the weighted ROBE bag lookup."""
 
 import shutil
 import tempfile
@@ -7,39 +7,6 @@ import tempfile
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from repro.launch.dryrun import parse_collectives, wire_bytes, _shape_bytes
-
-
-HLO_SAMPLE = """
-  %all-gather.3 = f32[152064,1024]{1,0} all-gather(%p0), replica_groups={}
-  %ar = (f32[16,4096,1024]{2,1,0}, f32[16,4096,1024]{2,1,0}) all-reduce(%a, %b), to_apply=%add
-  %a2a.1 = bf16[384,107,7168]{2,1,0} all-to-all(%send), dimensions={0}
-  ROOT %rs = bf16[64,26,64]{2,1,0} reduce-scatter(%part), dimensions={0}
-  %not_a_coll = f32[2,2]{1,0} add(%x, %y)
-"""
-
-
-def test_parse_collectives_counts_and_bytes():
-    c = parse_collectives(HLO_SAMPLE)
-    assert c["all-gather"]["count"] == 1
-    assert c["all-gather"]["bytes"] == 152064 * 1024 * 4
-    assert c["all-reduce"]["count"] == 1
-    assert c["all-reduce"]["bytes"] == 2 * 16 * 4096 * 1024 * 4   # tuple
-    assert c["all-to-all"]["bytes"] == 384 * 107 * 7168 * 2
-    assert c["reduce-scatter"]["count"] == 1
-    assert "add" not in c
-    # ring factors: AR ×2, others ×1
-    w = wire_bytes(c)
-    expect = (c["all-gather"]["bytes"] + 2 * c["all-reduce"]["bytes"]
-              + c["all-to-all"]["bytes"] + c["reduce-scatter"]["bytes"])
-    assert w == expect
-
-
-def test_shape_bytes_scalar_and_tuple():
-    assert _shape_bytes("f32[]") == 4
-    assert _shape_bytes("bf16[8,2]") == 32
-    assert _shape_bytes("(s32[4], pred[8])") == 24
 
 
 def test_registry_covers_all_assigned_cells():
@@ -133,25 +100,3 @@ def test_robe_lookup_bag_weighted():
     e5 = robe_lookup(mem, spec, 0, jnp.asarray([5]), 8)[0]
     np.testing.assert_allclose(np.asarray(out[0, 0]),
                                np.asarray(0.25 * e2 + 0.75 * e5), atol=1e-6)
-
-
-def test_roofline_reads_multi_pod_dryrun_artifacts():
-    """The committed 2×16×16 dry-run artifacts (results/dryrun/*__multi__*)
-    feed the roofline report: every dlrm-rm2 train cell must load with
-    per-device terms and its backend's own embedding cost model."""
-    from repro.launch.roofline import corrected_terms
-    rows = {}
-    for emb in ("default", "full", "hashed", "tt"):
-        r = corrected_terms("dlrm-rm2", "train_batch", emb, mesh="multi")
-        assert r is not None, f"missing multi-pod artifact for {emb}"
-        assert r["flops_dev"] > 0 and r["bytes_dev"] > 0
-        assert r["dominant"] in ("compute", "memory", "collective")
-        assert r["embedding_cost"]["params"] > 0
-        rows[emb] = r
-    # the whole point of the paper: the ROBE cell trains the same model
-    # with orders of magnitude fewer embedding parameters than the table
-    assert rows["full"]["embedding_cost"]["params"] > \
-        50 * rows["default"]["embedding_cost"]["params"]
-    # the row-sharded full table pays an embedding exchange on the wire;
-    # multi-pod artifacts must carry the parsed collective schedule
-    assert rows["full"]["wire_dev"] > 0

@@ -412,7 +412,7 @@ def test_replay_push_events_fire_between_batches():
     row = rep.as_row()
     for k in ("pushes", "push_p50_ms", "push_max_ms", "mean_staleness_s"):
         assert k in row
-    # plain replays keep the old row schema (check_bench key-drift gate)
+    # plain replays keep the old row schema
     plain = replay(service, requests, arrivals, cfg).as_row()
     assert "pushes" not in plain and "mean_staleness_s" not in plain
 
@@ -576,8 +576,8 @@ def test_online_end_to_end(tmp_path):
 
 @pytest.mark.online
 def test_run_push_cell_produces_bench_row(tmp_path):
-    """The BENCH_serving push row's producer: online-train then replay
-    drifting traffic with scheduled pushes; row carries the push columns."""
+    """The push cell's row: online-train then replay drifting traffic
+    with scheduled pushes; the row carries the push columns."""
     pub = str(tmp_path / "pub")
     server = EmbeddingServer(ServerConfig(
         vocab_sizes=VOCABS, embed_dim=8, n_dense=4, bot_mlp=(16, 8),

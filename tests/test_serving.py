@@ -637,7 +637,7 @@ def test_server_score_fn_slices_to_valid(server):
 
 def test_server_replay_cell_end_to_end(server):
     """One measured-service replay cell through the real server: the
-    BENCH_serving.json row shape, with the hit-rate criterion live."""
+    row's shape, with the hit-rate criterion live."""
     from repro.serve.replay import ReplayConfig, run_cell
     server.reset_cache_stats()
     row = run_cell(server, "full",
@@ -930,7 +930,7 @@ def test_fleet_staggered_push_cache_parity(tmp_path):
 
 
 def test_fleet_cell_row_shape(fleet):
-    """run_fleet_cell's BENCH row: explicit n_replicas/retried columns,
+    """run_fleet_cell's row: explicit n_replicas/retried columns,
     fleet-pooled hit rate, and the plain-cell schema otherwise."""
     from repro.serve.replay import ReplayConfig, run_fleet_cell
     fleet.reset_caches()

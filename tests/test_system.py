@@ -58,8 +58,7 @@ def test_robe_matches_full_quality_at_high_compression():
 
     Achievable compression scales with the cold-row mass: CriteoTB's 1000×
     rests on ~800M mostly-cold rows; at this test's 7.3k rows the
-    scale-consistent equivalent is ~20–50×.  benchmarks/table2 exercises
-    the 1000× setting at its (larger) scale."""
+    scale-consistent equivalent is ~20–50×."""
     rep_f, auc_f, n_f = _train("full", steps=150)
     # the paper's caveat (§4.4): the compressed model needs ~2× iterations
     rep_r, auc_r, n_r = _train("robe", steps=300)

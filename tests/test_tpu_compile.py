@@ -7,8 +7,10 @@ tests compile each kernel ahead of time for one chip of a described
 d=64, a 13,067,813-slot ROBE array) and dlrm-criteo-tb ``train_batch``
 (B=65,536, d=128, 26,135,627 slots) — and check that the program holds
 the kernel (``tpu_custom_call``); the default jnp lookup's block gather
-and its VJP's block scatter; and the whole serve program of the
-``dcnv2-bulk`` benchmark cell.  Nothing runs, so they say nothing about
+and its VJP's block scatter; and the whole program of each benchmark
+cell: the ``rm2-bulk`` and ``dcnv2-bulk`` serve programs and the
+``tb-train`` train step on one chip, the ``tb-train-dp4`` step over the
+four chips of the topology.  Nothing runs, so they say nothing about
 results or times on the chip.
 
 The topology is described inside a module fixture, never at import:
@@ -34,7 +36,8 @@ WIDTHS = {
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The four chips of a described ``v5e:2x2`` topology."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     try:
@@ -47,8 +50,13 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _shape(shape, dtype, sharding):
@@ -214,6 +222,138 @@ def test_dcnv2_bulk_serve_program_compiles_for_v5e(one_chip):
     text = compiled.as_text()
     assert any("slice_sizes={1,128}" in line and "robe_blocks" in line
                for line in text.splitlines() if " gather(" in line)
+
+
+#: a compile of a benchmark cell's whole program that takes longer points
+#: at a relayout whose code generation takes minutes (PERF.md §7)
+CELL_COMPILE_S = 120
+#: one v5e chip's device memory
+CHIP_BYTES = 16e9
+
+
+def _compile_timed(name, lowered):
+    """A cell's lowered program compiled for the described chip(s), and
+    the seconds that took."""
+    import time
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    took = time.perf_counter() - t0
+    print(f"{name}: compiled in {took:.1f} s")
+    return compiled, took
+
+
+def _assert_fits(compiled, took):
+    """Compiled within ``CELL_COMPILE_S``, with its arguments and planned
+    temporaries within one chip's memory (per chip, for a program over
+    several)."""
+    assert took < CELL_COMPILE_S
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < CHIP_BYTES
+
+
+def _train_step(cfg, batch, sharding, batch_sharding):
+    """The ``tb-train`` cells' step: ``build_train_step`` over the
+    model's loss with the configuration's adagrad (lr 0.01, eps 1e-8),
+    lowered for a state on ``sharding`` and a ``batch``-sample batch on
+    ``batch_sharding``."""
+    from repro.models.recsys import init_params, loss_fn
+    from repro.train.optimizer import OptimizerConfig, make_optimizer
+    from repro.train.train_loop import (TrainConfig, build_train_step,
+                                        init_state)
+    opt = make_optimizer(OptimizerConfig(kind="adagrad", lr=0.01, eps=1e-8))
+    tc = TrainConfig(max_restarts=0)
+    step = build_train_step(lambda p, b: loss_fn(p, cfg, b), opt, tc)
+    state = jax.eval_shape(
+        lambda k: init_state(init_params(k, cfg), opt, tc),
+        jax.random.PRNGKey(0))
+    state = jax.tree.map(lambda x: _shape(x.shape, x.dtype, sharding), state)
+    shapes = {"dense": ((batch, cfg.n_dense), jnp.float32),
+              "sparse": ((batch, cfg.n_columns), jnp.int32),
+              "label": ((batch,), jnp.int32)}
+    return step.lower(state, {k: _shape(sh, dt, batch_sharding)
+                              for k, (sh, dt) in shapes.items()})
+
+
+def test_rm2_bulk_serve_program_compiles_for_v5e(one_chip):
+    """The whole DLRM serve program of the ``rm2-bulk`` cell (262,144 rows,
+    d=64, the 13,067,813-slot array) compiles for one v5e chip within
+    ``CELL_COMPILE_S``, fits its 16 GB, and gathers whole blocks."""
+    from repro.models.recsys import init_params
+    from repro.serve.server import _scorer
+    cfg = get_arch("dlrm-rm2").make_config()
+    assert cfg.robe_size == 13_067_813 and cfg.embed_dim == 64
+    params = jax.tree.map(
+        lambda x: _shape(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
+    batch = {"dense": _shape((262144, 13), jnp.float32, one_chip),
+             "sparse": _shape((262144, 26), jnp.int32, one_chip)}
+    compiled, took = _compile_timed("rm2-bulk serve program",
+                                    _scorer(cfg).lower(params, batch))
+    _assert_fits(compiled, took)
+    assert any("slice_sizes={1,128}" in line and "robe_blocks" in line
+               for line in compiled.as_text().splitlines()
+               if " gather(" in line)
+
+
+def test_tb_train_step_compiles_for_v5e(one_chip):
+    """The ``tb-train`` cell's whole train step (65,536 samples, d=128,
+    the 26,135,627-slot array, adagrad over it) compiles for one v5e chip
+    within ``CELL_COMPILE_S``, fits its 16 GB, and its backward
+    scatter-adds whole 128-lane table rows."""
+    cfg = get_arch("dlrm-criteo-tb").make_config()
+    assert cfg.robe_size == 26_135_627 and cfg.embed_dim == 128
+    compiled, took = _compile_timed(
+        "tb-train step", _train_step(cfg, 65536, one_chip, one_chip))
+    _assert_fits(compiled, took)
+    scatters = [line for line in compiled.as_text().splitlines()
+                if " scatter(" in line and "robe_blocks" in line]
+    assert scatters and all("update_window_dims={1}" in line
+                            for line in scatters), scatters
+
+
+@pytest.fixture(scope="module")
+def dp4_step(v5e_2x2):
+    """The ``tb-train-dp4`` cell's step, compiled for the four chips:
+    the state replicated, the 65,536-sample batch split over ``data``,
+    under the mesh's ``DistContext`` as the cell's driver runs it."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.dist.api import DistContext, default_rules, use
+    from repro.launch.mesh import make_mesh
+    cfg = get_arch("dlrm-criteo-tb").make_config()
+    mesh = make_mesh((4, 1), ("data", "model"), devices=v5e_2x2)
+    with use(DistContext(mesh=mesh, rules=default_rules())):
+        lowered = _train_step(cfg, 65536, NamedSharding(mesh, P()),
+                              NamedSharding(mesh, P("data")))
+    return _compile_timed("tb-train-dp4 step", lowered)
+
+
+def test_tb_train_dp4_step_compiles_for_v5e(dp4_step):
+    """The data-parallel step compiles for a v5e:2x2 within
+    ``CELL_COMPILE_S``, each chip holding the replicated state and a
+    quarter of the batch within its 16 GB."""
+    compiled, took = dp4_step
+    _assert_fits(compiled, took)
+    args = jax.tree.leaves(compiled.input_shardings[0])
+    assert {s.num_devices for s in args} == {4}
+
+
+#: the collective ops of a compiled HLO text, by name
+_COLLECTIVE = (r"\s(all-reduce|all-gather|all-to-all|collective-permute"
+               r"|reduce-scatter|collective-broadcast)(?:-start)?\(")
+
+
+def test_tb_train_dp4_step_exchanges_only_the_gradient_all_reduce(dp4_step):
+    """At published widths the data-parallel step's one collective is the
+    all-reduce of the gradients, and it carries the whole 26,135,627-slot
+    array's: the lookups stay on their chips (no all-gather of the array
+    or of select masks, no all-to-all of gathered blocks)."""
+    import re
+    found = [(m.group(1), line)
+             for line in dp4_step[0].as_text().splitlines()
+             for m in [re.search(_COLLECTIVE, line)] if m]
+    assert [op for op, _ in found] == ["all-reduce"], found
+    assert "f32[26135627]" in found[0][1]
 
 
 def test_refused_kernels_raise_on_tpu(monkeypatch):
